@@ -1,13 +1,13 @@
-"""Kernel micro-benchmarks: Pallas (interpret on CPU) vs jnp reference.
+"""Kernel micro-benchmarks: Pallas vs jnp reference.
 
-On-CPU wall times measure the *reference path* speed and validate the
-harness; the kernels' TPU performance is assessed structurally (BlockSpec
-VMEM footprints) in EXPERIMENTS.md §Roofline.
+Under ``JAX_PLATFORMS=cpu`` the Pallas kernels run in interpret mode, so
+those wall times are host timings of the interpreter, not device speeds.
 
 CSV: name,us_per_call,derived
 """
 from __future__ import annotations
 
+import os
 import time
 
 import jax
@@ -19,6 +19,7 @@ from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.core import library
 from repro.kernels import ops as kops
+from repro.kernels.dataflow_fire import FabricSpec
 
 
 def _time(fn, reps=5):
@@ -60,17 +61,23 @@ def main():
     print(f"kernel_rmsnorm_pallas_interpret,{us_p:.1f},"
           f"note=interpret-mode;vmem_tile=256x1024")
 
-    # dataflow fire step (one cycle of the popcount fabric)
+    # dataflow fire block (K=16 cycles of the popcount fabric, 128 slots)
     bench = library.popcount_graph(16)
-    tables, step = kops.make_fire_step(bench.graph)
-    A2 = tables["plan"]["A"] + 2
-    full = jnp.zeros((A2,), jnp.int32).at[tables["plan"]["FULL_PAD"]].set(1)
-    val = jnp.zeros((A2,), jnp.int32)
-    us = _time(lambda: step(full, val))
-    n = len(bench.graph.nodes)
-    print(f"kernel_dataflow_fire_interpret,{us:.1f},"
-          f"nodes={n};arcs={A2 - 2};note=one-cycle")
+    B, K = 128, 16
+    tables, step = kops.make_block_step(bench.graph, K, batched=True)
+    sp = FabricSpec(tables)
+    full = jnp.zeros((B, sp.A2), jnp.int32).at[:, sp.FULL_PAD].set(1)
+    z = lambda *s: jnp.zeros((B, *s), jnp.int32)
+    args = (z(sp.n_in, K), z(sp.n_in), full, z(sp.A2), z(sp.n_in),
+            z(sp.n_out), z(sp.n_out), jnp.ones((B,), jnp.int32))
+    us = _time(lambda: step(*args))
+    print(f"kernel_dataflow_fire_block_{jax.default_backend()},{us:.1f},"
+          f"nodes={len(bench.graph.nodes)};arcs={sp.A};slots={B};"
+          f"cycles={K}")
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     main()

@@ -310,6 +310,9 @@ def quick(path: str | None = None) -> list[dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     quick() if "--quick" in sys.argv else main()
     if "--trace" in sys.argv:
         export_observability()

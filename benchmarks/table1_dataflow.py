@@ -9,16 +9,17 @@ Paper columns FF / LUT / Slices / Fmax map to (DESIGN.md §2):
             the compiled backend's wall-clock tokens/s on this host.
 
 Besides the resource table, ``backend_rows`` sweeps the cycle-accurate
-executors (DESIGN.md §3): the seed per-cycle Pallas driver, the XLA
-engine at K ∈ {1, block}, and the fused Pallas block engine, each at
-batch sizes B ∈ {1, 8, 64} — reporting us/call, cycles/s, tokens/s and
-device dispatches.  ``benchmarks/run.py`` serializes these records to
-BENCH_dataflow.json so the perf trajectory is tracked across PRs.
+executors (DESIGN.md §3): the XLA engine at K ∈ {1, block} and the
+fused Pallas block engine, each at batch sizes B ∈ {1, 8, 64} —
+reporting us/call, cycles/s, tokens/s and device dispatches.
+``benchmarks/run.py`` serializes these records to BENCH_dataflow.json
+so the perf trajectory is tracked across PRs.
 
 CSV: name,us_per_call,derived
 """
 from __future__ import annotations
 
+import os
 import time
 
 import numpy as np
@@ -92,18 +93,14 @@ def backend_rows(Bs=(1, 8, 64), block=16, reps=3, k_tokens=8,
     """Executor sweep: one JSON-able record per (bench, backend, B, K).
 
     Backends:
-      pallas-percycle — seed baseline: one pallas dispatch PER CYCLE
-                        (kernels.ops.run_fabric), B=1 only.
       xla             — jnp cycle body in a while_loop, K cycles fused
                         per loop iteration (K=1 is the seed engine).
       pallas          — fused fire-block kernel, K cycles + environment
-                        per dispatch; batched via the in-kernel B grid.
+                        per dispatch; batched over slot lanes.
 
     benches: optional iterable of bench names to restrict the sweep
     (the --quick smoke path).
     """
-    from repro.kernels import ops
-
     out = []
     for name, mk in library.BENCHES.items():
         if benches is not None and name not in benches:
@@ -127,11 +124,6 @@ def backend_rows(Bs=(1, 8, 64), block=16, reps=3, k_tokens=8,
                 tokens_per_s=round(B * tok1 / us * 1e6),
                 dispatches=rs[0].dispatches,
                 cycles=rs[0].cycles))
-
-        if dt == np.int32:      # the pallas kernels are int32-only
-            compiled = ops.make_fire_step(g)
-            base_call = lambda: ops.run_fabric(g, feeds, compiled=compiled)
-            record("pallas-percycle", 1, 1, base_call, base_call())
 
         for be, K in (("xla", 1), ("xla", block), ("pallas", block)):
             if be == "pallas" and dt != np.int32:
@@ -311,5 +303,8 @@ def main(with_backends: bool = False):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     import sys
     main(with_backends="--backends" in sys.argv)

@@ -518,6 +518,7 @@ class DataflowEngine:
         self.schedule = schedule
         self._sched = None
         self._sched_on = False
+        self.sched_bails = 0    # run()/run_batch() calls that fell back
         if schedule:
             from repro.core.schedule import schedule_blockers
             blockers = schedule_blockers(graph)
@@ -613,7 +614,8 @@ class DataflowEngine:
             try:
                 return _sched.run_scheduled(self, feeds, max_cycles)
             except _sched.ScheduleBail:
-                pass        # pathological period: dynamic path below
+                # pathological period: the dynamic path below runs it
+                self.sched_bails += 1
         if self.backend == "reference":
             return run_reference(self.graph, feeds, self.token_shape,
                                  np.dtype(str(self.dtype)), max_cycles,
@@ -655,6 +657,7 @@ class DataflowEngine:
                 res = _sched.run_batch_scheduled(self, feeds_batch,
                                                  max_cycles)
             except _sched.ScheduleBail:
+                self.sched_bails += 1
                 res = None
             if res is not None:     # None: mixed feed lengths — the
                 return res          # schedule is per-length; dynamic

@@ -65,17 +65,6 @@ _ALU_OPS = tuple(
     if op not in (Op.COPY, Op.BRANCH, Op.SINK, Op.NDMERGE, Op.DMERGE))
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with the jax<=0.4.x experimental fallback (same
-    compat shim as core/pipeline.py)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 @jax.jit
 def _mf_slot_reset(fv, fl, full, val, ptr, out_last, out_count, chf, chv,
                    mask, fv_rows, fl_rows, full0, val0, chf0, chv0):
@@ -484,8 +473,9 @@ class MultiFabric:
                     out = core(*sq)
                     return jax.tree.map(lambda x: x[None], out)
                 spec = PartitionSpec("shards")
-                step = jax.jit(_shard_map(stacked, self._mesh,
-                                          in_specs=spec, out_specs=spec))
+                step = jax.jit(jax.shard_map(
+                    stacked, mesh=self._mesh, in_specs=spec,
+                    out_specs=spec, check_vma=False))
             else:
                 step = jax.jit(jax.vmap(core, axis_name="shards"))
             self._steps[nb] = step

@@ -34,18 +34,6 @@ from repro.core.graph import Graph, Op
 from repro.core.engine import run_reference
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map with a fallback for jax<=0.4.x, where it still
-    lives in jax.experimental.shard_map (and the no-replication-check
-    kwarg is spelled check_rep, not check_vma)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
-
-
 # ---------------------------------------------------------------------------
 # schedule generation
 # ---------------------------------------------------------------------------
@@ -101,6 +89,9 @@ def pipeline_apply(mesh: Mesh, stage_fn, stage_params, x_micro,
     schedule: [T, S] static table.
     Returns y_micro [M, mb, ...].
     """
+    # jax.make_mesh gives Explicit axes; the stage loop (and its
+    # transpose under grad) is written for Auto ones
+    mesh = Mesh(mesh.devices, mesh.axis_names)
     S = mesh.shape["pp"]
     T, S2 = schedule.shape
     assert S2 == S, (schedule.shape, S)
@@ -142,10 +133,10 @@ def pipeline_apply(mesh: Mesh, stage_fn, stage_params, x_micro,
             jnp.where(stage == S - 1, out, jnp.zeros_like(out)), "pp")
         return out
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         per_stage, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P("pp"), stage_params), P()),
-        out_specs=P())
+        out_specs=P(), check_vma=False)
     return fn(stage_params, x_micro)
 
 

@@ -597,15 +597,14 @@ class ScheduleContext:
         key = (struct, length, backend, batched)
         run = self._runners.get(key)
         if run is None:
-            fn = self._make_run_fn(struct)
             if backend == "pallas":
                 from repro.kernels import schedule_fire as _ksf
-                run = _ksf.make_sched_run(fn, max(self.out_arc.size, 1),
-                                          batched)
+                run = _ksf.make_sched_run(self, struct, batched)
             elif batched:
-                run = jax.jit(jax.vmap(fn, in_axes=(0, None)))
+                run = jax.jit(jax.vmap(self._make_run_fn(struct),
+                                       in_axes=(0, None)))
             else:
-                run = jax.jit(fn)
+                run = jax.jit(self._make_run_fn(struct))
             self._runners[key] = run
         return run
 

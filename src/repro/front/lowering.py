@@ -19,7 +19,7 @@ is a *schema* over several operators:
   buses (always-full environment arcs), which is what lets the PR 3
   constant-folding pass collapse constant subexpressions at compile
   time;
-* ``pjit`` / ``custom_jvp_call`` etc. — inlined recursively;
+* ``jit`` / ``pjit`` / ``custom_jvp_call`` etc. — inlined recursively;
 * ``while`` / carry-only ``scan`` (``lax.while_loop``, ``fori_loop``,
   carry-only ``lax.scan``) — the paper's cyclic loop schema
   (DESIGN.md §10): an NDMERGE entry per carry whose initial value
@@ -71,7 +71,7 @@ SUPPORTED = {
     "stop_gradient": "alias",
     "broadcast_in_dim": "alias (scalar)", "reshape": "alias (scalar)",
     "squeeze": "alias (scalar)",
-    "pjit": "inlined", "closed_call": "inlined",
+    "jit": "inlined", "pjit": "inlined", "closed_call": "inlined",
     "custom_jvp_call": "inlined", "custom_vjp_call": "inlined",
     "while": "cyclic loop schema: NDMERGE entry per carry + predicate "
              "cone + BRANCH back-edge/exit steering (scalar carries)",
@@ -93,7 +93,8 @@ _BINOP = {
 _COMMUTATIVE = frozenset(
     ("add", "mul", "max", "min", "and", "or", "xor", "eq", "ne"))
 _ALIAS = ("stop_gradient", "broadcast_in_dim", "reshape", "squeeze")
-_CALL = ("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call")
+_CALL = ("jit", "pjit", "closed_call", "custom_jvp_call",
+         "custom_vjp_call")
 
 
 def _is_literal(atom) -> bool:

@@ -1,36 +1,28 @@
-"""Pallas kernels for static-dataflow engine cycles ("fire steps").
+"""Static-dataflow engine cycles ("fire blocks"): the Pallas kernel and
+its pure-jnp mirror.
 
-The paper's FPGA executes all ready operators concurrently; on TPU the
-cycle is one vectorized pass.  The kernels are *gather-only*
-(TPU-friendly, no scatters): node-side arrays compute readiness and
-results, then each arc pulls its next state from its (unique)
-producer/consumer — legal precisely BECAUSE of the paper's
-one-sender/one-receiver channel rule.
+The paper's FPGA executes all ready operators concurrently; on a TPU a
+cycle is one vectorized pass, and a block of K cycles runs in one
+dispatch.  The environment runs inside the block: input arcs are
+strobed from per-arc feed streams, output arcs drain into last-value +
+token-count accumulators.  Quiescence is only observable at block
+granularity — a block reports the relative cycle of its last progress
+(``last_prog``), and the host stops when a block's tail goes idle (idle
+is absorbing: no feed, no fire, no drain can re-arm without one of the
+others).
 
-Two granularities:
+Two implementations of the same block:
 
-* ``fire_step_pallas``  — ONE engine cycle per ``pallas_call``; the
-  environment (input strobe / output drain) is handled by the caller.
-  Kept as the per-cycle baseline (and for tests of the bare fire rule).
-* ``fire_block_pallas`` — K engine cycles per ``pallas_call`` via an
-  in-kernel ``lax.fori_loop``.  The ``full``/``val`` arc registers stay
-  VMEM-resident across all K cycles and the *environment itself runs
-  inside the kernel*: input arcs are strobed from per-arc feed streams
-  (``feed_vals``/``feed_len`` with a per-arc pointer) and output arcs
-  are drained into last-value + token-count accumulators.  Quiescence
-  is only observable at block granularity — the kernel reports the
-  relative cycle of the last progress (``last_prog``), and the host
-  stops when a block's tail goes idle (idle is absorbing: no feed, no
-  fire, no drain can re-arm without one of the others).  This replaces
-  one device dispatch + HBM round-trip per cycle with one per K cycles.
-  ``fire_block_batched_pallas`` adds an explicit batch grid dimension:
-  B independent token streams ride one fabric in a single dispatch.
-
-Inputs (all VMEM-resident; fabrics are small — one FPGA's worth):
-  full[A2] int32, val[A2] int32       arc registers (+2 dummy slots)
-  opcode[N2], in_idx[N2,3], out_idx[N2,2]   node table (+1 dummy node)
-  prod_node/prod_slot[A2], cons_node/cons_slot[A2]  arc adjacency
-  const_mask[A2], env_row[A2], out_mask[A2]         environment maps
+* :func:`_block_body` — pure jnp over flat tables (``opcode[N2]``,
+  ``in_idx[N2,3]``, ``out_idx[N2,2]``, arc adjacency, environment
+  maps).  Gather-only: node-side arrays compute readiness and results,
+  then each arc pulls its next state from its unique producer/consumer
+  (the paper's one-sender/one-receiver rule).  The xla backend vmaps it
+  over slots.
+* :func:`fire_block_batched_pallas` — the Pallas kernel on the TPU lane
+  layout (one row per arc, slots on lanes; see the section below), with
+  every node emitted as static code.  :func:`fire_block_pallas` is the
+  same kernel with one live slot.
 """
 from __future__ import annotations
 
@@ -44,7 +36,7 @@ from repro.core.graph import Op
 
 
 def _ready_and_z(opcode, in_idx, out_idx, full, val, class_slices=None):
-    """Vectorized firing rule (shared by kernel and ref).
+    """Vectorized firing rule of :func:`_block_body`.
 
     class_slices — static ``((opcode, start, stop), ...)`` from an
     opcode-specialized plan (DESIGN.md §8).  When given, the node table
@@ -172,8 +164,7 @@ def _ready_and_z_spec(class_slices, in_idx, out_idx, full, val):
 
 def _fire_parts(opcode, in_idx, out_idx, prod_node, prod_slot, cons_node,
                 cons_slot, const_mask, full, val, class_slices=None):
-    """Fire step returning the per-node ``ready`` vector (the profiled
-    paths need it; :func:`_fire_body` reduces it to a sum)."""
+    """One fire step: (full', val', per-node ``ready``)."""
     ready, z, consume, produce = _ready_and_z(opcode, in_idx, out_idx,
                                               full, val, class_slices)
     # arc-side gather (single producer / single consumer per channel)
@@ -183,26 +174,6 @@ def _fire_parts(opcode, in_idx, out_idx, prod_node, prod_slot, cons_node,
     new_full = new_full | (const_mask > 0)
     new_val = jnp.where(produced, z[prod_node], val)
     return new_full.astype(full.dtype), new_val, ready
-
-
-def _fire_body(opcode, in_idx, out_idx, prod_node, prod_slot, cons_node,
-               cons_slot, const_mask, full, val, class_slices=None):
-    new_full, new_val, ready = _fire_parts(
-        opcode, in_idx, out_idx, prod_node, prod_slot, cons_node,
-        cons_slot, const_mask, full, val, class_slices)
-    return new_full, new_val, ready.astype(jnp.int32).sum()
-
-
-def _kernel(opcode_ref, in_idx_ref, out_idx_ref, prod_node_ref,
-            prod_slot_ref, cons_node_ref, cons_slot_ref, const_ref,
-            full_ref, val_ref, nfull_ref, nval_ref, fired_ref):
-    nf, nv, fired = _fire_body(
-        opcode_ref[...], in_idx_ref[...], out_idx_ref[...],
-        prod_node_ref[...], prod_slot_ref[...], cons_node_ref[...],
-        cons_slot_ref[...], const_ref[...], full_ref[...], val_ref[...])
-    nfull_ref[...] = nf
-    nval_ref[...] = nv
-    fired_ref[0] = fired
 
 
 def plan_arrays(graph, optimize: bool = False):
@@ -242,34 +213,6 @@ def plan_arrays(graph, optimize: bool = False):
                 prod_node=prod_node, prod_slot=prod_slot,
                 cons_node=cons_node, cons_slot=cons_slot,
                 const_mask=const_mask, plan=p, class_slices=class_slices)
-
-
-def fire_step_pallas(tables, full, val, interpret=None):
-    """One engine cycle via pallas_call. full/val: int32[A+2]."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    A2 = full.shape[0]
-    N2 = tables["opcode"].shape[0]
-    out = pl.pallas_call(
-        _kernel,
-        in_specs=[pl.BlockSpec(x.shape, lambda n=x.ndim: (0,) * n)
-                  for x in (tables["opcode"], tables["in_idx"],
-                            tables["out_idx"], tables["prod_node"],
-                            tables["prod_slot"], tables["cons_node"],
-                            tables["cons_slot"], tables["const_mask"])]
-        + [pl.BlockSpec((A2,), lambda: (0,)),
-           pl.BlockSpec((A2,), lambda: (0,))],
-        out_specs=[pl.BlockSpec((A2,), lambda: (0,)),
-                   pl.BlockSpec((A2,), lambda: (0,)),
-                   pl.BlockSpec((1,), lambda: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((A2,), jnp.int32),
-                   jax.ShapeDtypeStruct((A2,), jnp.int32),
-                   jax.ShapeDtypeStruct((1,), jnp.int32)],
-        interpret=interpret,
-    )(tables["opcode"], tables["in_idx"], tables["out_idx"],
-      tables["prod_node"], tables["prod_slot"], tables["cons_node"],
-      tables["cons_slot"], tables["const_mask"], full, val)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +308,7 @@ def _env_cycle(tab, feed_vals, feed_len, carry, class_slices=None,
 
 def _block_body(tab, feed_vals, feed_len, full, val, ptr, out_last,
                 out_count, n_cycles: int, class_slices=None, prof=None):
-    """Run `n_cycles` engine cycles; pure jnp (shared by kernel + ref).
+    """Run `n_cycles` engine cycles; pure jnp (the xla slot step).
 
     Returns (full, val, ptr, out_last, out_count, fired, last_prog)
     where fired counts firings within this block and last_prog is the
@@ -387,188 +330,382 @@ def _block_body(tab, feed_vals, feed_len, full, val, ptr, out_last,
     return carry[:7] + tuple(carry[8:])
 
 
-def _block_kernel(n_cycles, class_slices, *refs):
-    """pallas kernel: 12 table refs, feed_vals, feed_len, 5 state refs in;
-    5 state refs + fired + last_prog out."""
-    ins, outs = refs[:19], refs[19:]
-    tab = {k: r[...] for k, r in zip(_TABLE_KEYS, ins[:12])}
-    feed_vals, feed_len = ins[12][...], ins[13][...]
-    state = [r[...] for r in ins[14:19]]
-    res = _block_body(tab, feed_vals, feed_len, *state, n_cycles=n_cycles,
-                      class_slices=class_slices)
-    for r, v in zip(outs[:5], res[:5]):
-        r[...] = v
-    outs[5][0] = res[5]
-    outs[6][0] = res[6]
+# ---------------------------------------------------------------------------
+# TPU lane layout shared by every Pallas kernel of the fabric
+# ---------------------------------------------------------------------------
+# A register file is stored row-major by arc, with the slots (independent
+# token streams) on the two minor axes: ``[A2, m, 128]`` int32, slot
+# ``b`` at ``[:, b // 128, b % 128]``.  Every node of the fabric is known
+# at trace time, so each one reads its operand rows with static leading
+# indices and computes one lane-dense vector op across all slots of a
+# tile — no gathers, no scatters.  A grid step covers ``s`` sublane rows
+# (s * 128 slots); ``s`` is 8 (one full vreg per row) once there are
+# more than 1024 slots.
+LANES = 128
+_SUBLANES = 8
 
 
-def _batched_block_kernel(n_cycles, class_slices, *refs):
-    """Same as _block_kernel but every non-table ref has a leading
-    batch-block dim of 1 (grid over B selects the stream), plus a
-    per-stream ``active`` flag: an inactive slot's block is skipped
-    entirely (state passes through, fired/last_prog report 0) — the
-    per-slot clock that lets a continuous-batching server freeze
-    quiesced/empty slots instead of burning K cycles on them."""
-    ins, outs = refs[:20], refs[20:]
-    tab = {k: r[...] for k, r in zip(_TABLE_KEYS, ins[:12])}
-    feed_vals, feed_len = ins[12][0], ins[13][0]
-    state = [r[0] for r in ins[14:19]]
-    active = ins[19][0] != 0
-    res = jax.lax.cond(
-        active,
-        lambda: _block_body(tab, feed_vals, feed_len, *state,
-                            n_cycles=n_cycles, class_slices=class_slices),
-        lambda: (*state, jnp.int32(0), jnp.int32(0)))
-    for r, v in zip(outs[:5], res[:5]):
-        r[...] = v[None]
-    outs[5][0, 0] = res[5]
-    outs[6][0, 0] = res[6]
+def interpret_mode() -> bool:
+    """Pallas kernels run in interpret mode exactly when JAX's default
+    backend is the CPU (tests and rehearsals under ``JAX_PLATFORMS=cpu``);
+    on a TPU every kernel is compiled by Mosaic."""
+    return jax.default_backend() == "cpu"
 
 
-def _block_kernel_prof(n_cycles, class_slices, *refs):
-    """Profiled :func:`_block_kernel`: 5 extra in-refs carry the §12
-    counter arrays in and 5 extra out-refs carry them out, accumulated
-    across the K in-kernel cycles — profiling adds zero extra
-    dispatches, only wider block I/O."""
-    ins, outs = refs[:24], refs[24:]
-    tab = {k: r[...] for k, r in zip(_TABLE_KEYS, ins[:12])}
-    feed_vals, feed_len = ins[12][...], ins[13][...]
-    state = [r[...] for r in ins[14:19]]
-    prof = tuple(r[...] for r in ins[19:24])
-    res = _block_body(tab, feed_vals, feed_len, *state, n_cycles=n_cycles,
-                      class_slices=class_slices, prof=prof)
-    for r, v in zip(outs[:5], res[:5]):
-        r[...] = v
-    outs[5][0] = res[5]
-    outs[6][0] = res[6]
-    for r, v in zip(outs[7:12], res[7:12]):
-        r[...] = v
+def slot_tiles(batch: int) -> tuple[int, int]:
+    """(m, s): ``batch`` slots padded to ``m`` rows of 128 lanes, split
+    into grid tiles of ``s`` rows (m % s == 0)."""
+    m = -(-batch // LANES)
+    if m <= _SUBLANES:
+        return m, m
+    return -(-m // _SUBLANES) * _SUBLANES, _SUBLANES
 
 
-def _batched_block_kernel_prof(n_cycles, class_slices, *refs):
-    """Profiled :func:`_batched_block_kernel` — an inactive slot's
-    counters pass through untouched (a parked slot accrues no stalls)."""
-    ins, outs = refs[:25], refs[25:]
-    tab = {k: r[...] for k, r in zip(_TABLE_KEYS, ins[:12])}
-    feed_vals, feed_len = ins[12][0], ins[13][0]
-    state = [r[0] for r in ins[14:19]]
-    active = ins[19][0] != 0
-    prof = tuple(r[0] for r in ins[20:25])
-    res = jax.lax.cond(
-        active,
-        lambda: _block_body(tab, feed_vals, feed_len, *state,
-                            n_cycles=n_cycles, class_slices=class_slices,
-                            prof=prof),
-        lambda: (*state, jnp.int32(0), jnp.int32(0), *prof))
-    for r, v in zip(outs[:5], res[:5]):
-        r[...] = v[None]
-    outs[5][0, 0] = res[5]
-    outs[6][0, 0] = res[6]
-    for r, v in zip(outs[7:12], res[7:12]):
-        r[...] = v[None]
+def to_lanes(x, m: int):
+    """[B, *R] -> [*R, m, 128]: slots onto the minor axes, zero-padded."""
+    B = x.shape[0]
+    x = jnp.pad(x, [(0, m * LANES - B)] + [(0, 0)] * (x.ndim - 1))
+    x = jnp.moveaxis(x, 0, -1)
+    return x.reshape(*x.shape[:-1], m, LANES)
 
 
-def _whole(x):
-    """BlockSpec covering the whole (broadcast) array, any grid arity."""
-    nd = x.ndim
-    return pl.BlockSpec(x.shape, lambda *_, n=nd: (0,) * n)
+def from_lanes(x, batch: int):
+    """Inverse of :func:`to_lanes`: [*R, m, 128] -> [batch, *R]."""
+    x = x.reshape(*x.shape[:-2], -1)[..., :batch]
+    return jnp.moveaxis(x, -1, 0)
 
 
-def fire_block_pallas(tables, feed_vals, feed_len, full, val, ptr,
-                      out_last, out_count, *, n_cycles: int,
-                      prof=None, interpret=None):
-    """K fused engine cycles (environment included) via one pallas_call.
+def feed_window(feed_vals, ptr, n_cycles: int):
+    """[B, n_in, K] next-K-token window of every feed row from its
+    pointer (clamped like the reference gather).  A slot feeds at most
+    one token per row per cycle, so a K-cycle block only ever reads
+    ``window[..., ptr_now - ptr_block_start]``: the kernel selects it
+    lane-wise instead of gathering from the whole stream."""
+    L = feed_vals.shape[2]
+    idx = jnp.clip(ptr[:, :, None] + jnp.arange(n_cycles, dtype=ptr.dtype),
+                   0, L - 1)
+    return jnp.take_along_axis(feed_vals, idx, axis=2)
 
-    tables: block_plan_arrays() output (jnp or numpy arrays).
+
+def pick(rows, d):
+    """rows[d] lane-wise for a static list of rows: a select chain."""
+    out = rows[0]
+    for j in range(1, len(rows)):
+        out = jnp.where(d == j, rows[j], out)
+    return out
+
+
+def compiler_params(block_rows: int, sublanes: int):
+    """Mosaic parameters for a kernel whose in+out blocks hold
+    ``block_rows`` rows of ``sublanes`` x 128 int32: the slot-tile grid
+    is parallel, and the scoped VMEM limit covers the double-buffered
+    blocks (sublane rows pad to 8)."""
+    from jax.experimental.pallas import tpu as pltpu
+    need = 2 * block_rows * max(sublanes, _SUBLANES) * LANES * 4
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel",),
+        vmem_limit_bytes=int(min(max(need + (8 << 20), 32 << 20),
+                                 100 << 20)))
+
+
+# -- trace-time boolean folding: pad rows and const arcs are static ----
+def _and(*xs):
+    acc = True
+    for x in xs:
+        if x is False:
+            return False
+        if x is not True:
+            acc = x if acc is True else acc & x
+    return acc
+
+
+def _or(*xs):
+    acc = False
+    for x in xs:
+        if x is True:
+            return True
+        if x is not False:
+            acc = x if acc is False else acc | x
+    return acc
+
+
+def _not(x):
+    return (not x) if isinstance(x, bool) else ~x
+
+
+def _sel(c, a, b):
+    if c is True or a is b:
+        return a
+    if c is False:
+        return b
+    return jnp.where(c, a, b)
+
+
+def _msel(c, a, b):
+    """_sel for masks (Mosaic selects no i1 vectors)."""
+    return _or(_and(c, a), _and(_not(c), b))
+
+
+def _i32(x, shape):
+    if isinstance(x, bool):
+        return jnp.full(shape, int(x), jnp.int32)
+    return x.astype(jnp.int32)
+
+
+class FabricSpec:
+    """The node/arc tables as Python ints: what the kernels bake in as
+    static row indices and per-node code."""
+
+    def __init__(self, tables):
+        import numpy as np
+        p = tables["plan"]
+        self.A = int(p["A"])
+        self.A2 = self.A + 2
+        self.FULL_PAD = int(p["FULL_PAD"])
+        self.EMPTY_PAD = int(p["EMPTY_PAD"])
+        self.opcode = [int(x) for x in np.asarray(tables["opcode"])]
+        self.N2 = len(self.opcode)
+        self.in_idx = np.asarray(tables["in_idx"]).tolist()
+        self.out_idx = np.asarray(tables["out_idx"]).tolist()
+        self.prod = list(zip(np.asarray(tables["prod_node"]).tolist(),
+                             np.asarray(tables["prod_slot"]).tolist()))
+        self.cons = list(zip(np.asarray(tables["cons_node"]).tolist(),
+                             np.asarray(tables["cons_slot"]).tolist()))
+        self.const = [bool(x) for x in np.asarray(tables["const_mask"])]
+        self.in_arcs = [int(p["aidx"][a]) for a in p["input_arcs"]]
+        self.out_arcs = [int(p["aidx"][a]) for a in p["output_arcs"]]
+        self.n_in = max(len(self.in_arcs), 1)
+        self.n_out = max(len(self.out_arcs), 1)
+
+
+def _fire_node(sp, n, F, V):
+    """One node's firing rule on post-feed rows (the lane form of
+    :func:`_ready_and_z`): (ready, z thunk, consume[3], produce[2],
+    inputs_ready).  Rows are bool arrays or static Python bools."""
+    op = sp.opcode[n]
+    i0, i1, i2 = sp.in_idx[n]
+    o0, o1 = sp.out_idx[n]
+    inf = (F(i0), F(i1), F(i2))
+    oute = (_not(F(o0)), _not(F(o1)))
+    all_out = _and(*oute)
+    if op == int(Op.NDMERGE):
+        ready = _and(_or(inf[0], inf[1]), all_out)
+        z = lambda: _sel(inf[0], V(i0), V(i1))
+        consume = (_and(ready, inf[0]), _and(ready, _not(inf[0])), False)
+        return ready, z, consume, (ready, ready), _or(inf[0], inf[1])
+    if op == int(Op.DMERGE):
+        c3 = V(i2) != 0
+        ir = _and(inf[2], _msel(c3, inf[0], inf[1]))
+        ready = _and(ir, all_out)
+        z = lambda: _sel(c3, V(i0), V(i1))
+        consume = (_and(ready, c3), _and(ready, ~c3), ready)
+        return ready, z, consume, (ready, ready), ir
+    ir = _and(*inf)
+    if op == int(Op.BRANCH):
+        c2 = V(i1) != 0
+        ready = _and(inf[0], inf[1], _msel(c2, oute[0], oute[1]))
+        return (ready, lambda: V(i0), (ready,) * 3,
+                (_and(ready, c2), _and(ready, ~c2)), ir)
+    from repro.core.engine import _alu_op
+    ready = _and(ir, all_out)
+    z = lambda: _alu_op(Op(op), V(i0), V(i1), jnp.int32)
+    return ready, z, (ready,) * 3, (ready, ready), ir
+
+
+def _dyn_block_kernel(sp, n_cycles, profile, *refs):
+    """K dynamic engine cycles (feed -> fire -> drain) for one tile of
+    slots.  Refs in: window[n_in, K], feed_len[n_in], active, then the
+    state (full, val [A2], ptr [n_in], out_last, out_count [n_out]) and,
+    profiled, the §12 counters (nf, si, so [N2], ab, ahw [A2]); out: the
+    state, fired, last_prog and the counters.  Each cycle loads the
+    rows it reads, and stores every row it changes after all loads, so
+    every node fires against one snapshot.  Inactive slots keep their
+    input state and report 0 fired / 0 last_prog."""
+    n_st = 10 if profile else 5
+    win, fl, active = refs[:3]
+    st_in = refs[3:3 + n_st]
+    outs = refs[3 + n_st:]
+    full, val, ptr, ol, oc, fired, lastp = outs[:7]
+    prof = outs[7:]
+    st_out = (full, val, ptr, ol, oc, *prof)
+    for r_in, r_out in zip(st_in, st_out):
+        r_out[...] = r_in[...]
+    shape = active.shape
+    N = sp.N2 - 1                       # the last node row is the dummy
+    fed = set(sp.in_arcs)
+
+    def cycle(c, carry):
+        nfired, lp = carry
+        fc, vc = {}, {}
+
+        def F(a):
+            if a == sp.FULL_PAD or (a < sp.A and sp.const[a]):
+                return True
+            if a == sp.EMPTY_PAD:
+                return False
+            if a not in fc:
+                fc[a] = full[a] != 0
+            return fc[a]
+
+        def V(a):
+            if a not in vc:
+                vc[a] = val[a]
+            return vc[a]
+
+        # 1. strobe the input buses
+        prog = False
+        for r, a in enumerate(sp.in_arcs):
+            p_r = ptr[r]
+            can = _and(_not(F(a)), p_r < fl[r])
+            nxt = pick([win[r, j] for j in range(n_cycles)],
+                       p_r - st_in[2][r])
+            vc[a] = _sel(can, nxt, V(a))
+            fc[a] = _or(F(a), can)
+            ptr[r] = p_r + _i32(can, shape)
+            prog = _or(prog, can)
+        # 2. fire every ready node against the post-feed snapshot
+        rules = [_fire_node(sp, n, F, V) for n in range(sp.N2)]
+        zs = {}
+        nf_new, nv_new = {}, {}
+        for a in range(sp.A):
+            if sp.const[a]:
+                continue
+            cn, cs = sp.cons[a]
+            pn, ps = sp.prod[a]
+            consumed = rules[cn][2][cs] if cn < N else False
+            produced = rules[pn][3][ps] if pn < N else False
+            nf_new[a] = _or(_and(F(a), _not(consumed)), produced)
+            if produced is not False:
+                if pn not in zs:
+                    zs[pn] = rules[pn][1]()
+                nv_new[a] = _sel(produced, zs[pn], V(a))
+            elif a in fed:
+                nv_new[a] = V(a)
+        n_now = None
+        for rdy, *_ in rules:
+            if rdy is not False:
+                n_now = _i32(rdy, shape) if n_now is None \
+                    else n_now + _i32(rdy, shape)
+        if n_now is not None:
+            nfired = nfired + n_now
+            prog = _or(prog, n_now > 0)
+        if profile:
+            nf_r, si_r, so_r, ab_r, ahw_r = prof
+            for n, (rdy, _, _, _, ir) in enumerate(rules):
+                if rdy is not False:
+                    nf_r[n] = nf_r[n] + _i32(rdy, shape)
+                if ir is not True:
+                    si_r[n] = si_r[n] + _i32(_not(ir), shape)
+                stall = _and(ir, _not(rdy))
+                if stall is not False:
+                    so_r[n] = so_r[n] + _i32(stall, shape)
+            for a in range(sp.A2):
+                occ = nf_new.get(a, F(a))
+                if occ is not False:
+                    occ = _i32(occ, shape)
+                    ab_r[a] = ab_r[a] + occ
+                    ahw_r[a] = jnp.maximum(ahw_r[a], occ)
+        # 3. the environment drains the output buses
+        for r, a in enumerate(sp.out_arcs):
+            got = nf_new.get(a, F(a))
+            if got is False:
+                continue
+            ol[r] = _sel(got, nv_new.get(a, V(a)), ol[r])
+            oc[r] = oc[r] + _i32(got, shape)
+            nf_new[a] = False
+            prog = _or(prog, got)
+        for a, x in nf_new.items():
+            full[a] = _i32(x, shape)
+        for a, x in nv_new.items():
+            val[a] = x
+        if prog is not False:
+            lp = _sel(prog, jnp.broadcast_to(c + 1, shape), lp)
+        return nfired, lp
+
+    zero = jnp.zeros(shape, jnp.int32)
+    nfired, lp = jax.lax.fori_loop(0, n_cycles, cycle, (zero, zero))
+    act = active[...] != 0
+    for r_in, r_out in zip(st_in, st_out):
+        r_out[...] = jnp.where(act, r_out[...], r_in[...])
+    fired[...] = jnp.where(act, nfired, 0)
+    lastp[...] = jnp.where(act, lp, 0)
+
+
+def _dyn_block_call(sp, n_cycles, profile, batch):
+    """The pallas_call of :func:`_dyn_block_kernel` for ``batch`` slots
+    in lane layout (operands already [R, m, 128])."""
+    m, s = slot_tiles(batch)
+    grid = (m // s,)
+
+    def spec(*lead):
+        return pl.BlockSpec((*lead, s, LANES),
+                            lambda i, k=len(lead): (0,) * k + (i, 0))
+
+    st_rows = [sp.A2, sp.A2, sp.n_in, sp.n_out, sp.n_out]
+    if profile:
+        st_rows += [sp.N2] * 3 + [sp.A2] * 2
+    in_specs = [spec(sp.n_in, n_cycles), spec(sp.n_in), spec()] \
+        + [spec(r) for r in st_rows]
+    out_rows = st_rows[:5] + [None, None] + st_rows[5:]
+    out_specs = [spec() if r is None else spec(r) for r in out_rows]
+    out_shape = [jax.ShapeDtypeStruct(
+        (m, LANES) if r is None else (r, m, LANES), jnp.int32)
+        for r in out_rows]
+    rows = sp.n_in * (n_cycles + 1) + 1 + 2 * sum(st_rows) + 2
+    return pl.pallas_call(
+        functools.partial(_dyn_block_kernel, sp, n_cycles, profile),
+        grid=grid, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret_mode(),
+        compiler_params=None if interpret_mode()
+        else compiler_params(rows, s),
+        name="dataflow_fire_block")
+
+
+def fire_block_batched_pallas(sp: FabricSpec, feed_vals, feed_len, full,
+                              val, ptr, out_last, out_count, *,
+                              n_cycles: int, active=None, prof=None):
+    """Batched block step: B independent streams through one fabric in a
+    single dispatch.  All state/feed arrays carry a leading batch axis;
+    the node/arc tables (``sp``) are baked into the kernel.  ``active``
+    (int32[B], default all-ones) is the per-stream clock gate: slots
+    with active == 0 keep their state and report fired/last_prog = 0, so
+    a serving layer can park quiesced slots without a global barrier.
+    Returns (full', val', ptr', out_last', out_count', fired[B, 1],
+    last_prog[B, 1]).  prof: optional 5-tuple of per-stream §12 counter
+    arrays ([B, N2] / [B, A2] int32), accumulated in-kernel per active
+    stream and returned after last_prog.
+
+    The kernel runs on the lane layout (:func:`to_lanes`): the caller's
+    [B, ...] arrays are transposed in and out around the call."""
+    B = full.shape[0]
+    m, _ = slot_tiles(B)
+    if active is None:
+        active = jnp.ones((B,), jnp.int32)
+    state = [full, val, ptr, out_last, out_count, *(prof or ())]
+    win = feed_window(feed_vals, ptr, n_cycles)
+    args = [to_lanes(win, m), to_lanes(feed_len, m),
+            to_lanes(active, m)] + [to_lanes(x, m) for x in state]
+    res = _dyn_block_call(sp, n_cycles, prof is not None, B)(*args)
+    out = [from_lanes(x, B) for x in res]
+    out[5] = out[5][:, None]
+    out[6] = out[6][:, None]
+    return tuple(out)
+
+
+def fire_block_pallas(sp: FabricSpec, feed_vals, feed_len, full, val, ptr,
+                      out_last, out_count, *, n_cycles: int, prof=None):
+    """K fused engine cycles (environment included) for one stream: the
+    batched kernel with one live slot.
+
     feed_vals[n_in, L] int32, feed_len[n_in] int32.
     State: full/val[A2], ptr[n_in], out_last/out_count[n_out], int32.
     Returns (full', val', ptr', out_last', out_count', fired[1],
     last_prog[1]).  prof: optional 5-tuple of §12 counter arrays
     (nf/si/so[N2], ab/ahw[A2] int32) — accumulated in-kernel and
     returned after last_prog."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    tabs = [jnp.asarray(tables[k]) for k in _TABLE_KEYS]
-    state = [full, val, ptr, out_last, out_count]
-    out_sd = ([jax.ShapeDtypeStruct(x.shape, jnp.int32) for x in state]
-              + [jax.ShapeDtypeStruct((1,), jnp.int32)] * 2)
-    if prof is None:
-        return pl.pallas_call(
-            functools.partial(_block_kernel, n_cycles,
-                              tables.get("class_slices")),
-            in_specs=[_whole(x)
-                      for x in (*tabs, feed_vals, feed_len, *state)],
-            out_specs=[_whole(s) for s in out_sd],
-            out_shape=out_sd,
-            interpret=interpret,
-        )(*tabs, feed_vals, feed_len, *state)
-    prof = list(prof)
-    out_sd = out_sd + [jax.ShapeDtypeStruct(x.shape, jnp.int32)
-                       for x in prof]
-    return pl.pallas_call(
-        functools.partial(_block_kernel_prof, n_cycles,
-                          tables.get("class_slices")),
-        in_specs=[_whole(x)
-                  for x in (*tabs, feed_vals, feed_len, *state, *prof)],
-        out_specs=[_whole(s) for s in out_sd],
-        out_shape=out_sd,
-        interpret=interpret,
-    )(*tabs, feed_vals, feed_len, *state, *prof)
-
-
-def fire_block_batched_pallas(tables, feed_vals, feed_len, full, val, ptr,
-                              out_last, out_count, *, n_cycles: int,
-                              active=None, prof=None, interpret=None):
-    """Batched block step: grid=(B,) — B independent streams through one
-    fabric in a single dispatch.  All state/feed arrays carry a leading
-    batch axis; the node/arc tables are shared (broadcast) across the
-    grid.  ``active`` (int32[B], default all-ones) is the per-stream
-    clock gate: slots with active==0 skip the whole block (state frozen,
-    fired/last_prog = 0), so a serving layer can park quiesced slots
-    without a global barrier.  Returns the same tuple as
-    fire_block_pallas with a leading B axis (fired/last_prog: [B, 1]).
-    prof: optional 5-tuple of per-stream §12 counter arrays
-    ([B, N2] / [B, A2] int32), accumulated in-kernel per active stream
-    and returned after last_prog."""
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
-    B = full.shape[0]
-    if active is None:
-        active = jnp.ones((B,), jnp.int32)
-    tabs = [jnp.asarray(tables[k]) for k in _TABLE_KEYS]
-    state = [full, val, ptr, out_last, out_count]
-
-    def row(x):
-        nd = x.ndim
-        return pl.BlockSpec((1, *x.shape[1:]),
-                            lambda b, n=nd: (b,) + (0,) * (n - 1))
-
-    out_sd = ([jax.ShapeDtypeStruct(x.shape, jnp.int32) for x in state]
-              + [jax.ShapeDtypeStruct((B, 1), jnp.int32)] * 2)
-    if prof is None:
-        return pl.pallas_call(
-            functools.partial(_batched_block_kernel, n_cycles,
-                              tables.get("class_slices")),
-            grid=(B,),
-            in_specs=[_whole(x) for x in tabs]
-            + [row(x) for x in (feed_vals, feed_len, *state)]
-            + [pl.BlockSpec((1,), lambda b: (b,))],
-            out_specs=[row(s) for s in out_sd],
-            out_shape=out_sd,
-            interpret=interpret,
-        )(*tabs, feed_vals, feed_len, *state, active)
-    prof = list(prof)
-    out_sd = out_sd + [jax.ShapeDtypeStruct(x.shape, jnp.int32)
-                       for x in prof]
-    return pl.pallas_call(
-        functools.partial(_batched_block_kernel_prof, n_cycles,
-                          tables.get("class_slices")),
-        grid=(B,),
-        in_specs=[_whole(x) for x in tabs]
-        + [row(x) for x in (feed_vals, feed_len, *state)]
-        + [pl.BlockSpec((1,), lambda b: (b,))]
-        + [row(x) for x in prof],
-        out_specs=[row(s) for s in out_sd],
-        out_shape=out_sd,
-        interpret=interpret,
-    )(*tabs, feed_vals, feed_len, *state, active, *prof)
+    one = lambda x: x[None]
+    res = fire_block_batched_pallas(
+        sp, one(feed_vals), one(feed_len), one(full), one(val),
+        one(ptr), one(out_last), one(out_count), n_cycles=n_cycles,
+        prof=None if prof is None else tuple(map(one, prof)))
+    return tuple(x[0] for x in res)

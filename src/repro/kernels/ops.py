@@ -1,8 +1,8 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On TPU these call the compiled kernels; on CPU (this container) they run
-in interpret mode — same kernel body, Python-evaluated — so correctness
-is validated everywhere while the BlockSpec tiling targets real TPUs.
+On a TPU these call the kernels as Mosaic compiles them; under
+``JAX_PLATFORMS=cpu`` they run in interpret mode (same kernel body,
+evaluated as plain JAX), which is how the tests check them.
 """
 from __future__ import annotations
 
@@ -10,10 +10,9 @@ import functools
 
 import jax
 
-from repro.kernels.dataflow_fire import (block_plan_arrays,
+from repro.kernels.dataflow_fire import (FabricSpec, block_plan_arrays,
                                          fire_block_batched_pallas,
-                                         fire_block_pallas,
-                                         fire_step_pallas, plan_arrays)
+                                         fire_block_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
@@ -26,33 +25,6 @@ def flash_attention(q, k, v, *, causal=True, bq=128, bk=128):
 @functools.partial(jax.jit, static_argnames=("eps", "rows_blk"))
 def rmsnorm(x, w, eps=1e-5, rows_blk=256):
     return rmsnorm_pallas(x, w, eps=eps, rows_blk=rows_blk)
-
-
-_STATIC_TABLE_KEYS = ("plan", "class_slices")   # never device arrays
-
-
-def _device_tables(tables):
-    """jnp copies of the array tables; static entries pass through."""
-    import jax.numpy as jnp
-    jt = {k: jnp.asarray(v) for k, v in tables.items()
-          if k not in _STATIC_TABLE_KEYS}
-    for k in _STATIC_TABLE_KEYS:
-        if k in tables:
-            jt[k] = tables[k]
-    return jt
-
-
-def make_fire_step(graph):
-    """Compile the dataflow fire-step kernel for a fabric; returns
-    (tables, jitted fn(full, val) -> (full', val', fired))."""
-    tables = plan_arrays(graph)
-    jt = _device_tables(tables)
-
-    @jax.jit
-    def step(full, val):
-        return fire_step_pallas(jt, full, val)
-
-    return tables, step
 
 
 def make_block_step(graph, n_cycles: int, batched: bool = False,
@@ -78,7 +50,7 @@ def make_block_step(graph, n_cycles: int, batched: bool = False,
     profiling adds zero extra dispatches."""
     if tables is None:
         tables = block_plan_arrays(graph, optimize=optimize)
-    jt = _device_tables(tables)
+    spec = FabricSpec(tables)
 
     if batched:
         if profile:
@@ -86,7 +58,7 @@ def make_block_step(graph, n_cycles: int, batched: bool = False,
             def step(feed_vals, feed_len, full, val, ptr, out_last,
                      out_count, active, nf, si, so, ab, ahw):
                 return fire_block_batched_pallas(
-                    jt, feed_vals, feed_len, full, val, ptr, out_last,
+                    spec, feed_vals, feed_len, full, val, ptr, out_last,
                     out_count, n_cycles=n_cycles, active=active,
                     prof=(nf, si, so, ab, ahw))
         else:
@@ -94,77 +66,20 @@ def make_block_step(graph, n_cycles: int, batched: bool = False,
             def step(feed_vals, feed_len, full, val, ptr, out_last,
                      out_count, active):
                 return fire_block_batched_pallas(
-                    jt, feed_vals, feed_len, full, val, ptr, out_last,
+                    spec, feed_vals, feed_len, full, val, ptr, out_last,
                     out_count, n_cycles=n_cycles, active=active)
     elif profile:
         @jax.jit
         def step(feed_vals, feed_len, full, val, ptr, out_last, out_count,
                  nf, si, so, ab, ahw):
             return fire_block_pallas(
-                jt, feed_vals, feed_len, full, val, ptr, out_last,
+                spec, feed_vals, feed_len, full, val, ptr, out_last,
                 out_count, n_cycles=n_cycles, prof=(nf, si, so, ab, ahw))
     else:
         @jax.jit
         def step(feed_vals, feed_len, full, val, ptr, out_last, out_count):
             return fire_block_pallas(
-                jt, feed_vals, feed_len, full, val, ptr, out_last,
+                spec, feed_vals, feed_len, full, val, ptr, out_last,
                 out_count, n_cycles=n_cycles)
 
     return tables, step
-
-
-def run_fabric(graph, feeds, dtype=None, max_cycles: int = 10_000,
-               compiled=None):
-    """Drive a fabric to completion using the per-cycle Pallas fire-step
-    kernel, with the environment (feed/drain) handled host-side: ONE
-    device dispatch per engine cycle.  This is the seed baseline the
-    fused block engine (DataflowEngine backend="pallas") is benchmarked
-    against.  Pass compiled=(tables, step) from make_fire_step to reuse
-    a compilation across calls.  Returns an EngineResult mirroring
-    repro.core.engine semantics (dispatches = cycles)."""
-    import numpy as np
-    from repro.core.engine import EngineResult
-
-    tables, step = compiled if compiled is not None \
-        else make_fire_step(graph)
-    p = tables["plan"]
-    A2 = p["A"] + 2
-    full = np.zeros((A2,), np.int32)
-    val = np.zeros((A2,), np.int32)
-    full[p["FULL_PAD"]] = 1
-    for a, v in graph.consts.items():
-        full[p["aidx"][a]] = 1
-        val[p["aidx"][a]] = int(v)
-    feeds = {a: np.asarray(v, np.int32).reshape(-1)
-             for a, v in (feeds or {}).items()}
-    ptr = {a: 0 for a in p["input_arcs"]}
-    out_last = {a: np.int32(0) for a in p["output_arcs"]}
-    out_count = {a: 0 for a in p["output_arcs"]}
-    cycles = fired = 0
-    progress = True
-    while progress and cycles < max_cycles:
-        progress = False
-        for a in p["input_arcs"]:
-            i = p["aidx"][a]
-            if not full[i] and a in feeds and ptr[a] < len(feeds[a]):
-                val[i] = feeds[a][ptr[a]]
-                full[i] = 1
-                ptr[a] += 1
-                progress = True
-        nf, nv, nfired = step(full, val)
-        full, val = np.asarray(nf).copy(), np.asarray(nv).copy()
-        full[p["EMPTY_PAD"]] = 0
-        full[p["FULL_PAD"]] = 1
-        k = int(nfired[0])
-        fired += k
-        progress = progress or k > 0
-        for a in p["output_arcs"]:
-            i = p["aidx"][a]
-            if full[i]:
-                out_last[a] = val[i]
-                out_count[a] += 1
-                full[i] = 0
-                progress = True
-        cycles += 1
-    return EngineResult(outputs=out_last, counts=out_count, cycles=cycles,
-                        fired=fired, dispatches=cycles)

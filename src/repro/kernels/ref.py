@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import naive_attention
-from repro.kernels.dataflow_fire import _TABLE_KEYS, _block_body, _fire_body
+from repro.kernels.dataflow_fire import _TABLE_KEYS, _block_body
 
 
 def flash_attention_ref(q, k, v, *, causal=True):
@@ -17,16 +17,6 @@ def rmsnorm_ref(x, w, eps=1e-5):
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps) *
             w.astype(jnp.float32)).astype(x.dtype)
-
-
-def fire_step_ref(tables, full, val):
-    """Same math as the kernel body, plain jnp (no pallas_call)."""
-    return _fire_body(
-        jnp.asarray(tables["opcode"]), jnp.asarray(tables["in_idx"]),
-        jnp.asarray(tables["out_idx"]), jnp.asarray(tables["prod_node"]),
-        jnp.asarray(tables["prod_slot"]), jnp.asarray(tables["cons_node"]),
-        jnp.asarray(tables["cons_slot"]), jnp.asarray(tables["const_mask"]),
-        full, val)
 
 
 def fire_block_ref(tables, feed_vals, feed_len, full, val, ptr, out_last,

@@ -361,6 +361,25 @@ def test_lowering_errors_name_the_primitive():
         trace(lambda x, y: x + y, I32, I32, const_args={7: 3})
 
 
+def test_nested_jit_call_is_inlined():
+    """A nested ``jax.jit`` traces to a ``jit`` call primitive; the
+    frontend inlines its body like any other call."""
+    inner = jax.jit(lambda a, b: jnp.maximum(a - b, 0) * 2)
+
+    def f(x, y):
+        return inner(x, y) + jax.jit(lambda z: z + 1)(y)
+
+    prog = trace(f, I32, I32)
+    xs, ys = _i32([7, 1, 9, -3], [2, 5, 9, 4])
+    feeds = prog.make_feeds(xs, ys)
+    want = np.maximum(xs - ys, 0) * 2 + ys + 1
+    r = run_reference(prog, feeds)
+    assert r.counts[prog.out_arc] == len(xs)
+    assert int(np.asarray(r.outputs[prog.out_arc])) == int(want[-1])
+    got = DataflowEngine(prog, backend="xla", block_cycles=4).run(feeds)
+    assert int(np.asarray(got.outputs[prog.out_arc])) == int(want[-1])
+
+
 def test_feed_adapter_contract():
     prog = trace(lambda x, y: x + y, I32, I32)
     with pytest.raises(ValueError, match="expected 2 argument streams"):

@@ -1,4 +1,4 @@
-"""Pallas kernels vs pure-jnp oracles (interpret mode on CPU).
+"""LM Pallas kernels vs pure-jnp oracles (interpret mode on CPU).
 
 Per assignment: shape/dtype sweeps with hypothesis, assert_allclose
 against ref.py.
@@ -11,9 +11,7 @@ import pytest
 pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
-from repro.core import library
-from repro.core.engine import run_reference
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 
@@ -91,47 +89,3 @@ def test_rmsnorm_3d_batch():
     np.testing.assert_allclose(np.asarray(rmsnorm_pallas(x, w)),
                                np.asarray(ref.rmsnorm_ref(x, w)),
                                rtol=1e-5, atol=1e-5)
-
-
-# ---------------------------------------------------------------------------
-# dataflow fire step: full benchmarks driven by the kernel
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("name,args", [
-    ("fibonacci", (11,)),
-    ("pop_count", (np.array([12345, 65535, 7]),)),
-    ("vector_sum", (np.arange(64).reshape(2, 32),)),
-    ("bubble_sort", (np.array([[5, 3, 8, 1, 9, 2, 7, 4]]),)),
-])
-def test_fire_kernel_runs_benchmarks(name, args):
-    bench = library.BENCHES[name]()
-    feeds = bench.make_feeds(*args)
-    got = ops.run_fabric(bench.graph, feeds)
-    want = run_reference(bench.graph, feeds)
-    assert got.cycles == want.cycles
-    assert got.fired == want.fired
-    for a in bench.graph.output_arcs():
-        assert got.counts[a] == want.counts[a], a
-        if want.counts[a]:
-            assert int(got.outputs[a]) == int(np.asarray(want.outputs[a]))
-
-
-def test_fire_body_matches_ref_random_states():
-    """Property: kernel fire == jnp ref on random arc states."""
-    bench = library.popcount_graph(8)
-    tables, step = ops.make_fire_step(bench.graph)
-    p = tables["plan"]
-    A2 = p["A"] + 2
-    rng = np.random.default_rng(0)
-    for trial in range(10):
-        full = rng.integers(0, 2, (A2,)).astype(np.int32)
-        full[p["FULL_PAD"]] = 1
-        full[p["EMPTY_PAD"]] = 0
-        full[tables["const_mask"] > 0] = 1
-        val = rng.integers(0, 1000, (A2,)).astype(np.int32)
-        nf1, nv1, f1 = step(full, val)
-        nf2, nv2, f2 = ref.fire_step_ref(tables, jnp.asarray(full),
-                                         jnp.asarray(val))
-        np.testing.assert_array_equal(np.asarray(nf1),
-                                      np.asarray(nf2).astype(np.int32))
-        np.testing.assert_array_equal(np.asarray(nv1), np.asarray(nv2))
-        assert int(f1[0]) == int(f2)

@@ -1,0 +1,141 @@
+"""The fabric's device steps compile for a TPU v5e chip.
+
+Nothing here runs: each test lowers a step at serving size for a
+described (not attached) ``v5e:2x2`` topology and asks the installed
+TPU compiler for the executable, so a kernel that interpret mode
+accepts but Mosaic refuses (unaligned blocks, 1-D gathers, i1 selects)
+fails here instead of on the chip.  The topology is described inside a
+fixture, never at import, so every test worker collects the same tests.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import library, passes
+from repro.core.engine import DataflowEngine, pack_feeds
+from repro.kernels import dataflow_fire, schedule_fire
+
+SLOTS = 2048
+K = 16
+FABRICS = ("fir", "bubble_sort")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    return topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent
+    cache but cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def chip(one_chip, no_persistent_cache, monkeypatch):
+    """ShapeDtypeStruct factory on the described chip; the kernels are
+    steered off interpret mode (this process's backend is the CPU)."""
+    for mod in (dataflow_fire, schedule_fire):
+        monkeypatch.setattr(mod, "interpret_mode", lambda: False)
+    return lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                               sharding=one_chip)
+
+
+def _compile(fn, *args):
+    return fn.lower(*args).compile().as_text()
+
+
+def _engine(name, **kw):
+    bench = library.BENCHES[name]()
+    return bench, DataflowEngine(bench.graph, block_cycles=K, **kw)
+
+
+def _state(sp, chip, *lead):
+    return [chip(*lead, sp.A2), chip(*lead, sp.A2), chip(*lead, sp.n_in),
+            chip(*lead, sp.n_out), chip(*lead, sp.n_out)]
+
+
+@pytest.mark.parametrize("name", FABRICS)
+@pytest.mark.parametrize("profile", (False, True))
+def test_batched_fire_kernel_compiles(name, profile, chip):
+    _, eng = _engine(name, backend="pallas", profile=profile)
+    sp = dataflow_fire.FabricSpec(eng._tables)
+    args = [chip(SLOTS, sp.n_in, 1024), chip(SLOTS, sp.n_in),
+            *_state(sp, chip, SLOTS), chip(SLOTS)]
+    if profile:
+        args += [chip(SLOTS, sp.N2)] * 3 + [chip(SLOTS, sp.A2)] * 2
+    assert "tpu_custom_call" in _compile(eng._pallas_step(K, True), *args)
+
+
+@pytest.mark.parametrize("name", FABRICS)
+def test_single_stream_fire_kernel_compiles(name, chip):
+    _, eng = _engine(name, backend="pallas")
+    sp = dataflow_fire.FabricSpec(eng._tables)
+    args = [chip(sp.n_in, 1024), chip(sp.n_in), *_state(sp, chip)]
+    assert "tpu_custom_call" in _compile(eng._pallas_step(K, False), *args)
+
+
+def _sched(name):
+    bench = library.BENCHES[name]()
+    g, _ = passes.optimize_graph(bench.graph)
+    eng = DataflowEngine(g, backend="pallas", block_cycles=K, schedule=True)
+    assert eng._sched_on
+    return bench, eng, eng._sched_ctx()
+
+
+@pytest.mark.parametrize("name", FABRICS)
+def test_scheduled_run_kernel_compiles(name, chip):
+    bench, eng, ctx = _sched(name)
+    feeds = library.random_feeds(name, bench, 1024,
+                                 np.random.default_rng(0))
+    fv, fl = pack_feeds(eng.p["input_arcs"], feeds)
+    plan = ctx.plan_for(tuple(int(x) for x in fl))
+    plan.ensure(eng.max_cycles)
+    struct, reps = plan.trace_struct(min(plan.total, eng.max_cycles))
+    for batched, lead in ((False, ()), (True, (256,))):
+        run = ctx.runner(struct, fv.shape[1], "pallas", batched)
+        hlo = _compile(run, chip(*lead, *fv.shape), chip(*reps.shape))
+        assert "tpu_custom_call" in hlo, batched
+
+
+@pytest.mark.parametrize("name", FABRICS)
+def test_scheduled_slot_kernel_compiles(name, chip):
+    _, _, ctx = _sched(name)
+    core = ctx.slot_step_fn(K, "pallas").core
+    n_in, n_out = ctx.ia_pad.size, ctx.oa_pad.size
+    bits = schedule_fire.pattern_bits(ctx)
+    t_full = ctx.slot_tables()[7]
+    hlo = _compile(core, chip(*bits.shape), chip(*t_full.shape),
+                   chip(SLOTS, n_in, 1024), chip(SLOTS, K), chip(SLOTS),
+                   chip(SLOTS, ctx.A2), chip(SLOTS, ctx.A2),
+                   chip(SLOTS, n_in), chip(SLOTS, n_out), chip(SLOTS, n_out))
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("name", FABRICS)
+def test_xla_slot_step_compiles(name, chip):
+    _, eng = _engine(name, backend="xla")
+    sp = dataflow_fire.FabricSpec(eng._block_tables())
+    hlo = _compile(eng._slot_step(K), chip(SLOTS, sp.n_in, 1024),
+                   chip(SLOTS, sp.n_in), *_state(sp, chip, SLOTS),
+                   chip(SLOTS))
+    assert "tpu_custom_call" not in hlo
