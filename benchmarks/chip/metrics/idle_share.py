@@ -1,0 +1,9 @@
+"""Device: share of the traced window in which no operation ran on the
+chip, from the profiler trace, in %."""
+
+
+def read(run):
+    t = run.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
