@@ -456,6 +456,22 @@ def pack_feeds(input_arcs, feeds, token_shape=(), dtype=np.int32,
 BACKENDS = ("xla", "pallas", "reference")
 
 
+def _spans_closed_on_error(method):
+    """A slot API method that raises closes the spans it opened on its
+    caller's ``obs`` probe; without one it is called as it is."""
+    @functools.wraps(method)
+    def call(self, *args, obs=None, **kwargs):
+        if obs is None:
+            return method(self, *args, **kwargs)
+        mark = obs.mark()
+        try:
+            return method(self, *args, obs=obs, **kwargs)
+        except BaseException:
+            obs.unwind(mark)
+            raise
+    return call
+
+
 class DataflowEngine:
     """Cycle-accurate executor for a static dataflow :class:`Graph`.
 
@@ -796,16 +812,23 @@ class DataflowEngine:
         if step is None:
             from repro.kernels import ref as _kref
             tables = self._block_tables()
-            fn = functools.partial(
+            vstep = jax.vmap(functools.partial(
                 _kref.fire_block_masked_prof_ref if self.profile
                 else _kref.fire_block_masked_ref,
-                tables, n_cycles=n_cycles)
-            step = jax.jit(jax.vmap(fn))
+                tables, n_cycles=n_cycles))
+
+            def dataflow_slot_step_xla(*args):   # the module's name
+                return vstep(*args)
+            step = jax.jit(dataflow_slot_step_xla)
             self._slot_steps[n_cycles] = step
         return step
 
+    def _step_cache_size(self) -> int:
+        return len(self._slot_steps) + len(getattr(self, "_steps", ()))
+
+    @_spans_closed_on_error
     def reset_slots(self, state: SlotState, slot_ids,
-                    new_feeds, caps=None) -> SlotState:
+                    new_feeds, caps=None, obs=None) -> SlotState:
         """Admit one request per slot id: fresh arc registers + the new
         feed stream, in one fused dispatch for the whole round.  Slots
         must be free (never-used or harvested); everything else keeps
@@ -819,7 +842,11 @@ class DataflowEngine:
         MOVE semantics: the input state's device buffers are donated to
         the fused reset dispatch, so ``state`` (and any older SlotState
         sharing its buffers) must not be used again on backends that
-        honor donation — always continue from the returned state."""
+        honor donation — always continue from the returned state.
+
+        obs: the calling server's :class:`repro.obs.Probe` (or None): on
+        the dynamic path it times packing, the copies to the device and
+        the reset dispatch as spans, and counts the bytes copied."""
         self._check_slot_api()
         if self._part_on:
             return self._mf_ctx().slot_reset(state, slot_ids, new_feeds,
@@ -837,6 +864,8 @@ class DataflowEngine:
                              "requests (harvest before refilling)")
         p = self.p
         B = state.slots
+        if obs is not None:
+            sp = obs.begin("dataflow.admit.pack")
         packed = [pack_feeds(p["input_arcs"], f, (), np.int32, pad_rows=1)
                   for f in new_feeds]
         L = state.fv.shape[2]
@@ -846,6 +875,8 @@ class DataflowEngine:
             state = dataclasses.replace(
                 state, fv=jnp.pad(state.fv,
                                   ((0, 0), (0, 0), (0, L - state.fv.shape[2]))))
+            if obs is not None:
+                obs.count("retraces", what="feed_buffer")
         n_in = state.fv.shape[1]
         mask = np.zeros((B,), bool)
         fv_rows = np.zeros((B, n_in, L), np.int32)
@@ -855,17 +886,28 @@ class DataflowEngine:
             fv_rows[b, :, :fv.shape[1]] = fv
             fl_rows[b] = fl
         full0, val0 = self._state0_rows()
+        active = state.active.copy()
+        active[slot_ids] = 1
+        staged = (mask, fv_rows, fl_rows, full0, val0, active)
+        if obs is not None:
+            obs.end(sp)
+            nbytes = sum(x.nbytes for x in staged)
+            obs.count("h2d_bytes", nbytes, site="admit")
+            sp = obs.begin("dataflow.admit.h2d", bytes=nbytes)
+        mask_d, fv_d, fl_d, full0_d, val0_d, active_d = (
+            jnp.asarray(x) for x in staged)
+        if obs is not None:
+            obs.end(sp)
+            sp = obs.begin("dataflow.admit.dispatch")
         fv_, fl_, full, val, ptr, out_last, out_count = _slot_reset(
             state.fv, state.fl, state.full, state.val, state.ptr,
-            state.out_last, state.out_count, jnp.asarray(mask),
-            jnp.asarray(fv_rows), jnp.asarray(fl_rows),
-            jnp.asarray(full0), jnp.asarray(val0))
+            state.out_last, state.out_count, mask_d, fv_d, fl_d, full0_d,
+            val0_d)
         if caps is None:
             caps = [None] * len(slot_ids)
         if len(caps) != len(slot_ids):
             raise ValueError(f"{len(slot_ids)} slot ids but "
                              f"{len(caps)} caps")
-        active = state.active.copy()
         for host in (base := state.base.copy(), last := state.last.copy(),
                      fired := state.fired.copy(),
                      disp := state.dispatches.copy(),
@@ -877,11 +919,10 @@ class DataflowEngine:
                 raise ValueError(f"slot {b}: cap must be >= 1, got {c}")
             cap[b] = self.max_cycles if c is None else int(c)
         quiesced = state.quiesced.copy()
-        active[slot_ids] = 1
         quiesced[slot_ids] = False
         prof, prof_cycles = state.prof, state.prof_cycles
         if self.profile and prof is not None:
-            prof = _prof_reset(prof, jnp.asarray(mask))
+            prof = _prof_reset(prof, mask_d)
         if self.profile:
             prof_cycles = prof_cycles.copy()
             prof_cycles[slot_ids] = 0
@@ -894,20 +935,28 @@ class DataflowEngine:
             for b, (_, fl) in zip(slot_ids, packed):
                 flen = tuple(int(x) for x in fl[:n_real])
                 sched.reset(b, ctx.plan_for(flen))
+        if obs is not None:
+            obs.end(sp)
         return SlotState(fv_, fl_, full, val, ptr, out_last, out_count,
                          active, base, last, fired, quiesced, disp,
                          cap=cap, stalled=stalled,
-                         active_dev=jnp.asarray(active),
+                         active_dev=active_d,
                          prof=prof, prof_cycles=prof_cycles,
                          sched=sched)
 
+    @_spans_closed_on_error
     def step_block(self, state: SlotState,
-                   n_cycles: int | None = None) -> SlotState:
+                   n_cycles: int | None = None, obs=None) -> SlotState:
         """Advance every active slot by ``n_cycles`` (default
         ``block_cycles``) fabric cycles in ONE device dispatch; free
         slots are clock-gated out.  Per-slot clocks (base/last/fired)
         advance on the host; a slot whose block had an idle tail is
-        marked ``quiesced`` (idle is absorbing — the request is done)."""
+        marked ``quiesced`` (idle is absorbing — the request is done).
+
+        obs: the calling server's :class:`repro.obs.Probe` (or None): on
+        the dynamic path it splits enqueueing the step from waiting for
+        its readback, and counts slot-cycles, readback bytes and new
+        jitted steps."""
         self._check_slot_api()
         nb = self.block_cycles if n_cycles is None else int(n_cycles)
         if nb < 1:
@@ -919,6 +968,9 @@ class DataflowEngine:
         if self._sched_on:
             from repro.core import schedule as _sched
             return _sched.step_block_sched(self, state, nb)
+        if obs is not None:
+            sp = obs.begin("dataflow.step.dispatch")
+            n_steps = self._step_cache_size()
         step = self._slot_step(nb)
         active_dev = state.active_dev if state.active_dev is not None \
             else jnp.asarray(state.active)
@@ -932,7 +984,17 @@ class DataflowEngine:
                                state.ptr, state.out_last, state.out_count,
                                active_dev)
             prof = state.prof
+        if obs is not None:
+            if self._step_cache_size() > n_steps:
+                obs.count("retraces", what="step")
+            obs.end(sp)
+            obs.count("d2h_bytes", f.nbytes + lp.nbytes, site="step")
+            obs.count("slot_cycles", state.slots * nb)
+            obs.count("active_slot_cycles", int(state.active.sum()) * nb)
+            sp = obs.begin("dataflow.step.wait")
         f, lp = jax.device_get((f, lp))      # one host sync per block
+        if obs is not None:
+            obs.end(sp)
         f = np.asarray(f).reshape(state.slots)
         lp = np.asarray(lp).reshape(state.slots)
         fired = state.fired + f
@@ -958,13 +1020,18 @@ class DataflowEngine:
                          prof=prof, prof_cycles=prof_cycles,
                          sched=state.sched)
 
-    def harvest(self, state: SlotState, slot_ids
+    @_spans_closed_on_error
+    def harvest(self, state: SlotState, slot_ids, obs=None
                 ) -> tuple[SlotState, list[EngineResult]]:
         """Extract the resident requests' EngineResults from the given
         (active) slots and free them.  Results follow the same
         accounting as run(): cycles = last progress cycle + 1 trailing
         idle cycle, capped at the slot's cycle cap (per-request if the
-        admission set one); dispatches = blocks the request rode."""
+        admission set one); dispatches = blocks the request rode.
+
+        obs: the calling server's :class:`repro.obs.Probe` (or None): on
+        the dynamic path it splits the readback from building results,
+        and counts the bytes read back."""
         self._check_slot_api()
         if self._part_on:
             return self._mf_ctx().slot_harvest(state, slot_ids)
@@ -972,10 +1039,18 @@ class DataflowEngine:
         idle = [b for b in slot_ids if not state.active[b]]
         if idle:
             raise ValueError(f"slots {idle} are free — nothing to harvest")
+        read_prof = self.profile and state.prof is not None
+        if obs is not None:
+            nbytes = state.out_last.nbytes + state.out_count.nbytes + (
+                sum(x.nbytes for x in state.prof) if read_prof else 0)
+            obs.count("d2h_bytes", nbytes, site="harvest")
+            sp = obs.begin("dataflow.harvest.d2h", bytes=nbytes)
         out_last, out_count = jax.device_get((state.out_last,
                                               state.out_count))
-        prof = jax.device_get(state.prof) if self.profile \
-            and state.prof is not None else None
+        prof = jax.device_get(state.prof) if read_prof else None
+        if obs is not None:
+            obs.end(sp)
+            sp = obs.begin("dataflow.harvest.results")
 
         def _prof_row(b):
             # scheduled engines accrue §12 counters on the host from the
@@ -998,6 +1073,8 @@ class DataflowEngine:
         quiesced = state.quiesced.copy()
         active[slot_ids] = 0
         quiesced[slot_ids] = False
+        if obs is not None:
+            obs.end(sp)
         return dataclasses.replace(state, active=active, quiesced=quiesced,
                                    active_dev=jnp.asarray(active)), results
 

@@ -52,22 +52,24 @@ def make_block_step(graph, n_cycles: int, batched: bool = False,
         tables = block_plan_arrays(graph, optimize=optimize)
     spec = FabricSpec(tables)
 
+    # the batched step is the server's slot step: its jitted module is
+    # named ``jit_dataflow_slot_step`` in the profiler's trace
     if batched:
         if profile:
-            @jax.jit
-            def step(feed_vals, feed_len, full, val, ptr, out_last,
-                     out_count, active, nf, si, so, ab, ahw):
+            def dataflow_slot_step(feed_vals, feed_len, full, val, ptr,
+                                   out_last, out_count, active,
+                                   nf, si, so, ab, ahw):
                 return fire_block_batched_pallas(
                     spec, feed_vals, feed_len, full, val, ptr, out_last,
                     out_count, n_cycles=n_cycles, active=active,
                     prof=(nf, si, so, ab, ahw))
         else:
-            @jax.jit
-            def step(feed_vals, feed_len, full, val, ptr, out_last,
-                     out_count, active):
+            def dataflow_slot_step(feed_vals, feed_len, full, val, ptr,
+                                   out_last, out_count, active):
                 return fire_block_batched_pallas(
                     spec, feed_vals, feed_len, full, val, ptr, out_last,
                     out_count, n_cycles=n_cycles, active=active)
+        step = jax.jit(dataflow_slot_step)
     elif profile:
         @jax.jit
         def step(feed_vals, feed_len, full, val, ptr, out_last, out_count,

@@ -10,12 +10,15 @@ Three layers, from device to host:
   trace-event JSON loadable in Perfetto.
 - ``metrics``  : :class:`MetricsRegistry`, process-local counters /
   gauges / histograms with a JSON snapshot.
+- ``probe``    : :class:`Probe`, one server's recorder and registry as
+  the serving hot path sees them (spans and counters per heartbeat).
 
 Nothing in this package imports jax: the engine hands over plain numpy
 arrays, so obs stays importable from any host-side tool.
 """
 from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                validate_snapshot)
+from repro.obs.probe import Probe
 from repro.obs.profile import FabricProfile
 from repro.obs.trace import (
     TraceInvariantError,
@@ -30,6 +33,7 @@ __all__ = [
     "Gauge",
     "Histogram",
     "MetricsRegistry",
+    "Probe",
     "TraceInvariantError",
     "TraceRecorder",
     "load_chrome",

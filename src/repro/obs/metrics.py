@@ -4,7 +4,9 @@ Deliberately tiny and dependency-free (no jax, no threads): the server
 and benchmark drivers update metrics from their host loops, and
 ``snapshot()`` renders everything to a JSON-safe dict.  Metrics are
 keyed by ``(name, sorted labels)`` -- requesting the same name+labels
-twice returns the same instrument, so call sites never cache handles.
+twice returns the same instrument, found again through a memo of the
+call's own arguments, so call sites never cache handles and a hot
+path builds no key string.
 """
 from __future__ import annotations
 
@@ -92,13 +94,24 @@ class MetricsRegistry:
         self._counters: dict[str, Counter] = {}
         self._gauges: dict[str, Gauge] = {}
         self._histograms: dict[str, Histogram] = {}
+        self._memo: dict[tuple, object] = {}   # (kind, name, *labels)
 
     # ----------------------------------------------------------- instruments
     def counter(self, name: str, **labels) -> Counter:
-        return self._counters.setdefault(_key(name, labels), Counter())
+        m = ("c", name, *labels.items())
+        c = self._memo.get(m)
+        if c is None:
+            c = self._memo[m] = self._counters.setdefault(
+                _key(name, labels), Counter())
+        return c
 
     def gauge(self, name: str, **labels) -> Gauge:
-        return self._gauges.setdefault(_key(name, labels), Gauge())
+        m = ("g", name, *labels.items())
+        g = self._memo.get(m)
+        if g is None:
+            g = self._memo[m] = self._gauges.setdefault(
+                _key(name, labels), Gauge())
+        return g
 
     def histogram(self, name: str, buckets=DEFAULT_BUCKETS, **labels) -> Histogram:
         return self._histograms.setdefault(_key(name, labels), Histogram(buckets))

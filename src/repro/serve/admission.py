@@ -93,9 +93,14 @@ class FairQueue:
             yield from q
 
     def depths(self) -> dict:
-        """Per-tenant queued-request counts (observability export —
-        feeds the server's ``queue_depth{tenant=...}`` gauges)."""
+        """Per-tenant queued-request counts (observability export)."""
         return {t: len(q) for t, q in self._buckets.items() if q}
+
+    def depth(self, tenant) -> int:
+        """Queued requests of one tenant (the server's
+        ``queue_depth{tenant=...}`` gauge)."""
+        q = self._buckets.get(tenant)
+        return len(q) if q else 0
 
     def _bucket(self, tenant) -> collections.deque:
         q = self._buckets.get(tenant)

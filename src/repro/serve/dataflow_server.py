@@ -88,6 +88,7 @@ from repro.core.engine import (BACKENDS, PLAN_CACHE_STATS, DataflowEngine,
                                run_reference)
 from repro.core.partition import resolve_partition
 from repro.core.graph import Graph
+from repro.obs.probe import Probe
 from repro.serve.admission import (POLICIES, DroppedError, FairQueue,
                                    QueueFullError, Rejected)
 from repro.serve.types import (InvalidRequestError, Request,
@@ -248,11 +249,18 @@ class DataflowServer:
         # fabric counters into every slot step, so each harvested
         # Result carries result.engine.profile (a FabricProfile);
         # trace/metrics accept a repro.obs TraceRecorder /
-        # MetricsRegistry (or None: zero recording overhead).
+        # MetricsRegistry (or None: zero recording overhead).  Either one
+        # turns on the hot path's spans and counters through a Probe
+        # bound to this server alone: the engine may be shared.
         self.profile = bool(profile)
         self.trace = trace
         self.metrics = metrics
-        self._gauged_tenants: set[str] = set()
+        self._obs = None if trace is None and metrics is None \
+            else Probe(trace, metrics)
+        if trace is not None and trace.annotate is None:
+            # spans land in the profiler's trace beside the device ops
+            from jax.profiler import TraceAnnotation
+            trace.annotate = TraceAnnotation
         if faults is not None and trace is not None \
                 and getattr(faults, "notify", None) is None:
             # injected faults land on the trace timeline next to the
@@ -358,15 +366,16 @@ class DataflowServer:
         if self.metrics is not None:
             self.metrics.counter(name, **labels).inc(n)
 
-    def _update_queue_metrics(self) -> None:
+    def _update_queue_metrics(self, tenants) -> None:
+        """Set the total queue-depth gauge and the gauges of
+        ``tenants`` (an iterable, left unread without a registry): the
+        tenants whose depth just changed."""
         if self.metrics is None:
             return
         self.metrics.gauge("queue_depth").set(len(self.queue))
-        depths = {str(t): d for t, d in self.queue.depths().items()}
-        self._gauged_tenants |= set(depths)
-        for t in self._gauged_tenants:
-            self.metrics.gauge("queue_depth", tenant=t).set(
-                depths.get(t, 0))
+        for t in tenants:
+            self.metrics.gauge("queue_depth", tenant=str(t)).set(
+                self.queue.depth(t))
 
     def _observe_result(self, res: Result) -> Result:
         """Per-request terminal accounting — every Result passes
@@ -481,6 +490,7 @@ class DataflowServer:
                 f"request {request.uid}: missing feeds for input arcs "
                 f"{missing} — every input arc needs a stream")
         # bounded admission (DESIGN.md §11)
+        changed = {request.tenant}
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             if self.policy == "reject":
                 self._trace("reject", uid=request.uid,
@@ -494,6 +504,7 @@ class DataflowServer:
                                 tenant=request.tenant)
             if self.policy == "drop-oldest":
                 victim = self.queue.drop_oldest()
+                changed.add(victim.tenant)
                 queued = self._queued_at.pop(victim.uid)
                 self._retries.pop(victim.uid, None)
                 self._log_event("drop-oldest", uid=victim.uid,
@@ -531,7 +542,7 @@ class DataflowServer:
         self._trace("submit", uid=request.uid, tenant=request.tenant,
                     queue_depth=len(self.queue))
         self._count("requests_submitted", tenant=str(request.tenant))
-        self._update_queue_metrics()
+        self._update_queue_metrics(changed)
         return request.uid
 
     def _queue_only_metrics(self, queued: int,
@@ -548,22 +559,29 @@ class DataflowServer:
 
     def _admit(self) -> None:
         free = self.state.free_slots()
+        if not (free and self.queue):
+            return
+        obs = self._obs
+        if obs is not None:
+            sp = obs.begin("dataflow.admit")
         batch: list[tuple[int, Request]] = []
         while free and self.queue:
             batch.append((free.pop(0), self.queue.pop()))
-        if batch:
-            self.state = self.engine.reset_slots(
-                self.state, [b for b, _ in batch],
-                [r.feeds for _, r in batch],
-                caps=[r.max_cycles for _, r in batch])
-            self.admission_rounds += 1
-            for b, r in batch:
-                self._resident[b] = (r, self.block)
-                self._trace("admit", uid=r.uid, slot=b, tenant=r.tenant,
-                            queue_wait_blocks=self.block
-                            - self._queued_at[r.uid])
-                self._count("requests_admitted", tenant=str(r.tenant))
-            self._update_queue_metrics()
+        self.state = self.engine.reset_slots(
+            self.state, [b for b, _ in batch],
+            [r.feeds for _, r in batch],
+            caps=[r.max_cycles for _, r in batch], obs=obs)
+        self.admission_rounds += 1
+        for b, r in batch:
+            self._resident[b] = (r, self.block)
+            self._trace("admit", uid=r.uid, slot=b, tenant=r.tenant,
+                        queue_wait_blocks=self.block
+                        - self._queued_at[r.uid])
+            self._count("requests_admitted", tenant=str(r.tenant))
+        self._update_queue_metrics(r.tenant for _, r in batch)
+        if obs is not None:
+            obs.admitted += len(batch)
+            obs.end(sp, rows=len(batch))
 
     # -- heartbeat ------------------------------------------------------
     def step(self) -> list[Result]:
@@ -577,9 +595,24 @@ class DataflowServer:
         nears its cap (block partitioning does not change cycle
         semantics — property-tested across K), so even a truncated
         request simulates exactly its cap, bit-identical to a solo
-        ``run`` under the same cap."""
+        ``run`` under the same cap.
+
+        With a trace recorder the heartbeat is the span
+        ``dataflow.heartbeat``; its self time is the scheduler's own
+        (DESIGN.md §12)."""
         done, self._done = self._done, []
-        return done + self._step_inner()
+        obs = self._obs
+        if obs is None:
+            return done + self._step_inner()
+        sp = obs.begin("dataflow.heartbeat", block=self.block)
+        obs.admitted = obs.active = 0
+        out = []
+        try:
+            out = done + self._step_inner()
+        finally:
+            obs.end(sp, active=obs.active, admitted=obs.admitted,
+                    finished=len(out))
+        return out
 
     def _step_inner(self) -> list[Result]:
         results = self._expire_queued()
@@ -609,11 +642,19 @@ class DataflowServer:
             self.engine.block_cycles,
             min(int(self.state.cap[b]) - int(self.state.base[b])
                 for b in self._resident))
+        obs = self._obs
+        if obs is not None:
+            obs.active = len(self._resident)
+            sp = obs.begin("dataflow.step", n_cycles=n_cycles)
         try:
             self.state = self._dispatch_block(n_cycles)
         except Exception as e:      # retries exhausted: degrade, requeue
+            if obs is not None:
+                obs.end(sp)
             self._degrade(e)
             return results
+        if obs is not None:
+            obs.end(sp)
         self.block += 1
         self._count("dispatches", backend=self.engine.backend)
         # 4. harvest quiesced slots; a fault-wedged request's quiescence
@@ -657,7 +698,7 @@ class DataflowServer:
                 uid=r.uid,
                 metrics=self._queue_only_metrics(queued, expired=True))))
         if expired:
-            self._update_queue_metrics()
+            self._update_queue_metrics(r.tenant for r in expired)
         return results
 
     def _dispatch_block(self, n_cycles: int):
@@ -673,7 +714,8 @@ class DataflowServer:
                     if err is not None:
                         raise err
                 return self.engine.step_block(self.state,
-                                              n_cycles=n_cycles)
+                                              n_cycles=n_cycles,
+                                              obs=self._obs)
             except Exception as e:
                 attempt += 1
                 if attempt > self.max_retries:
@@ -712,7 +754,7 @@ class DataflowServer:
             # the requeue closes the victim's slot span on the trace
             self._trace("requeue", uid=req.uid, slot=b,
                         tenant=req.tenant, from_backend=failed)
-        self._update_queue_metrics()
+        self._update_queue_metrics(r.tenant for r in victims)
         chain = self._chain_from(failed)
         for be in chain[1:] if chain[0] == failed else chain:
             if be == "reference":
@@ -746,11 +788,12 @@ class DataflowServer:
                         error=repr(err) if err else None)
 
     def _step_reference(self) -> list[Result]:
-        results = []
+        results, tenants = [], set()
         for _ in range(self.slots):
             if not self.queue:
                 break
             req = self.queue.pop()
+            tenants.add(req.tenant)
             queued = self._queued_at.pop(req.uid)
             cap = req.max_cycles or self.max_cycles
             er, err = None, None
@@ -787,14 +830,20 @@ class DataflowServer:
             results.append(self._observe_result(res))
         if results:
             self.block += 1
-            self._update_queue_metrics()
+            self._update_queue_metrics(tenants)
         return results
 
     def _harvest_slots(self, done: list[int],
                        kind: str = "ok") -> list[Result]:
         if not done:
             return []
-        self.state, engine_results = self.engine.harvest(self.state, done)
+        obs = self._obs
+        if obs is not None:
+            sp = obs.begin("dataflow.harvest", rows=len(done), kind=kind)
+        self.state, engine_results = self.engine.harvest(self.state, done,
+                                                         obs=obs)
+        if obs is not None:
+            sp_results = obs.begin("dataflow.harvest.results")
         results = []
         for b, er in zip(done, engine_results):
             req, admitted = self._resident.pop(b)
@@ -824,6 +873,9 @@ class DataflowServer:
                         fired=er.fired, tokens_out=res.metrics.tokens_out,
                         backend=self.engine.backend)
             results.append(self._observe_result(res))
+        if obs is not None:
+            obs.end(sp_results)
+            obs.end(sp)
         return results
 
     def drain(self) -> list[Result]:
