@@ -37,6 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph, Op
+from repro.kernels.feed_stage import place_feeds
 
 _MAX_IN = 3
 _MAX_OUT = 2
@@ -399,19 +400,74 @@ class SlotState:
                 if self.active[b] and self.quiesced[b]]
 
 
+def feed_capacity(n_in: int, L: int) -> int:
+    """Tokens one admission dispatch stages: the streams of eight slots
+    at the feed buffer's full length, rounded up to a power of two.  It
+    changes only when the feed buffer grows; a round that admits more
+    tokens is split over several dispatches of this one shape."""
+    return 1 << (8 * n_in * L - 1).bit_length()
+
+
+def _dispatch_groups(tokens, capacity: int):
+    """[a, b) ranges of consecutive admitted slots whose ``tokens`` fit
+    ``capacity`` each: one range unless the round overflows."""
+    ends = np.cumsum(tokens)
+    out, a, base = [], 0, 0
+    while a < len(ends):
+        b = int(np.searchsorted(ends, base + capacity, side="right"))
+        out.append((a, b))
+        a, base = b, int(ends[b - 1])
+    return out
+
+
+_NO_TOKENS = np.zeros((0,), np.int32)     # an input arc a request leaves out
+
+
+def _staged_size(B: int, n_in: int, L: int) -> int:
+    """int32 words of one dispatch's staged buffer (see _slot_reset)."""
+    return feed_capacity(n_in, L) + B * (n_in + 3)
+
+
+def _stage(B: int, L: int, active, slots, lens, streams) -> np.ndarray:
+    """One dispatch's host buffer (layout: :func:`_slot_reset`): the
+    ascending ``slots``' ``streams`` (``lens[len(slots), n_in]``)."""
+    n_in = lens.shape[1]
+    C = feed_capacity(n_in, L)
+    buf = np.zeros((_staged_size(B, n_in, L),), np.int32)
+    if streams:
+        np.concatenate(streams, out=buf[:int(lens.sum())], casting="unsafe")
+    buf[C:C + B * n_in].reshape(B, n_in)[slots] = lens
+    mask, order, act = buf[C + B * n_in:].reshape(3, B)
+    mask[slots] = 1
+    order[:len(slots)] = slots
+    act[:] = active
+    return buf
+
+
 @functools.partial(jax.jit, donate_argnums=(0, 1, 2, 3, 4, 5, 6))
-def _slot_reset(fv, fl, full, val, ptr, out_last, out_count, mask,
-                fv_rows, fl_rows, full0, val0):
+def _slot_reset(fv, fl, full, val, ptr, out_last, out_count, staged,
+                full0, val0):
     """Reset the masked slots to fresh initial state + new feed streams
-    in ONE fused dispatch (an admission round, not one call per slot)."""
+    in ONE fused dispatch (an admission round, not one call per slot).
+
+    staged: the dispatch's one host buffer, int32 words laid out as the
+    admitted streams back to back (``feed_capacity`` words, zero
+    padded) | fl[B, n_in] | mask[B] | order[B] (admitted slots,
+    ascending, first) | active[B].  Returns the reset state, the mask
+    and active as device arrays."""
+    B, n_in, _ = fv.shape
+    C = staged.shape[0] - B * (n_in + 3)
+    fl_rows = staged[C:C + B * n_in].reshape(B, n_in)
+    mask, order, active = staged[C + B * n_in:].reshape(3, B)
+    mask = mask > 0
     m1 = mask[:, None]
-    return (jnp.where(mask[:, None, None], fv_rows, fv),
+    return (place_feeds(fv, staged[:C], mask, order, fl_rows),
             jnp.where(m1, fl_rows, fl),
             jnp.where(m1, full0[None], full),
             jnp.where(m1, val0[None], val),
             jnp.where(m1, 0, ptr),
             jnp.where(m1, 0, out_last),
-            jnp.where(m1, 0, out_count))
+            jnp.where(m1, 0, out_count), mask, active)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -577,6 +633,7 @@ class DataflowEngine:
             self._sched_on = False
         self.p = _plan(graph, optimize=self.optimize)
         self._slot_steps: dict[int, object] = {}
+        self._state0_dev = None     # (full0, val0) on the device, once
         self._tables = None
         if self._part_on:
             pass    # multifabric builds its own per-region tables lazily
@@ -834,6 +891,13 @@ class DataflowEngine:
         must be free (never-used or harvested); everything else keeps
         its state untouched.
 
+        Ragged staging: only the admitted tokens travel, packed back to
+        back into one host buffer of ``feed_capacity`` words per
+        dispatch, with the per-slot lengths beside them; the device
+        writes the admitted rows of the feed buffer from it
+        (``repro.kernels.feed_stage``).  A round with more tokens than
+        the capacity is split over several dispatches of the same shape.
+
         caps: optional per-admission cycle caps (one entry per slot id;
         ``None`` entries fall back to the engine's ``max_cycles``) — a
         request-level budget the scheduler enforces by shortening
@@ -846,7 +910,8 @@ class DataflowEngine:
 
         obs: the calling server's :class:`repro.obs.Probe` (or None): on
         the dynamic path it times packing, the copies to the device and
-        the reset dispatch as spans, and counts the bytes copied."""
+        the reset dispatch as spans, and counts the bytes copied and the
+        extra dispatches an overflowing round took (``admit_splits``)."""
         self._check_slot_api()
         if self._part_on:
             return self._mf_ctx().slot_reset(state, slot_ids, new_feeds,
@@ -862,67 +927,66 @@ class DataflowEngine:
         if busy:
             raise ValueError(f"slots {busy} still hold unharvested "
                              "requests (harvest before refilling)")
-        p = self.p
-        B = state.slots
-        if obs is not None:
-            sp = obs.begin("dataflow.admit.pack")
-        packed = [pack_feeds(p["input_arcs"], f, (), np.int32, pad_rows=1)
-                  for f in new_feeds]
-        L = state.fv.shape[2]
-        need = max((fv.shape[1] for fv, _ in packed), default=1)
-        if need > L:        # grow the stream buffer (pow2 bounds retraces)
-            L = 1 << (int(need) - 1).bit_length()
-            state = dataclasses.replace(
-                state, fv=jnp.pad(state.fv,
-                                  ((0, 0), (0, 0), (0, L - state.fv.shape[2]))))
-            if obs is not None:
-                obs.count("retraces", what="feed_buffer")
-        n_in = state.fv.shape[1]
-        mask = np.zeros((B,), bool)
-        fv_rows = np.zeros((B, n_in, L), np.int32)
-        fl_rows = np.zeros((B, n_in), np.int32)
-        for b, (fv, fl) in zip(slot_ids, packed):
-            mask[b] = True
-            fv_rows[b, :, :fv.shape[1]] = fv
-            fl_rows[b] = fl
-        full0, val0 = self._state0_rows()
-        active = state.active.copy()
-        active[slot_ids] = 1
-        staged = (mask, fv_rows, fl_rows, full0, val0, active)
-        if obs is not None:
-            obs.end(sp)
-            nbytes = sum(x.nbytes for x in staged)
-            obs.count("h2d_bytes", nbytes, site="admit")
-            sp = obs.begin("dataflow.admit.h2d", bytes=nbytes)
-        mask_d, fv_d, fl_d, full0_d, val0_d, active_d = (
-            jnp.asarray(x) for x in staged)
-        if obs is not None:
-            obs.end(sp)
-            sp = obs.begin("dataflow.admit.dispatch")
-        fv_, fl_, full, val, ptr, out_last, out_count = _slot_reset(
-            state.fv, state.fl, state.full, state.val, state.ptr,
-            state.out_last, state.out_count, mask_d, fv_d, fl_d, full0_d,
-            val0_d)
         if caps is None:
             caps = [None] * len(slot_ids)
         if len(caps) != len(slot_ids):
             raise ValueError(f"{len(slot_ids)} slot ids but "
                              f"{len(caps)} caps")
-        for host in (base := state.base.copy(), last := state.last.copy(),
-                     fired := state.fired.copy(),
-                     disp := state.dispatches.copy(),
-                     stalled := state.stalled.copy()):
-            host[slot_ids] = 0
         cap = state.cap.copy()
         for b, c in zip(slot_ids, caps):
             if c is not None and int(c) < 1:
                 raise ValueError(f"slot {b}: cap must be >= 1, got {c}")
             cap[b] = self.max_cycles if c is None else int(c)
+        p = self.p
+        B = state.slots
+        if obs is not None:
+            sp = obs.begin("dataflow.admit.pack")
+        rows = sorted(range(len(slot_ids)), key=slot_ids.__getitem__)
+        slots = [slot_ids[i] for i in rows]
+        streams, lens = self._admitted_streams([new_feeds[i] for i in rows])
+        n_in = lens.shape[1]
+        L = state.fv.shape[2]
+        need = int(lens.max(initial=1))
+        if need > L:        # grow the stream buffer (pow2 bounds retraces)
+            L = 1 << (need - 1).bit_length()
+            grow = ((0, 0), (0, 0), (0, L - state.fv.shape[2]))
+            state = dataclasses.replace(state, fv=jnp.pad(state.fv, grow))
+            if obs is not None:
+                obs.count("retraces", what="feed_buffer")
+        active = state.active.copy()
+        active[slot_ids] = 1
+        staged = [_stage(B, L, active, slots[a:b], lens[a:b],
+                         streams[a * n_in:b * n_in])
+                  for a, b in _dispatch_groups(lens.sum(axis=1),
+                                               feed_capacity(n_in, L))]
+        if self._state0_dev is None:
+            self._state0_dev = tuple(jnp.asarray(x)
+                                     for x in self._state0_rows())
+        if obs is not None:
+            obs.end(sp)
+            nbytes = sum(x.nbytes for x in staged)
+            obs.count("h2d_bytes", nbytes, site="admit")
+            if len(staged) > 1:
+                obs.count("admit_splits", len(staged) - 1)
+            sp = obs.begin("dataflow.admit.h2d", bytes=nbytes)
+        staged = [jnp.asarray(x) for x in staged]
+        if obs is not None:
+            obs.end(sp)
+            sp = obs.begin("dataflow.admit.dispatch")
+        dev = (state.fv, state.fl, state.full, state.val, state.ptr,
+               state.out_last, state.out_count)
+        prof, prof_cycles = state.prof, state.prof_cycles
+        for x in staged:
+            *dev, mask_d, active_d = _slot_reset(*dev, x, *self._state0_dev)
+            if self.profile and prof is not None:
+                prof = _prof_reset(prof, mask_d)
+        for host in (base := state.base.copy(), last := state.last.copy(),
+                     fired := state.fired.copy(),
+                     disp := state.dispatches.copy(),
+                     stalled := state.stalled.copy()):
+            host[slot_ids] = 0
         quiesced = state.quiesced.copy()
         quiesced[slot_ids] = False
-        prof, prof_cycles = state.prof, state.prof_cycles
-        if self.profile and prof is not None:
-            prof = _prof_reset(prof, mask_d)
         if self.profile:
             prof_cycles = prof_cycles.copy()
             prof_cycles[slot_ids] = 0
@@ -932,17 +996,35 @@ class DataflowEngine:
                 sched = self._make_slot_sched(B)
             ctx = self._sched_ctx()
             n_real = len(p["input_arcs"])
-            for b, (_, fl) in zip(slot_ids, packed):
-                flen = tuple(int(x) for x in fl[:n_real])
-                sched.reset(b, ctx.plan_for(flen))
+            for b, fl in zip(slots, lens):
+                sched.reset(b, ctx.plan_for(tuple(int(x)
+                                                  for x in fl[:n_real])))
         if obs is not None:
             obs.end(sp)
-        return SlotState(fv_, fl_, full, val, ptr, out_last, out_count,
-                         active, base, last, fired, quiesced, disp,
+        return SlotState(*dev, active, base, last, fired, quiesced, disp,
                          cap=cap, stalled=stalled,
                          active_dev=active_d,
                          prof=prof, prof_cycles=prof_cycles,
                          sched=sched)
+
+    def _admitted_streams(self, feeds_list):
+        """(streams, lens[R, n_in]): every admitted request's input-arc
+        streams in plan order, a missing arc (and the pad row of a
+        fabric without inputs) as an empty stream, R requests in
+        order.  Raises like :func:`pack_feeds` on a non-input arc."""
+        arcs = self.p["input_arcs"]
+        known = set(arcs)
+        pad = [_NO_TOKENS] * (max(len(arcs), 1) - len(arcs))
+        streams = []
+        for feeds in feeds_list:
+            feeds = feeds or {}
+            if not known.issuperset(feeds):
+                raise ValueError("feeds for non-input arcs: "
+                                 f"{sorted(set(feeds) - known)}")
+            streams += [feeds.get(a, _NO_TOKENS) for a in arcs]
+            streams += pad
+        lens = np.fromiter(map(len, streams), np.int64, len(streams))
+        return streams, lens.reshape(len(feeds_list), -1)
 
     @_spans_closed_on_error
     def step_block(self, state: SlotState,

@@ -13,6 +13,8 @@ Counters the hot path keeps (DESIGN.md §12):
 
 - ``h2d_bytes{site=admit}``: bytes of the host arrays an admission round
   hands the device;
+- ``admit_splits``: extra reset dispatches of admission rounds whose
+  tokens overflow the staging capacity;
 - ``d2h_bytes{site=step|harvest}``: bytes read back from the device;
 - ``retraces{what=feed_buffer|step}``: growths of the feed buffer, new
   jitted slot steps;

@@ -25,7 +25,7 @@ import numpy as np
 import pytest
 
 from repro.core import library
-from repro.core.engine import DataflowEngine
+from repro.core.engine import DataflowEngine, feed_capacity
 from repro.obs import MetricsRegistry, Probe, TraceRecorder, validate_chrome
 from repro.obs import trace as trace_mod
 from repro.serve.dataflow_server import DataflowServer
@@ -182,13 +182,14 @@ def test_a_degraded_step_closes_its_span_before_the_requeue(monkeypatch):
 
 
 def test_h2d_bytes_are_the_staged_arrays():
-    """One admission round stages the slot mask, the feed streams and
-    their lengths of every slot, one fresh arc register row (full and
-    value) and the active mask: B + 4 B n_in L + 4 B n_in + 8 (A + 2)
-    + 4 B bytes, whatever the round admits."""
+    """One admission round stages one int32 buffer: the admitted
+    streams, back to back in ``feed_capacity(n_in, L)`` words, then the
+    slots' stream lengths, mask, order and active flags, 4 (n_in + 3) B
+    bytes.  Nothing scales with B n_in L, and the fresh arc registers
+    stay on the device from the first round on."""
     b = _bench()
     eng = DataflowEngine(b.graph, backend="xla", block_cycles=4)
-    B, n_in, A = 6, len(b.graph.input_arcs()), len(b.graph.arcs)
+    B, n_in = 6, len(b.graph.input_arcs())
     mr = MetricsRegistry()
     obs = Probe(metrics=mr)
     st = eng.init_state(B)
@@ -197,12 +198,18 @@ def test_h2d_bytes_are_the_staged_arrays():
     st = eng.reset_slots(st, [0, 4], feeds, obs=obs)
     L = st.fv.shape[2]
     assert L == 8                               # grown to a power of two
-    want = B + 4 * B * n_in * L + 4 * B * n_in + 8 * (A + 2) + 4 * B
+    want = 4 * feed_capacity(n_in, L) + 4 * (n_in + 3) * B
     c = mr.snapshot()["counters"]
     assert c["h2d_bytes{site=admit}"] == want
     assert c["retraces{what=feed_buffer}"] == 1
     st = eng.reset_slots(st, [1], feeds[:1], obs=obs)
     assert mr.snapshot()["counters"]["h2d_bytes{site=admit}"] == 2 * want
+    # ten times the slots add only their per-slot words
+    mr10 = MetricsRegistry()
+    eng.reset_slots(eng.init_state(10 * B), [0, 4], feeds,
+                    obs=Probe(metrics=mr10))
+    assert mr10.snapshot()["counters"]["h2d_bytes{site=admit}"] == \
+        want + 4 * (n_in + 3) * 9 * B
     st = eng.step_block(st, obs=obs)
     c = mr.snapshot()["counters"]
     assert c["slot_cycles"] == B * 4 and c["active_slot_cycles"] == 3 * 4
@@ -395,11 +402,11 @@ def test_slot_step_modules_carry_stable_names(backend, module):
     eng = DataflowEngine(_bench().graph, backend=backend, block_cycles=4)
     text = eng._slot_step(4).lower(*_slot_args(eng)).as_text()
     assert f"module @{module} " in text
-    from repro.core.engine import _slot_reset
+    from repro.core.engine import _slot_reset, _staged_size
     st = eng.init_state(8)
     B, n_in, A2 = 8, st.fv.shape[1], st.full.shape[1]
     reset = _slot_reset.lower(
         st.fv, st.fl, st.full, st.val, st.ptr, st.out_last, st.out_count,
-        jnp.zeros((B,), bool), st.fv, st.fl, jnp.zeros((A2,), jnp.int32),
-        jnp.zeros((A2,), jnp.int32)).as_text()
+        jnp.zeros((_staged_size(B, n_in, st.fv.shape[2]),), jnp.int32),
+        jnp.zeros((A2,), jnp.int32), jnp.zeros((A2,), jnp.int32)).as_text()
     assert "module @jit__slot_reset " in reset and n_in >= 1

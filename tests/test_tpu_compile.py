@@ -17,7 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import library, passes
 from repro.core.engine import DataflowEngine, pack_feeds
-from repro.kernels import dataflow_fire, schedule_fire
+from repro.kernels import dataflow_fire, feed_stage, schedule_fire
 
 SLOTS = 2048
 K = 16
@@ -54,7 +54,7 @@ def no_persistent_cache():
 def chip(one_chip, no_persistent_cache, monkeypatch):
     """ShapeDtypeStruct factory on the described chip; the kernels are
     steered off interpret mode (this process's backend is the CPU)."""
-    for mod in (dataflow_fire, schedule_fire):
+    for mod in (dataflow_fire, feed_stage, schedule_fire):
         monkeypatch.setattr(mod, "interpret_mode", lambda: False)
     return lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
                                                sharding=one_chip)
@@ -139,3 +139,18 @@ def test_xla_slot_step_compiles(name, chip):
                    chip(SLOTS, sp.n_in), *_state(sp, chip, SLOTS),
                    chip(SLOTS))
     assert "tpu_custom_call" not in hlo
+
+
+@pytest.mark.parametrize("slots,n_in,L", [
+    (512, 8, 1024), (256, 64, 256), (SLOTS, 8, 1024), (SLOTS, 8, 64)])
+def test_admission_reset_compiles(slots, n_in, L, chip):
+    """An admission round's reset at the chip benchmark's two cells and
+    at serving size: a lane-aligned feed buffer is filled by the Pallas
+    placement kernel, a shorter one by an XLA scatter."""
+    from repro.core.engine import _slot_reset, _staged_size
+    A2, n_out = 178, 8
+    hlo = _compile(_slot_reset, chip(slots, n_in, L), chip(slots, n_in),
+                   chip(slots, A2), chip(slots, A2), chip(slots, n_in),
+                   chip(slots, n_out), chip(slots, n_out),
+                   chip(_staged_size(slots, n_in, L)), chip(A2), chip(A2))
+    assert ("tpu_custom_call" in hlo) == (L % 128 == 0)
