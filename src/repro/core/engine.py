@@ -461,10 +461,16 @@ def _slot_reset(fv, fl, full, val, ptr, out_last, out_count, staged,
     mask, order, active = staged[C + B * n_in:].reshape(3, B)
     mask = mask > 0
     m1 = mask[:, None]
+
+    def fresh(x, x0):
+        if x.ndim == 2:                 # [B, A2], plan order
+            return jnp.where(m1, x0[None], x)
+        T, _, s, lanes = x.shape        # the row kernel's [T, R, s, 128]
+        mt = jnp.pad(mask, (0, T * s * lanes - B)).reshape(T, 1, s, lanes)
+        return jnp.where(mt, x0[None, :, None, None], x)
     return (place_feeds(fv, staged[:C], mask, order, fl_rows),
             jnp.where(m1, fl_rows, fl),
-            jnp.where(m1, full0[None], full),
-            jnp.where(m1, val0[None], val),
+            fresh(full, full0), fresh(val, val0),
             jnp.where(m1, 0, ptr),
             jnp.where(m1, 0, out_last),
             jnp.where(m1, 0, out_count), mask, active)
@@ -635,6 +641,7 @@ class DataflowEngine:
         self._slot_steps: dict[int, object] = {}
         self._state0_dev = None     # (full0, val0) on the device, once
         self._tables = None
+        self._spec = None           # the pallas kernels' FabricSpec
         if self._part_on:
             pass    # multifabric builds its own per-region tables lazily
         elif backend == "pallas":
@@ -666,6 +673,53 @@ class DataflowEngine:
             self._tables = block_plan_arrays(self.graph,
                                              optimize=self.optimize)
         return self._tables
+
+    def _fabric_spec(self):
+        """The pallas kernels' static view of the tables, built once."""
+        if self._spec is None:
+            from repro.kernels.dataflow_fire import FabricSpec
+            self._spec = FabricSpec(self._block_tables())
+        return self._spec
+
+    def kernel_choice(self, slots: int) -> str:
+        """The device kernel a slot step of ``slots`` slots runs and its
+        tiling: ``"rows TxS"`` (the row kernel over T slot tiles of S
+        sublanes), else the backend's own path."""
+        tiles = self._row_tiles(slots)
+        if tiles is not None:
+            return "rows {}x{}".format(*tiles)
+        if self._part_on:
+            return "partitioned"
+        return "scheduled" if self._sched_on else self.backend
+
+    def _row_tiles(self, slots: int):
+        """(T, s) where a slot step of ``slots`` slots runs the row kernel
+        (its register file is then kept in the kernel's tile layout,
+        ``SlotState.full``/``val`` [T, R, s, 128] in row order), else
+        None (plan order, [B, A2])."""
+        if self.backend != "pallas" or self._part_on or self._sched_on:
+            return None
+        return self._fabric_spec().rows.tiles(slots, self.block_cycles,
+                                              self.profile)
+
+    def slot_registers(self, state: SlotState):
+        """(full, val) [B, A2] of a slot state in the plan's arc order,
+        whatever layout the state keeps them in."""
+        if state.full.ndim == 2:
+            return state.full, state.val
+        from repro.kernels.dataflow_fire import LANES, from_tiles
+        lay = self._fabric_spec().rows
+        return tuple(from_tiles(x.reshape(x.shape[0], -1, LANES),
+                                state.slots, lay.R, lay.dst)
+                     for x in (state.full, state.val))
+
+    def _slot_rows0(self, tiled: bool):
+        """(full0, val0) of a fresh slot in the slot state's order."""
+        full0, val0 = self._state0_rows()
+        if tiled:
+            src = self._fabric_spec().rows.src
+            full0, val0 = full0[src], val0[src]
+        return full0, val0
 
     def _sched_ctx(self):
         """Lazy per-engine schedule state (DESIGN.md §13)."""
@@ -831,15 +885,21 @@ class DataflowEngine:
         B = int(slots)
         n_in = max(len(p["input_arcs"]), 1)
         n_out = max(len(p["output_arcs"]), 1)
-        full0, val0 = self._state0_rows()
+        tiles = self._row_tiles(B)
+        full0, val0 = self._slot_rows0(tiles is not None)
+        if tiles is None:
+            regs = lambda x0: jnp.asarray(
+                np.broadcast_to(x0, (B, x0.shape[0])).copy())
+        else:
+            T, sub = tiles
+            regs = lambda x0: jnp.broadcast_to(
+                jnp.asarray(x0)[None, :, None, None],
+                (T, x0.shape[0], sub, 128))
         z64 = lambda: np.zeros((B,), np.int64)
         return SlotState(
             fv=jnp.zeros((B, n_in, 1), jnp.int32),
             fl=jnp.zeros((B, n_in), jnp.int32),
-            full=jnp.asarray(np.broadcast_to(full0, (B, full0.shape[0]))
-                             .copy()),
-            val=jnp.asarray(np.broadcast_to(val0, (B, val0.shape[0]))
-                            .copy()),
+            full=regs(full0), val=regs(val0),
             ptr=jnp.zeros((B, n_in), jnp.int32),
             out_last=jnp.zeros((B, n_out), jnp.int32),
             out_count=jnp.zeros((B, n_out), jnp.int32),
@@ -879,6 +939,11 @@ class DataflowEngine:
             step = jax.jit(dataflow_slot_step_xla)
             self._slot_steps[n_cycles] = step
         return step
+
+    def _has_step(self, n_cycles: int) -> bool:
+        if self.backend == "pallas":
+            return (n_cycles, True) in self._steps
+        return n_cycles in self._slot_steps
 
     def _step_cache_size(self) -> int:
         return len(self._slot_steps) + len(getattr(self, "_steps", ()))
@@ -960,8 +1025,8 @@ class DataflowEngine:
                   for a, b in _dispatch_groups(lens.sum(axis=1),
                                                feed_capacity(n_in, L))]
         if self._state0_dev is None:
-            self._state0_dev = tuple(jnp.asarray(x)
-                                     for x in self._state0_rows())
+            self._state0_dev = tuple(jnp.asarray(x) for x in
+                                     self._slot_rows0(state.full.ndim == 4))
         if obs is not None:
             obs.end(sp)
             nbytes = sum(x.nbytes for x in staged)
@@ -1037,8 +1102,9 @@ class DataflowEngine:
 
         obs: the calling server's :class:`repro.obs.Probe` (or None): on
         the dynamic path it splits enqueueing the step from waiting for
-        its readback, and counts slot-cycles, readback bytes and new
-        jitted steps."""
+        its readback, spans a step's first compile for its shapes
+        (``dataflow.build``), and counts slot-cycles, node firings,
+        readback bytes and new jitted steps."""
         self._check_slot_api()
         nb = self.block_cycles if n_cycles is None else int(n_cycles)
         if nb < 1:
@@ -1050,9 +1116,15 @@ class DataflowEngine:
         if self._sched_on:
             from repro.core import schedule as _sched
             return _sched.step_block_sched(self, state, nb)
+        build = -1
         if obs is not None:
             sp = obs.begin("dataflow.step.dispatch")
             n_steps = self._step_cache_size()
+            if not self._has_step(nb):      # lowered and compiled now
+                build = obs.begin("dataflow.build",
+                                  nodes=len(self.graph.nodes),
+                                  arcs=self.p["A"],
+                                  kernel=self.kernel_choice(state.slots))
         step = self._slot_step(nb)
         active_dev = state.active_dev if state.active_dev is not None \
             else jnp.asarray(state.active)
@@ -1067,6 +1139,7 @@ class DataflowEngine:
                                active_dev)
             prof = state.prof
         if obs is not None:
+            obs.end(build)
             if self._step_cache_size() > n_steps:
                 obs.count("retraces", what="step")
             obs.end(sp)
@@ -1075,9 +1148,10 @@ class DataflowEngine:
             obs.count("active_slot_cycles", int(state.active.sum()) * nb)
             sp = obs.begin("dataflow.step.wait")
         f, lp = jax.device_get((f, lp))      # one host sync per block
+        f = np.asarray(f).reshape(state.slots)
         if obs is not None:
             obs.end(sp)
-        f = np.asarray(f).reshape(state.slots)
+            obs.count("node_firings", int(f.sum()))
         lp = np.asarray(lp).reshape(state.slots)
         fired = state.fired + f
         last = np.where(lp > 0, state.base + lp, state.last)
@@ -1172,7 +1246,7 @@ class DataflowEngine:
             from repro.kernels import ops as _kops
             _, step = _kops.make_block_step(
                 self.graph, n_cycles, batched=batched, tables=self._tables,
-                profile=self.profile)
+                profile=self.profile, spec=self._fabric_spec())
             self._steps[key] = step
         return step
 

@@ -24,6 +24,11 @@ toolchain step) instead of hand-assembled node tables.  Three of them
 regenerate hand-built benches above — property tests pin the traced
 fabric to the hand-built reference — and three are traced-only
 workloads.
+
+``array_multiplier`` is a gate-level netlist: ISCAS-85 c6288 (at 16
+bits) regenerated from its published structure, simulated bit-parallel
+(32 test patterns a token) and checked against a plain gate-list
+evaluator, :func:`gate_level_signature`.
 """
 from __future__ import annotations
 
@@ -589,6 +594,155 @@ def horner_loop_graph(degree: int = 8) -> Bench:
                  streaming=False)
 
 
+# ---------------------------------------------------------------------------
+# Gate-level netlists: ISCAS-85 c6288, the 16x16 array multiplier
+# ---------------------------------------------------------------------------
+# Bit-parallel simulation: a token is one int32 word per input bit and
+# carries that bit of 32 test patterns, so the fabric's AND/OR/XOR are
+# bitwise and the NOR gates of the published circuit are xor(or(a, b),
+# -1): the fabric's NOT is logical, not bitwise.
+ONES = -1
+
+
+def _gate_arcs(g: Graph, gates: list[tuple], prefix: str) -> None:
+    """Add a gate list as fabric nodes.  ``gates`` holds ``(kind, out,
+    a, b)`` with kind ``"and"`` or ``"nor"``; a net read by several gates
+    reaches them through a :func:`_fanout` copy tree, and every NOR is
+    ``or`` then ``xor`` with the all-ones bus ``ones``."""
+    uses: dict[str, int] = {}
+    for _, _, a, b in gates:
+        uses[a] = uses.get(a, 0) + 1
+        uses[b] = uses.get(b, 0) + 1
+    taps = {net: iter(_fanout(g, net, k, f"{prefix}f_{net}"))
+            for net, k in uses.items()}
+    for kind, out, a, b in gates:
+        x, y = next(taps[a]), next(taps[b])
+        if kind == "and":
+            g.add(Op.AND, [x, y], [out])
+        else:
+            g.add(Op.OR, [x, y], [f"{out}_or"])
+            g.add(Op.XOR, [f"{out}_or", "ones"], [out])
+
+
+def _half_adder(gates: list, a: str, b: str, s: str, c: str) -> None:
+    """Sum by five NORs (XNOR by four, then NOR of it with itself),
+    carry by one AND."""
+    gates += [("nor", f"{s}_1", a, b), ("nor", f"{s}_2", a, f"{s}_1"),
+              ("nor", f"{s}_3", b, f"{s}_1"),
+              ("nor", f"{s}_x", f"{s}_2", f"{s}_3"),
+              ("nor", s, f"{s}_x", f"{s}_x"), ("and", c, a, b)]
+
+
+def _full_adder(gates: list, a: str, b: str, cin: str, s: str,
+                c: str) -> None:
+    """The nine-NOR full adder: XNOR(a, b) by four NORs, XNOR of that
+    with the carry in by four more (the sum), and the carry out as NOR
+    of the first and fifth gates."""
+    gates += [("nor", f"{s}_1", a, b), ("nor", f"{s}_2", a, f"{s}_1"),
+              ("nor", f"{s}_3", b, f"{s}_1"),
+              ("nor", f"{s}_4", f"{s}_2", f"{s}_3"),
+              ("nor", f"{s}_5", f"{s}_4", cin),
+              ("nor", f"{s}_6", f"{s}_4", f"{s}_5"),
+              ("nor", f"{s}_7", cin, f"{s}_5"),
+              ("nor", s, f"{s}_6", f"{s}_7"), ("nor", c, f"{s}_1", f"{s}_5")]
+
+
+def multiplier_gates(bits: int) -> tuple[list, list[str]]:
+    """Gate list of a ``bits`` x ``bits`` unsigned array multiplier in
+    the structure of ISCAS-85 c6288 (Hansen, Yalcin & Hayes, IEEE D&T
+    16(3), 1999): ``bits``^2 AND gates form the partial products
+    ``pp_i_j = a_j & b_i``; a carry-save (Braun) array adds them, a first
+    row of half adders and ``bits`` - 2 rows of full adders; a final row,
+    one half adder and ripple-carry full adders, merges sums and carries.
+    That is ``bits`` half adders and ``bits`` (``bits`` - 2) full adders:
+    16 and 224 at 16 bits.  Returns the gates and the 2 ``bits`` product
+    nets, least significant first."""
+    n = bits
+    if n < 2:
+        raise ValueError(f"an array multiplier needs 2 or more bits, got {n}")
+    gates = [("and", f"pp_{i}_{j}", f"a{j}", f"b{i}")
+             for i in range(n) for j in range(n)]
+    prod = ["pp_0_0"]
+    s = [f"pp_0_{j}" for j in range(n)]          # row sums, column j
+    c: list[str] = []                            # row carries
+    for i in range(1, n):
+        ns, nc = [], []
+        for j in range(n - 1):
+            top = s[j + 1]
+            cell = f"r{i}c{j}"
+            if i == 1:
+                _half_adder(gates, top, f"pp_{i}_{j}", f"{cell}_s",
+                            f"{cell}_c")
+            else:
+                _full_adder(gates, top, f"pp_{i}_{j}", c[j], f"{cell}_s",
+                            f"{cell}_c")
+            ns.append(f"{cell}_s")
+            nc.append(f"{cell}_c")
+        s, c = ns + [f"pp_{i}_{n - 1}"], nc
+        prod.append(s[0])
+    carry = None                                 # the final adder row
+    for j in range(n - 1):
+        cell = f"fc{j}"
+        if carry is None:
+            _half_adder(gates, s[j + 1], c[j], f"{cell}_s", f"{cell}_c")
+        else:
+            _full_adder(gates, s[j + 1], c[j], carry, f"{cell}_s",
+                        f"{cell}_c")
+        prod.append(f"{cell}_s")
+        carry = f"{cell}_c"
+    return gates, prod + [carry]
+
+
+def array_multiplier_graph(bits: int = 4) -> Bench:
+    """ISCAS-85 c6288 (at ``bits`` = 16) regenerated as a fabric for
+    bit-parallel simulation (:func:`multiplier_gates`).  Inputs
+    ``a0..``, ``b0..``: bit j of each of 32 patterns per token.  Each of
+    the 2 ``bits`` product nets ``p_k`` is compacted on the fabric:
+    ``xor p_k, acc_k -> s_k; copy s_k -> acc_k, sig_k; init acc_k = 0``,
+    so output ``sig_k`` carries the XOR of the responses to every token
+    so far and its last value checks the whole stream.  The plain
+    reference is :func:`gate_level_signature`."""
+    gates, prod = multiplier_gates(bits)
+    g = Graph(name=f"array_multiplier_{bits}")
+    g.const("ones", ONES)
+    _gate_arcs(g, gates, "")
+    for k, net in enumerate(prod):
+        g.init(f"acc_{k}", 0)
+        g.add(Op.XOR, [net, f"acc_{k}"], [f"s_{k}"])
+        g.add(Op.COPY, [f"s_{k}"], [f"acc_{k}", f"sig_{k}"])
+    g.validate()
+    a_in = [f"a{j}" for j in range(bits)]
+    b_in = [f"b{j}" for j in range(bits)]
+
+    def make_feeds(ab):
+        """ab: [k, 2 bits] words, the a bits then the b bits."""
+        ab = np.atleast_2d(np.asarray(ab))
+        return {x: ab[:, i] for i, x in enumerate(a_in + b_in)}
+
+    def reference(ab):
+        return gate_level_signature(bits, make_feeds(ab))
+
+    return Bench(g, make_feeds, reference, f"sig_{2 * bits - 1}",
+                 out_arcs=[f"sig_{k}" for k in range(2 * bits)])
+
+
+def gate_level_signature(bits: int, feeds: dict) -> np.ndarray:
+    """The plain reference of :func:`array_multiplier_graph`: evaluate
+    the gate list in numpy over int32 words (bitwise AND and NOR), all
+    tokens at once, and XOR each product net's words.  Returns the
+    2 ``bits`` compacted words, least significant product bit first.
+    Independent of the dataflow machine."""
+    gates, prod = multiplier_gates(bits)
+    k = len(feeds["a0"])
+    net = {f"{x}{j}": np.asarray(feeds[f"{x}{j}"], np.int32)
+           for x in "ab" for j in range(bits)}
+    for kind, out, a, b in gates:
+        x = net[a] & net[b] if kind == "and" else ~(net[a] | net[b])
+        net[out] = x.astype(np.int32)
+    return np.array([np.bitwise_xor.reduce(net[p]) if k else 0
+                     for p in prod], np.int32)
+
+
 BENCHES: dict[str, Callable[[], Bench]] = {
     "fibonacci": fibonacci_graph,
     "vector_sum": vector_sum_graph,
@@ -609,6 +763,8 @@ BENCHES: dict[str, Callable[[], Bench]] = {
     "fib": fib_loop_graph,
     "newton_sqrt": newton_sqrt_graph,
     "horner_loop": horner_loop_graph,
+    # gate-level netlist (ISCAS-85 c6288 at bits=16; 4 keeps tests cheap)
+    "array_multiplier": array_multiplier_graph,
 }
 
 # single-shot fabrics: one initiation -> one result token, and `k` in

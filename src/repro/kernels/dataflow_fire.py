@@ -19,13 +19,14 @@ Two implementations of the same block:
   then each arc pulls its next state from its unique producer/consumer
   (the paper's one-sender/one-receiver rule).  The xla backend vmaps it
   over slots.
-* :func:`fire_block_batched_pallas` — the Pallas kernel on the TPU lane
-  layout (one row per arc, slots on lanes; see the section below), with
-  every node emitted as static code.  :func:`fire_block_pallas` is the
-  same kernel with one live slot.
+* :func:`fire_block_batched_pallas` — the Pallas kernel (the row
+  kernel, see its section below): the fabric as tables in SMEM, one
+  register row per arc reader, slots on sublanes and lanes.
+  :func:`fire_block_pallas` is the same kernel with one live slot.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -331,16 +332,11 @@ def _block_body(tab, feed_vals, feed_len, full, val, ptr, out_last,
 
 
 # ---------------------------------------------------------------------------
-# TPU lane layout shared by every Pallas kernel of the fabric
+# TPU lane layout shared by the Pallas kernels of the fabric
 # ---------------------------------------------------------------------------
-# A register file is stored row-major by arc, with the slots (independent
-# token streams) on the two minor axes: ``[A2, m, 128]`` int32, slot
-# ``b`` at ``[:, b // 128, b % 128]``.  Every node of the fabric is known
-# at trace time, so each one reads its operand rows with static leading
-# indices and computes one lane-dense vector op across all slots of a
-# tile — no gathers, no scatters.  A grid step covers ``s`` sublane rows
-# (s * 128 slots); ``s`` is 8 (one full vreg per row) once there are
-# more than 1024 slots.
+# Slots (independent token streams) sit on the two minor axes: an array
+# ``[R, m, 128]`` int32 holds slot ``b`` at ``[:, b // 128, b % 128]``,
+# and a grid step covers ``s`` sublane rows (s * 128 slots).
 LANES = 128
 _SUBLANES = 8
 
@@ -380,11 +376,17 @@ def feed_window(feed_vals, ptr, n_cycles: int):
     pointer (clamped like the reference gather).  A slot feeds at most
     one token per row per cycle, so a K-cycle block only ever reads
     ``window[..., ptr_now - ptr_block_start]``: the kernel selects it
-    lane-wise instead of gathering from the whole stream."""
+    lane-wise instead of gathering from the whole stream.
+
+    The window is a select and a sum over the stream axis, which XLA
+    fuses into one pass over the feed buffer: on a TPU a gather of the
+    same elements costs about 12 ns each (PERF.md §6)."""
     L = feed_vals.shape[2]
     idx = jnp.clip(ptr[:, :, None] + jnp.arange(n_cycles, dtype=ptr.dtype),
                    0, L - 1)
-    return jnp.take_along_axis(feed_vals, idx, axis=2)
+    hit = jnp.arange(L, dtype=idx.dtype)[:, None] == idx[:, :, None, :]
+    return jnp.where(hit, feed_vals[..., None], 0).sum(
+        axis=2, dtype=feed_vals.dtype)
 
 
 def pick(rows, d):
@@ -395,20 +397,29 @@ def pick(rows, d):
     return out
 
 
-def compiler_params(block_rows: int, sublanes: int):
-    """Mosaic parameters for a kernel whose in+out blocks hold
-    ``block_rows`` rows of ``sublanes`` x 128 int32: the slot-tile grid
-    is parallel, and the scoped VMEM limit covers the double-buffered
-    blocks (sublane rows pad to 8)."""
+VMEM_LIMIT = 100 << 20      # the most scoped VMEM a fire kernel asks for
+_VMEM_SLACK = 8 << 20       # headroom over a kernel's own blocks
+
+
+def vmem_params(need: int):
+    """Mosaic parameters for a kernel whose blocks and scratch take
+    ``need`` bytes of VMEM: the slot-tile grid is parallel, and the
+    scoped VMEM limit covers the blocks with headroom."""
     from jax.experimental.pallas import tpu as pltpu
-    need = 2 * block_rows * max(sublanes, _SUBLANES) * LANES * 4
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",),
-        vmem_limit_bytes=int(min(max(need + (8 << 20), 32 << 20),
-                                 100 << 20)))
+        vmem_limit_bytes=int(min(max(need + _VMEM_SLACK, 32 << 20),
+                                 VMEM_LIMIT)))
 
 
-# -- trace-time boolean folding: pad rows and const arcs are static ----
+def compiler_params(block_rows: int, sublanes: int):
+    """:func:`vmem_params` for a kernel whose in+out blocks hold
+    ``block_rows`` rows of ``sublanes`` x 128 int32, double-buffered
+    (sublane rows pad to 8)."""
+    return vmem_params(2 * block_rows * max(sublanes, _SUBLANES) * LANES * 4)
+
+
+# -- trace-time boolean folding: missing inputs and outputs are static --
 def _and(*xs):
     acc = True
     for x in xs:
@@ -453,8 +464,8 @@ def _i32(x, shape):
 
 
 class FabricSpec:
-    """The node/arc tables as Python ints: what the kernels bake in as
-    static row indices and per-node code."""
+    """The node/arc tables as Python ints, from which the row kernel's
+    layout (:attr:`rows`) is built."""
 
     def __init__(self, tables):
         import numpy as np
@@ -467,229 +478,48 @@ class FabricSpec:
         self.N2 = len(self.opcode)
         self.in_idx = np.asarray(tables["in_idx"]).tolist()
         self.out_idx = np.asarray(tables["out_idx"]).tolist()
-        self.prod = list(zip(np.asarray(tables["prod_node"]).tolist(),
-                             np.asarray(tables["prod_slot"]).tolist()))
-        self.cons = list(zip(np.asarray(tables["cons_node"]).tolist(),
-                             np.asarray(tables["cons_slot"]).tolist()))
         self.const = [bool(x) for x in np.asarray(tables["const_mask"])]
         self.in_arcs = [int(p["aidx"][a]) for a in p["input_arcs"]]
         self.out_arcs = [int(p["aidx"][a]) for a in p["output_arcs"]]
         self.n_in = max(len(self.in_arcs), 1)
         self.n_out = max(len(self.out_arcs), 1)
 
+    @functools.cached_property
+    def rows(self) -> "RowLayout":
+        """The fabric's tables for the row kernel."""
+        return RowLayout(self)
 
-def _fire_node(sp, n, F, V):
-    """One node's firing rule on post-feed rows (the lane form of
-    :func:`_ready_and_z`): (ready, z thunk, consume[3], produce[2],
-    inputs_ready).  Rows are bool arrays or static Python bools."""
-    op = sp.opcode[n]
-    i0, i1, i2 = sp.in_idx[n]
-    o0, o1 = sp.out_idx[n]
-    inf = (F(i0), F(i1), F(i2))
-    oute = (_not(F(o0)), _not(F(o1)))
+
+def _node_rule(op, inf, oute, v):
+    """The firing rule of one opcode over operand rows (the lane form of
+    :func:`_ready_and_z`): ``inf`` the three inputs' full flags, ``oute``
+    the two outputs' empty flags (bool arrays, or static Python bools
+    for the inputs and outputs an opcode lacks), ``v`` three thunks of
+    the input values.  Returns (ready, z thunk, consume[3], produce[2],
+    inputs_ready)."""
     all_out = _and(*oute)
     if op == int(Op.NDMERGE):
         ready = _and(_or(inf[0], inf[1]), all_out)
-        z = lambda: _sel(inf[0], V(i0), V(i1))
+        z = lambda: _sel(inf[0], v[0](), v[1]())
         consume = (_and(ready, inf[0]), _and(ready, _not(inf[0])), False)
         return ready, z, consume, (ready, ready), _or(inf[0], inf[1])
     if op == int(Op.DMERGE):
-        c3 = V(i2) != 0
+        c3 = v[2]() != 0
         ir = _and(inf[2], _msel(c3, inf[0], inf[1]))
         ready = _and(ir, all_out)
-        z = lambda: _sel(c3, V(i0), V(i1))
+        z = lambda: _sel(c3, v[0](), v[1]())
         consume = (_and(ready, c3), _and(ready, ~c3), ready)
         return ready, z, consume, (ready, ready), ir
     ir = _and(*inf)
     if op == int(Op.BRANCH):
-        c2 = V(i1) != 0
+        c2 = v[1]() != 0
         ready = _and(inf[0], inf[1], _msel(c2, oute[0], oute[1]))
-        return (ready, lambda: V(i0), (ready,) * 3,
+        return (ready, v[0], (ready,) * 3,
                 (_and(ready, c2), _and(ready, ~c2)), ir)
     from repro.core.engine import _alu_op
     ready = _and(ir, all_out)
-    z = lambda: _alu_op(Op(op), V(i0), V(i1), jnp.int32)
+    z = lambda: _alu_op(Op(op), v[0](), v[1](), jnp.int32)
     return ready, z, (ready,) * 3, (ready, ready), ir
-
-
-def _dyn_block_kernel(sp, n_cycles, profile, *refs):
-    """K dynamic engine cycles (feed -> fire -> drain) for one tile of
-    slots.  Refs in: window[n_in, K], feed_len[n_in], active, then the
-    state (full, val [A2], ptr [n_in], out_last, out_count [n_out]) and,
-    profiled, the §12 counters (nf, si, so [N2], ab, ahw [A2]); out: the
-    state, fired, last_prog and the counters.  Each cycle loads the
-    rows it reads, and stores every row it changes after all loads, so
-    every node fires against one snapshot.  Inactive slots keep their
-    input state and report 0 fired / 0 last_prog."""
-    n_st = 10 if profile else 5
-    win, fl, active = refs[:3]
-    st_in = refs[3:3 + n_st]
-    outs = refs[3 + n_st:]
-    full, val, ptr, ol, oc, fired, lastp = outs[:7]
-    prof = outs[7:]
-    st_out = (full, val, ptr, ol, oc, *prof)
-    for r_in, r_out in zip(st_in, st_out):
-        r_out[...] = r_in[...]
-    shape = active.shape
-    N = sp.N2 - 1                       # the last node row is the dummy
-    fed = set(sp.in_arcs)
-
-    def cycle(c, carry):
-        nfired, lp = carry
-        fc, vc = {}, {}
-
-        def F(a):
-            if a == sp.FULL_PAD or (a < sp.A and sp.const[a]):
-                return True
-            if a == sp.EMPTY_PAD:
-                return False
-            if a not in fc:
-                fc[a] = full[a] != 0
-            return fc[a]
-
-        def V(a):
-            if a not in vc:
-                vc[a] = val[a]
-            return vc[a]
-
-        # 1. strobe the input buses
-        prog = False
-        for r, a in enumerate(sp.in_arcs):
-            p_r = ptr[r]
-            can = _and(_not(F(a)), p_r < fl[r])
-            nxt = pick([win[r, j] for j in range(n_cycles)],
-                       p_r - st_in[2][r])
-            vc[a] = _sel(can, nxt, V(a))
-            fc[a] = _or(F(a), can)
-            ptr[r] = p_r + _i32(can, shape)
-            prog = _or(prog, can)
-        # 2. fire every ready node against the post-feed snapshot
-        rules = [_fire_node(sp, n, F, V) for n in range(sp.N2)]
-        zs = {}
-        nf_new, nv_new = {}, {}
-        for a in range(sp.A):
-            if sp.const[a]:
-                continue
-            cn, cs = sp.cons[a]
-            pn, ps = sp.prod[a]
-            consumed = rules[cn][2][cs] if cn < N else False
-            produced = rules[pn][3][ps] if pn < N else False
-            nf_new[a] = _or(_and(F(a), _not(consumed)), produced)
-            if produced is not False:
-                if pn not in zs:
-                    zs[pn] = rules[pn][1]()
-                nv_new[a] = _sel(produced, zs[pn], V(a))
-            elif a in fed:
-                nv_new[a] = V(a)
-        n_now = None
-        for rdy, *_ in rules:
-            if rdy is not False:
-                n_now = _i32(rdy, shape) if n_now is None \
-                    else n_now + _i32(rdy, shape)
-        if n_now is not None:
-            nfired = nfired + n_now
-            prog = _or(prog, n_now > 0)
-        if profile:
-            nf_r, si_r, so_r, ab_r, ahw_r = prof
-            for n, (rdy, _, _, _, ir) in enumerate(rules):
-                if rdy is not False:
-                    nf_r[n] = nf_r[n] + _i32(rdy, shape)
-                if ir is not True:
-                    si_r[n] = si_r[n] + _i32(_not(ir), shape)
-                stall = _and(ir, _not(rdy))
-                if stall is not False:
-                    so_r[n] = so_r[n] + _i32(stall, shape)
-            for a in range(sp.A2):
-                occ = nf_new.get(a, F(a))
-                if occ is not False:
-                    occ = _i32(occ, shape)
-                    ab_r[a] = ab_r[a] + occ
-                    ahw_r[a] = jnp.maximum(ahw_r[a], occ)
-        # 3. the environment drains the output buses
-        for r, a in enumerate(sp.out_arcs):
-            got = nf_new.get(a, F(a))
-            if got is False:
-                continue
-            ol[r] = _sel(got, nv_new.get(a, V(a)), ol[r])
-            oc[r] = oc[r] + _i32(got, shape)
-            nf_new[a] = False
-            prog = _or(prog, got)
-        for a, x in nf_new.items():
-            full[a] = _i32(x, shape)
-        for a, x in nv_new.items():
-            val[a] = x
-        if prog is not False:
-            lp = _sel(prog, jnp.broadcast_to(c + 1, shape), lp)
-        return nfired, lp
-
-    zero = jnp.zeros(shape, jnp.int32)
-    nfired, lp = jax.lax.fori_loop(0, n_cycles, cycle, (zero, zero))
-    act = active[...] != 0
-    for r_in, r_out in zip(st_in, st_out):
-        r_out[...] = jnp.where(act, r_out[...], r_in[...])
-    fired[...] = jnp.where(act, nfired, 0)
-    lastp[...] = jnp.where(act, lp, 0)
-
-
-def _dyn_block_call(sp, n_cycles, profile, batch):
-    """The pallas_call of :func:`_dyn_block_kernel` for ``batch`` slots
-    in lane layout (operands already [R, m, 128])."""
-    m, s = slot_tiles(batch)
-    grid = (m // s,)
-
-    def spec(*lead):
-        return pl.BlockSpec((*lead, s, LANES),
-                            lambda i, k=len(lead): (0,) * k + (i, 0))
-
-    st_rows = [sp.A2, sp.A2, sp.n_in, sp.n_out, sp.n_out]
-    if profile:
-        st_rows += [sp.N2] * 3 + [sp.A2] * 2
-    in_specs = [spec(sp.n_in, n_cycles), spec(sp.n_in), spec()] \
-        + [spec(r) for r in st_rows]
-    out_rows = st_rows[:5] + [None, None] + st_rows[5:]
-    out_specs = [spec() if r is None else spec(r) for r in out_rows]
-    out_shape = [jax.ShapeDtypeStruct(
-        (m, LANES) if r is None else (r, m, LANES), jnp.int32)
-        for r in out_rows]
-    rows = sp.n_in * (n_cycles + 1) + 1 + 2 * sum(st_rows) + 2
-    return pl.pallas_call(
-        functools.partial(_dyn_block_kernel, sp, n_cycles, profile),
-        grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret_mode(),
-        compiler_params=None if interpret_mode()
-        else compiler_params(rows, s),
-        name="dataflow_fire_block")
-
-
-def fire_block_batched_pallas(sp: FabricSpec, feed_vals, feed_len, full,
-                              val, ptr, out_last, out_count, *,
-                              n_cycles: int, active=None, prof=None):
-    """Batched block step: B independent streams through one fabric in a
-    single dispatch.  All state/feed arrays carry a leading batch axis;
-    the node/arc tables (``sp``) are baked into the kernel.  ``active``
-    (int32[B], default all-ones) is the per-stream clock gate: slots
-    with active == 0 keep their state and report fired/last_prog = 0, so
-    a serving layer can park quiesced slots without a global barrier.
-    Returns (full', val', ptr', out_last', out_count', fired[B, 1],
-    last_prog[B, 1]).  prof: optional 5-tuple of per-stream §12 counter
-    arrays ([B, N2] / [B, A2] int32), accumulated in-kernel per active
-    stream and returned after last_prog.
-
-    The kernel runs on the lane layout (:func:`to_lanes`): the caller's
-    [B, ...] arrays are transposed in and out around the call."""
-    B = full.shape[0]
-    m, _ = slot_tiles(B)
-    if active is None:
-        active = jnp.ones((B,), jnp.int32)
-    state = [full, val, ptr, out_last, out_count, *(prof or ())]
-    win = feed_window(feed_vals, ptr, n_cycles)
-    args = [to_lanes(win, m), to_lanes(feed_len, m),
-            to_lanes(active, m)] + [to_lanes(x, m) for x in state]
-    res = _dyn_block_call(sp, n_cycles, prof is not None, B)(*args)
-    out = [from_lanes(x, B) for x in res]
-    out[5] = out[5][:, None]
-    out[6] = out[6][:, None]
-    return tuple(out)
 
 
 def fire_block_pallas(sp: FabricSpec, feed_vals, feed_len, full, val, ptr,
@@ -709,3 +539,459 @@ def fire_block_pallas(sp: FabricSpec, feed_vals, feed_len, full, val, ptr,
         one(ptr), one(out_last), one(out_count), n_cycles=n_cycles,
         prof=None if prof is None else tuple(map(one, prof)))
     return tuple(x[0] for x in res)
+
+
+# ---------------------------------------------------------------------------
+# The row kernel: the fabric as tables
+# ---------------------------------------------------------------------------
+# A kernel that emits every node as code lowers in time that grows with
+# the fabric, and a register file held as [A2, s, 128] blocks takes 8
+# sublanes of VMEM whatever s: ISCAS-85 c6288 (9.3k arcs) neither
+# lowered in reasonable time nor fit.  The row kernel takes the fabric
+# as data (DESIGN.md §3):
+#
+# * a slot tile's registers are rows of a 2-D [R * s, 128] block, s
+#   sublanes of 128 slots a row (s in 1, 2, 4, 8), nothing padded;
+# * rows are ordered by each arc's single receiver: input k of the j-th
+#   node (nodes ordered by opcode class) is row ``base_k + j``, so a
+#   class's operands are contiguous rows, and one vector step fires 8
+#   nodes of a class on every slot of the tile;
+# * the producer side is a permutation: a loop over (arc row, node)
+#   pairs read from SMEM copies each output arc's full flag out before
+#   the cycle fires, and delivers the produced token after it.
+#
+# Its code is a few vector ops per opcode class, whatever the fabric's
+# size.
+_CH = 8                 # nodes, and register rows, per vector step
+
+
+def _class_rank(op: int) -> int:
+    """Opcode classes in row order: the two-output ops first, so their
+    second output flag covers a prefix of the nodes; the 2- and 3-input
+    ops together, so each input's rows are one contiguous range."""
+    return {int(Op.COPY): 0, int(Op.BRANCH): 1, int(Op.DMERGE): 3,
+            int(Op.NOT): 4, int(Op.SINK): 5}.get(op, 2)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Segment:
+    op: int
+    const: tuple          # per input: a const bus (never consumed)
+    lo: int               # node positions [lo, hi), multiples of _CH
+    hi: int
+
+
+class RowLayout:
+    """A fabric's tables for the row kernel, from a :class:`FabricSpec`.
+
+    Node positions: plan nodes (the dummy row included) sorted by
+    (opcode class, opcode, which inputs are const), each run padded to a
+    multiple of ``_CH`` with pad nodes that are never ready.  Register
+    rows: the consumed arcs by receiver (input k of position j at
+    ``base[k] + j - lo[k]``), then the output arcs (drained in plan
+    order), the FULL_PAD and EMPTY_PAD rows, and pad rows (empty).
+    ``src[R]`` is each row's plan column, ``dst[A2]`` each plan column's
+    row (a const bus read by several nodes has a row per reader)."""
+
+    def __init__(self, sp: FabricSpec):
+        import numpy as np
+        from repro.core.graph import ARITY
+        arity = [ARITY[Op(op)] for op in sp.opcode]
+        keys = [(_class_rank(op), op, tuple(
+            sp.const[sp.in_idx[n][k]] for k in range(arity[n][0])))
+            for n, op in enumerate(sp.opcode)]
+        order = sorted(range(sp.N2), key=keys.__getitem__)
+        node_at, segs = [], []
+        i = 0
+        while i < len(order):
+            e = i
+            while e < len(order) and keys[order[e]] == keys[order[i]]:
+                e += 1
+            lo = len(node_at)
+            node_at += order[i:e] + [-1] * (-(e - i) % _CH)
+            _, op, const = keys[order[i]]
+            segs.append(_Segment(op, const, lo, len(node_at)))
+            i = e
+        self.segments = tuple(segs)
+        self.Np = Np = len(node_at)
+        n_ins = lambda sg: ARITY[Op(sg.op)][0]
+        # input k's rows: the positions of ops with more than k inputs
+        self.base, self.lo = [], []
+        src = []
+        for k in range(3):
+            span = [sg for sg in segs if n_ins(sg) > k]
+            lo = span[0].lo if span else 0
+            hi = span[-1].hi if span else 0
+            assert sum(sg.hi - sg.lo for sg in span) == hi - lo
+            self.base.append(len(src))
+            self.lo.append(lo)
+            src += [sp.in_idx[n][k] if n >= 0 else sp.EMPTY_PAD
+                    for n in node_at[lo:hi]]
+        two = [sg for sg in segs if ARITY[Op(sg.op)][1] == 2]
+        self.n2o = two[-1].hi if two else 0          # a prefix (rank 0, 1)
+        self.out_base = len(src)
+        self.n_out = len(sp.out_arcs)
+        self.n_out_p = -(-max(self.n_out, 1) // _CH) * _CH
+        src += sp.out_arcs + [sp.EMPTY_PAD] * (self.n_out_p - self.n_out)
+        full_row, empty_row = len(src), len(src) + 1
+        src += [sp.FULL_PAD, sp.EMPTY_PAD]
+        src += [sp.EMPTY_PAD] * (-len(src) % _CH)
+        self.R = len(src)
+        dst = np.full((sp.A2,), -1, np.int64)
+        for r in range(self.out_base + self.n_out - 1, -1, -1):
+            dst[src[r]] = r                  # a const's first reader
+        dst[sp.FULL_PAD], dst[sp.EMPTY_PAD] = full_row, empty_row
+        if (dst < 0).any() or any(sp.const[a] for a in sp.out_arcs):
+            raise ValueError("the row kernel needs every arc read by a "
+                             "node or drained, and no const bus drained")
+        self.src = np.asarray(src, np.int32)
+        self.dst = dst.astype(np.int32)
+        self.n_in = len(sp.in_arcs)
+        self.n_in_p = -(-max(self.n_in, 1) // _CH) * _CH
+        in_row = [int(dst[a]) for a in sp.in_arcs]
+        in_row += [empty_row] * (self.n_in_p - self.n_in)
+        prow, pdst, pz = [], [], []
+        for j, n in enumerate(node_at):
+            for k in range(arity[n][1] if n >= 0 else 0):
+                prow.append(int(dst[sp.out_idx[n][k]]))
+                pdst.append(j + k * Np)
+                pz.append(j)
+        # pad entries: the empty row's flag into a spare flag row, which
+        # no node writes, so delivering from it changes nothing
+        pad = -len(prow) % _CH
+        prow += [empty_row] * pad
+        pdst += [Np + self.n2o] * pad
+        pz += [0] * pad
+        self.E = len(prow)
+        self.o_prow = self.n_in_p
+        self.o_pdst = self.o_prow + self.E
+        self.o_pz = self.o_pdst + self.E
+        self.table = np.asarray(in_row + prow + pdst + pz, np.int32)
+        node_at = np.asarray(node_at)
+        self.node_src = np.where(node_at < 0, sp.N2, node_at).astype(
+            np.int32)
+        self.node_dst = np.empty((sp.N2,), np.int32)
+        self.node_dst[node_at[node_at >= 0]] = np.flatnonzero(node_at >= 0)
+
+    @property
+    def flag_rows(self) -> int:
+        """Rows of the output-flag scratch: first outputs, second
+        outputs of the two-output prefix, and the spare row."""
+        return self.Np + self.n2o + _CH
+
+    def state_rows(self, profile: bool) -> list:
+        rows = [self.R, self.R, self.n_in_p, self.n_out_p, self.n_out_p]
+        return rows + ([self.Np] * 3 + [self.R] * 2 if profile else [])
+
+    def vmem_bytes(self, s: int, n_cycles: int, profile: bool) -> int:
+        """VMEM of one slot tile of ``s`` sublanes: the feed blocks
+        double-buffered, the rest of the state in and out once, and the
+        scratch: the register file (copied in from HBM and back), the
+        output flags, the produced values and the firing count."""
+        feed = self.n_in_p * (n_cycles + 1) + _CH
+        st = sum(self.state_rows(profile)[2:])
+        scratch = 2 * self.R + self.flag_rows + self.Np + _CH
+        return (2 * feed + 2 * st + scratch) * s * LANES * 4 \
+            + 2 * max(s, _SUBLANES) * LANES * 4
+
+    def tiles(self, batch: int, n_cycles: int, profile: bool):
+        """(T, s): the most sublanes a slot tile may take within the
+        VMEM limit, and the number of tiles that cover ``batch``."""
+        m = -(-batch // LANES)
+        for s in (8, 4, 2, 1):
+            if s <= m and self.vmem_bytes(s, n_cycles, profile) \
+                    + _VMEM_SLACK <= VMEM_LIMIT:
+                return -(-m // s), s
+        raise ValueError(
+            f"the row kernel needs {self.vmem_bytes(1, n_cycles, profile)}"
+            f" B of VMEM for 128 slots, over the {VMEM_LIMIT} B limit")
+
+
+def to_tiles(x, T: int, s: int, cols=None):
+    """[B, C] -> [T, C' * s, 128]: slot tile t holds slots t*s*128 ..,
+    column c at rows c*s .. c*s+s-1 (zero-padded); ``cols`` (C' column
+    indices) picks and orders the columns, as whole rows of the tiles."""
+    B, C = x.shape
+    x = jnp.pad(x, ((0, T * s * LANES - B), (0, 0)))
+    x = x.reshape(T, s, LANES, C).transpose(0, 3, 1, 2)
+    if cols is not None:
+        x = x[:, cols]
+    return x.reshape(T, -1, LANES)
+
+
+def from_tiles(y, batch: int, C: int, cols=None):
+    """Inverse of :func:`to_tiles`: [T, C * s, 128] -> [batch, C'],
+    taking columns ``cols`` (row indices or a slice) when given."""
+    T, Cs, _ = y.shape
+    y = y.reshape(T, C, Cs // C, LANES)
+    if cols is not None:
+        y = y[:, cols]
+    return y.transpose(0, 2, 3, 1).reshape(-1, y.shape[1])[:batch]
+
+
+def _row_block_kernel(lay, n_cycles, profile, s, *refs):
+    """K engine cycles (feed -> fire -> drain) of one slot tile, the
+    fabric read from tables.  Refs in: the SMEM table, window[n_in, K],
+    feed_len[n_in], active (repeated for a chunk of nodes), then the
+    state (full, val [R], ptr [n_in], out_last, out_count [n_out]) and,
+    profiled, the §12 counters (nf, si, so [Np], ab, ahw [R]); out: the
+    state, fired, last_prog and the counters, full and val in HBM (in
+    and out aliased); scratch: the output arcs' flags by producer (then
+    the produced masks), the produced values, the firing count, and the
+    tile's full and val rows.  Rows of s sublanes; active == 0 gates a
+    slot's feed, firing and drain, so it keeps its state."""
+    from jax.experimental.pallas import tpu as pltpu
+    n_st = 10 if profile else 5
+    tab, win, fl, actv = refs[:4]
+    st_in = refs[4:4 + n_st]
+    outs = refs[4 + n_st:6 + 2 * n_st]
+    oe, zb, acc, F, V = refs[6 + 2 * n_st:]
+    F_hbm, V_hbm, ptr, ol, oc, fired, lastp = outs[:7]
+    prof = outs[7:]
+    K, Np = n_cycles, lay.Np
+    tile = pl.program_id(0)
+    # the register file stays in HBM, in place; the tile's rows are
+    # copied into VMEM once a call, and back at its end
+    pltpu.sync_copy(F_hbm.at[tile], F)
+    pltpu.sync_copy(V_hbm.at[tile], V)
+    one, many = (s, LANES), (_CH * s, LANES)
+
+    def row(r):
+        return pl.ds(pl.multiple_of(r * s, s), s)
+
+    def chunk(r):
+        return pl.ds(pl.multiple_of(r * s, _CH * s), _CH * s)
+
+    def each_chunk(n_rows, body):
+        def step(i, c):
+            body(i * _CH)
+            return c
+        jax.lax.fori_loop(0, n_rows // _CH, step, 0)
+
+    def each_row(n_rows, body, carry):
+        """body(r, carry) for r < n_rows (a multiple of _CH), _CH
+        independent rows a loop step, for the scheduler to overlap."""
+        def step(i, c):
+            for u in range(_CH):
+                c = body(i * _CH + u, c)
+            return c
+        return jax.lax.fori_loop(0, n_rows // _CH, step, carry)
+
+    def copy_in(src, dst):
+        def body(r):
+            dst[chunk(r)] = src[chunk(r)]
+        each_chunk(src.shape[0] // s, body)
+
+    for r_in, r_out in zip(st_in[2:], (ptr, ol, oc, *prof)):
+        copy_in(r_in, r_out)
+
+    def zero(ref):
+        def body(r):
+            ref[chunk(r)] = jnp.zeros(many, jnp.int32)
+        each_chunk(ref.shape[0] // s, body)
+
+    zero(oe)
+    acc[...] = jnp.zeros(many, jnp.int32)
+    act1 = actv[pl.ds(0, s), :] != 0
+    actc = actv[...] != 0
+
+    def fire(sg, i, c):
+        from repro.core.graph import ARITY
+        n_in, n_out = ARITY[Op(sg.op)]
+        j0 = sg.lo + i * _CH
+        at = [lay.base[k] - lay.lo[k] + j0 for k in range(n_in)]
+        Fk = [F[chunk(r)] for r in at]
+        inf = tuple(Fk[k] != 0 if k < n_in else True for k in range(3))
+        oute = tuple(oe[chunk(j0 + k * Np)] == 0 if k < n_out else True
+                     for k in range(2))
+        v = [functools.partial(lambda r: V[chunk(r)], r) for r in at]
+        v += [v[0]] * (3 - n_in)
+        ready, z, consume, produce, ir = _node_rule(sg.op, inf, oute, v)
+        ready = _and(ready, actc)
+        for k in range(n_in):
+            ck = _and(consume[k], actc)
+            if not sg.const[k] and ck is not False:
+                F[chunk(at[k])] = jnp.where(ck, 0, Fk[k])
+        produce = [_and(produce[k], actc) for k in range(n_out)]
+        if any(p is not False for p in produce):
+            zb[chunk(j0)] = z()
+        for k, p in enumerate(produce):
+            oe[chunk(j0 + k * Np)] = _i32(p, many)
+        acc[...] = acc[...] + _i32(ready, many)
+        if profile:
+            nf, si, so = prof[:3]
+            nf[chunk(j0)] = nf[chunk(j0)] + _i32(ready, many)
+            si[chunk(j0)] = si[chunk(j0)] + _i32(_and(actc, _not(ir)),
+                                                 many)
+            so[chunk(j0)] = so[chunk(j0)] + _i32(
+                _and(actc, ir, _not(ready)), many)
+        return c
+
+    def fold():
+        tot = acc[pl.ds(0, s), :]
+        for i in range(1, _CH):
+            tot = tot + acc[pl.ds(i * s, s), :]
+        return tot
+
+    def cycle(c, carry):
+        lp, before = carry
+
+        # 1. strobe the input buses
+        def feed(r, prog):
+            a = tab[r]
+            f = F[row(a)]
+            p = ptr[row(r)]
+            can = (f == 0) & (p < fl[row(r)]) & act1
+            nxt = pick([win[row(r * K + j)] for j in range(K)],
+                       p - st_in[2][row(r)])
+            V[row(a)] = jnp.where(can, nxt, V[row(a)])
+            F[row(a)] = jnp.where(can, 1, f)
+            ptr[row(r)] = p + can.astype(jnp.int32)
+            return prog | can.astype(jnp.int32)
+        prog = each_row(lay.n_in_p, feed, jnp.zeros(one, jnp.int32))
+
+        # 2. the output arcs' full flags, by producer, before any fires
+        def look(e, x):
+            oe[row(tab[lay.o_pdst + e])] = F[row(tab[lay.o_prow + e])]
+            return x
+        each_row(lay.E, look, 0)
+        # 3. fire every class against that snapshot (consuming in place)
+        for sg in lay.segments:
+            jax.lax.fori_loop(0, (sg.hi - sg.lo) // _CH,
+                              functools.partial(fire, sg), 0)
+
+        # 4. deliver the produced tokens
+        def put(e, x):
+            a = tab[lay.o_prow + e]
+            p = oe[row(tab[lay.o_pdst + e])] != 0
+            F[row(a)] = jnp.where(p, 1, F[row(a)])
+            V[row(a)] = jnp.where(p, zb[row(tab[lay.o_pz + e])], V[row(a)])
+            return x
+        each_row(lay.E, put, 0)
+        if profile:
+            ab, ahw = prof[3:]
+
+            def occupancy(r):
+                occ = _i32((F[chunk(r)] != 0) & actc, many)
+                ab[chunk(r)] = ab[chunk(r)] + occ
+                ahw[chunk(r)] = jnp.maximum(ahw[chunk(r)], occ)
+            each_chunk(lay.R, occupancy)
+
+        # 5. the environment drains the output buses
+        def drain(r, prog):
+            a = lay.out_base + r
+            f = F[row(a)]
+            got = (f != 0) & act1
+            ol[row(r)] = jnp.where(got, V[row(a)], ol[row(r)])
+            oc[row(r)] = oc[row(r)] + got.astype(jnp.int32)
+            F[row(a)] = jnp.where(got, 0, f)
+            return prog | got.astype(jnp.int32)
+        prog = each_row(lay.n_out_p, drain, prog)
+        now = fold()
+        prog = prog | (now != before).astype(jnp.int32)
+        return jnp.where(prog != 0, c + 1, lp), now
+
+    zeros = jnp.zeros(one, jnp.int32)
+    lp, tot = jax.lax.fori_loop(0, K, cycle, (zeros, zeros))
+    fired[...] = tot
+    lastp[...] = lp
+    pltpu.sync_copy(F, F_hbm.at[tile])
+    pltpu.sync_copy(V, V_hbm.at[tile])
+
+
+def _row_block_call(lay, n_cycles, profile, T, s):
+    """The pallas_call of :func:`_row_block_kernel` over ``T`` slot
+    tiles of ``s`` sublanes (operands in :func:`to_tiles` layout)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def spec(rows, buffers=2):
+        return pl.BlockSpec((None, rows * s, LANES), lambda i: (i, 0, 0),
+                            pipeline_mode=pl.Buffered(buffers))
+
+    st = lay.state_rows(profile)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
+                spec(lay.n_in_p * n_cycles), spec(lay.n_in_p), spec(_CH),
+                hbm, hbm] + [spec(r, 1) for r in st[2:]]
+    out_rows = st[:5] + [1, 1] + st[5:]
+    scratch = [pltpu.VMEM((lay.flag_rows * s, LANES), jnp.int32),
+               pltpu.VMEM((lay.Np * s, LANES), jnp.int32),
+               pltpu.VMEM((_CH * s, LANES), jnp.int32),
+               pltpu.VMEM((lay.R * s, LANES), jnp.int32),
+               pltpu.VMEM((lay.R * s, LANES), jnp.int32)]
+    return pl.pallas_call(
+        functools.partial(_row_block_kernel, lay, n_cycles, profile, s),
+        grid=(T,), in_specs=in_specs,
+        out_specs=[hbm, hbm] + [spec(r, 1) for r in out_rows[2:]],
+        input_output_aliases={4: 0, 5: 1},
+        out_shape=[jax.ShapeDtypeStruct((T, r * s, LANES), jnp.int32)
+                   for r in out_rows],
+        scratch_shapes=scratch, interpret=interpret_mode(),
+        compiler_params=None if interpret_mode()
+        else vmem_params(lay.vmem_bytes(s, n_cycles, profile)),
+        name="dataflow_fire_block")
+
+
+def fire_block_batched_pallas(sp: FabricSpec, feed_vals, feed_len, full,
+                              val, ptr, out_last, out_count, *,
+                              n_cycles: int, active=None, prof=None):
+    """Batched block step: B independent streams through one fabric in a
+    single dispatch.  All state/feed arrays carry a leading batch axis;
+    the fabric's tables (``sp.rows``) ride in SMEM, its opcode classes
+    in the kernel's code.  ``active``
+    (int32[B], default all-ones) is the per-stream clock gate: slots
+    with active == 0 keep their state and report fired/last_prog = 0, so
+    a serving layer can park quiesced slots without a global barrier.
+    Returns (full', val', ptr', out_last', out_count', fired[B, 1],
+    last_prog[B, 1]).  prof: optional 5-tuple of per-stream §12 counter
+    arrays ([B, N2] / [B, A2] int32), accumulated in-kernel per active
+    stream and returned after last_prog.
+
+    The kernel (:func:`_row_block_kernel`) runs on its own tile layout:
+    the caller's [B, ...] arrays are permuted and transposed in and out
+    around the call, except ``full``/``val`` already in that layout
+    ([T, R, s, 128], as a slot state keeps them), which pass through."""
+    B = ptr.shape[0]
+    if active is None:
+        active = jnp.ones((B,), jnp.int32)
+    lay = sp.rows
+    tiled = full.ndim == 4          # already the kernel's [T, R, s, 128]
+    if tiled:
+        T, _, s, _ = full.shape
+    else:
+        T, s = lay.tiles(B, n_cycles, prof is not None)
+    tiles = functools.partial(to_tiles, T=T, s=s)
+
+    def regs(x):
+        return x.reshape(T, -1, LANES) if tiled else tiles(x, cols=lay.src)
+
+    def rows_of(x, n):
+        return jnp.pad(x, ((0, 0), (0, n - x.shape[1])))
+
+    def nodes(x):
+        return tiles(jnp.pad(x, ((0, 0), (0, 1))), cols=lay.node_src)
+
+    win = feed_window(feed_vals, ptr, n_cycles)
+    win = jnp.pad(win, ((0, 0), (0, lay.n_in_p - win.shape[1]), (0, 0)))
+    args = [jnp.asarray(lay.table), tiles(win.reshape(B, -1)),
+            tiles(rows_of(feed_len, lay.n_in_p)),
+            jnp.tile(tiles(active[:, None]), (1, _CH, 1)),
+            regs(full), regs(val),
+            tiles(rows_of(ptr, lay.n_in_p)),
+            tiles(rows_of(out_last, lay.n_out_p)),
+            tiles(rows_of(out_count, lay.n_out_p))]
+    if prof is not None:
+        nf, si, so, ab, ahw = prof
+        args += [nodes(nf), nodes(si), nodes(so),
+                 tiles(ab, cols=lay.src), tiles(ahw, cols=lay.src)]
+    res = _row_block_call(lay, n_cycles, prof is not None, T, s)(*args)
+    st = lay.state_rows(prof is not None)
+    cols = [lay.dst, lay.dst, slice(sp.n_in), slice(sp.n_out),
+            slice(sp.n_out), None, None]
+    if prof is not None:
+        cols += [lay.node_dst] * 3 + [lay.dst] * 2
+    rows = st[:5] + [1, 1] + st[5:]
+    out = [y.reshape(full.shape) if tiled and i < 2
+           else from_tiles(y, B, r, c)
+           for i, (y, r, c) in enumerate(zip(res, rows, cols))]
+    return tuple(out)

@@ -29,7 +29,7 @@ def rmsnorm(x, w, eps=1e-5, rows_blk=256):
 
 def make_block_step(graph, n_cycles: int, batched: bool = False,
                     tables=None, optimize: bool = False,
-                    profile: bool = False):
+                    profile: bool = False, spec=None):
     """Compile the fused K-cycle fire-block kernel for a fabric.
 
     Returns (tables, jitted step).  Single-stream step signature:
@@ -47,10 +47,12 @@ def make_block_step(graph, n_cycles: int, batched: bool = False,
     ``class_slices``).  With profile=True the step takes five trailing
     §12 counter arrays (nf, si, so, ab, ahw — per-stream rows when
     batched) and returns them, accumulated in-kernel, after last_prog:
-    profiling adds zero extra dispatches."""
+    profiling adds zero extra dispatches.  ``spec``: the tables'
+    :class:`FabricSpec`, when the caller keeps one."""
     if tables is None:
         tables = block_plan_arrays(graph, optimize=optimize)
-    spec = FabricSpec(tables)
+    if spec is None:
+        spec = FabricSpec(tables)
 
     # the batched step is the server's slot step: its jitted module is
     # named ``jit_dataflow_slot_step`` in the profiler's trace

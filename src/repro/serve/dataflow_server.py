@@ -295,6 +295,8 @@ class DataflowServer:
         self._reference = False
         self.engine: DataflowEngine | None = None
         self.state = None
+        build = -1 if self._obs is None else self._obs.begin(
+            "dataflow.build", nodes=len(graph.nodes), arcs=len(graph.arcs))
         if engine is not None:
             # an explicit engine wins over backend/block_cycles/max_cycles
             # (block size is a perf knob, never a semantics one), but it
@@ -337,6 +339,9 @@ class DataflowServer:
                                     error=repr(e))
         if self.engine is not None and not self._reference:
             self.state = self.engine.init_state(slots)
+        if self._obs is not None:
+            self._obs.end(build, kernel="reference" if self.state is None
+                          else self.engine.kernel_choice(slots))
 
     # -- construction helpers -------------------------------------------
     def _chain_from(self, backend: str) -> tuple[str, ...]:

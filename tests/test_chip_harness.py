@@ -64,3 +64,25 @@ def test_readers_on_a_traced_tiny_run(root, on_cpu, program_traced):
     assert "dataflow.step.wait" in names
     assert longest["wall_ms"] >= max(w for _, _, w in longest["spans"])
     assert longest["cpu_ms"] > 0
+
+
+def test_fire_density_reads_the_firing_counter(root, on_cpu,
+                                               program_traced):
+    """``fire_density``: node firings over nodes x active slot-cycles,
+    from the program's counters; ``None`` where the run gives no node
+    count, as ``run.py`` does today."""
+    cell = cells.load_cell("tiny.backlog", root)
+    s = run.serve(cell, BIG, 0.8, False, DEVICE)
+    obs = program_traced["obs"]
+    nodes = len(s.srv.graph.nodes)
+    read = cells.reader("fire_density", root)
+    view = run.RunView("backlog", 0.8, s.setup_s, s.log, s.traffic, s.spans,
+                       None, {}, 8, 4, None)
+    view.obs = obs
+    assert read(view) is None
+    view.fabric = {"nodes": nodes}
+    fired = obs.counter("node_firings")
+    assert fired > 0
+    assert read(view) == pytest.approx(
+        100.0 * fired / (nodes * obs.counter("active_slot_cycles")))
+    assert 0 < read(view) <= 100

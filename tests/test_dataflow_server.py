@@ -129,16 +129,16 @@ def test_active_mask_freezes_parked_slots():
     st = eng.reset_slots(st, [0], [bench.make_feeds([3])])
     while not st.quiesced_slots():
         st = eng.step_block(st)
-    frozen = [np.asarray(x)[0].copy()
-              for x in (st.full, st.val, st.ptr, st.out_last, st.out_count)]
+    regs = lambda st: (*eng.slot_registers(st), st.ptr, st.out_last,
+                       st.out_count)
+    frozen = [np.asarray(x)[0].copy() for x in regs(st)]
     st, [res0] = eng.harvest(st, [0])
     fired0, base0 = int(st.fired[0]), int(st.base[0])
     st = eng.reset_slots(st, [1], [bench.make_feeds([255, 16, 7])])
     for _ in range(5):
         st = eng.step_block(st)
     for name_, x, w in zip(("full", "val", "ptr", "out_last", "out_count"),
-                           (st.full, st.val, st.ptr, st.out_last,
-                            st.out_count), frozen):
+                           regs(st), frozen):
         np.testing.assert_array_equal(np.asarray(x)[0], w, err_msg=name_)
     # the parked slot's clock did not advance while slot 1 ran 5 blocks
     assert int(st.fired[0]) == fired0 and int(st.base[0]) == base0

@@ -33,8 +33,10 @@ from repro.serve.types import Request
 
 BACKENDS = ("xla", "pallas")
 
-# the span each span opens inside
+# the span each span opens inside: a fabric's build at the server's
+# construction, a slot step's first compile inside its dispatch
 PARENT = {
+    "dataflow.build": (None, "dataflow.step.dispatch"),
     "dataflow.heartbeat": None,
     "dataflow.admit": "dataflow.heartbeat",
     "dataflow.admit.pack": "dataflow.admit",
@@ -88,8 +90,7 @@ def test_span_tree_nests_and_counts_heartbeats(backend):
     assert (np.diff(a["t0_ns"]) >= 0).all()            # in order of begin
     for i, name in enumerate(names):
         p = a["parent"][i]
-        want = PARENT[name]
-        assert (p < 0) if want is None else names[p] == want, (i, name)
+        assert _parent_ok(names, p, name), (i, name)
         if p >= 0:
             assert a["t0_ns"][p] <= a["t0_ns"][i] <= a["t1_ns"][i] \
                 <= a["t1_ns"][p]
@@ -101,9 +102,18 @@ def test_span_tree_nests_and_counts_heartbeats(backend):
     assert a["admitted"][beats].sum() == 14
     assert a["rows"][names == "dataflow.admit"].sum() == 14
     assert set(a["kind"][names == "dataflow.harvest"]) == {"ok"}
-    # the thread CPU clock is read at the heartbeats' ends only
-    assert (a["cpu_ns"][beats] > 0).all()
-    assert (a["cpu_ns"][~beats] == -1).all()
+    # the thread CPU clock is read at the root spans' ends only: the
+    # heartbeats and the server's build
+    roots = a["parent"] < 0
+    assert (names[roots & ~beats] == "dataflow.build").all()
+    assert (a["cpu_ns"][roots] > 0).all()
+    assert (a["cpu_ns"][~roots] == -1).all()
+
+
+def _parent_ok(names, p, name) -> bool:
+    want = PARENT[name]
+    wants = want if isinstance(want, tuple) else (want,)
+    return (names[p] if p >= 0 else None) in wants
 
 
 def _check_tree(a):
@@ -113,8 +123,7 @@ def _check_tree(a):
     assert (a["t1_ns"] >= a["t0_ns"]).all()
     for i, name in enumerate(names):
         p = a["parent"][i]
-        want = PARENT[name]
-        assert (p < 0) if want is None else names[p] == want, (i, name)
+        assert _parent_ok(names, p, name), (i, name)
         if p >= 0:
             assert a["t0_ns"][p] <= a["t0_ns"][i] <= a["t1_ns"][i] \
                 <= a["t1_ns"][p]
@@ -231,8 +240,10 @@ def test_tracing_off_reads_no_clock_and_builds_no_annotation(monkeypatch):
     assert len(srv.drain()) == 1
     monkeypatch.undo()
     # the control: a recorder whose annotation factory raises is reached
+    tr = TraceRecorder()
     srv = DataflowServer(_bench().graph, slots=2, block_cycles=4,
-                         backend="xla", trace=TraceRecorder(annotate=boom))
+                         backend="xla", trace=tr)
+    tr.annotate = boom
     srv.submit(feeds)
     with pytest.raises(AssertionError, match="touched the tracer"):
         srv.step()
@@ -291,7 +302,8 @@ def test_wall_clock_export_carries_the_spans():
     assert len(spans) == tr.n_spans
     assert {e["name"] for e in spans} == set(PARENT)
     assert all("block" in e["args"] and e["dur"] >= 0 for e in spans)
-    assert all(("cpu_us" in e["args"]) == (e["name"] == "dataflow.heartbeat")
+    assert all(("cpu_us" in e["args"])
+               == (e["name"] in ("dataflow.heartbeat", "dataflow.build"))
                for e in spans)
     life = [e for e in trace["traceEvents"]
             if e["ph"] == "i" and "uid" in e["args"]]
@@ -410,3 +422,35 @@ def test_slot_step_modules_carry_stable_names(backend, module):
         jnp.zeros((_staged_size(B, n_in, st.fv.shape[2]),), jnp.int32),
         jnp.zeros((A2,), jnp.int32), jnp.zeros((A2,), jnp.int32)).as_text()
     assert "module @jit__slot_reset " in reset and n_in >= 1
+
+
+@pytest.mark.parametrize("name,kernel", [("vector_sum", "rows 1x1"),
+                                         ("array_multiplier", "rows 1x1")])
+def test_build_spans_carry_the_fabric_and_the_kernel(name, kernel):
+    """One ``dataflow.build`` at the server's construction and one
+    around each slot step's first compile (a fresh engine's), with the
+    fabric's nodes and arcs and the kernel's choice of path and tiles."""
+    b = library.BENCHES[name]()
+    tr = TraceRecorder()
+    eng = DataflowEngine(b.graph, backend="pallas", block_cycles=4)
+    srv = DataflowServer(b.graph, slots=4, engine=eng, trace=tr)
+    srv.submit(library.random_feeds(name, b, 2, np.random.default_rng(0)))
+    srv.drain()
+    a = tr.span_arrays()
+    names = a["name"]
+    build = np.flatnonzero(names == "dataflow.build")
+    assert len(build) == 2
+    assert [names[p] if p >= 0 else None for p in a["parent"][build]] == \
+        [None, "dataflow.step.dispatch"]
+    assert list(a["nodes"][build]) == [len(b.graph.nodes)] * 2
+    assert list(a["arcs"][build]) == [len(b.graph.arcs)] * 2
+    assert list(a["kernel"][build]) == [kernel] * 2
+    assert srv.engine.kernel_choice(4) == kernel
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+def test_node_firings_count_the_answers_firings(backend):
+    mr = MetricsRegistry()
+    res, _ = _serve(backend, metrics=mr, seed=6)
+    fired = sum(r.engine.fired for r in res.values())
+    assert mr.snapshot()["counters"]["node_firings"] == fired > 0

@@ -90,8 +90,7 @@ def test_specialized_per_arc_state_identical(backend):
     feeds = bench.make_feeds(40)          # still running after 3 blocks
 
     def arc_state(eng, st):
-        full = np.asarray(st.full)[0]
-        val = np.asarray(st.val)[0]
+        full, val = (np.asarray(x)[0] for x in eng.slot_registers(st))
         return {a: (int(full[eng.p["aidx"][a]]),
                     int(val[eng.p["aidx"][a]]))
                 for a in eng.p["arcs"]}

@@ -163,9 +163,12 @@ def test_slot_parity_with_dynamic(backend):
             sd = dyn.step_block(sd)
             ss = sch.step_block(ss)
             # per-arc registers at block boundaries — bit-identical
+            regs = lambda eng, st: dict(
+                zip(("full", "val"), eng.slot_registers(st)),
+                ptr=st.ptr, out_last=st.out_last, out_count=st.out_count)
+            rd, rs = regs(dyn, sd), regs(sch, ss)
             for fld in ("full", "val", "ptr", "out_last", "out_count"):
-                a, b = jax.device_get((getattr(sd, fld),
-                                       getattr(ss, fld)))
+                a, b = jax.device_get((rd[fld], rs[fld]))
                 assert np.array_equal(a, b), (backend, K, blk, fld)
             # per-slot clocks advance by schedule position
             for fld in ("base", "last", "fired", "quiesced",

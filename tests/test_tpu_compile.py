@@ -7,7 +7,10 @@ accepts but Mosaic refuses (unaligned blocks, 1-D gathers, i1 selects)
 fails here instead of on the chip.  The topology is described inside a
 fixture, never at import, so every test worker collects the same tests.
 """
+import json
 import os
+import time
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -15,13 +18,15 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import library, passes
+from repro.core import asm, library, passes
 from repro.core.engine import DataflowEngine, pack_feeds
 from repro.kernels import dataflow_fire, feed_stage, schedule_fire
 
 SLOTS = 2048
 K = 16
 FABRICS = ("fir", "bubble_sort")
+CONFIGS = Path(__file__).resolve().parent.parent / "benchmarks" / "chip" \
+    / "configs"
 
 
 @pytest.fixture(scope="module")
@@ -84,6 +89,31 @@ def test_batched_fire_kernel_compiles(name, profile, chip):
     if profile:
         args += [chip(SLOTS, sp.N2)] * 3 + [chip(SLOTS, sp.A2)] * 2
     assert "tpu_custom_call" in _compile(eng._pallas_step(K, True), *args)
+
+
+@pytest.mark.parametrize("profile", (False, True))
+def test_fire_kernel_compiles_for_c6288(profile, chip):
+    """ISCAS-85 c6288 at the chip benchmark's slots (``c6288_regen``):
+    the static kernel's register file overran the 128 MiB of VMEM after
+    minutes of lowering; the row kernel lowers and compiles in seconds."""
+    config = json.loads((CONFIGS / "c6288_regen.json").read_text())
+    graph = asm.parse((CONFIGS / config["netlist"]).read_text())
+    eng = DataflowEngine(graph, backend="pallas", block_cycles=K,
+                         profile=profile)
+    sp = eng._fabric_spec()
+    slots = config["slots"]
+    # the slot state as the server holds it: registers in the row
+    # kernel's tile layout
+    T, sub = eng._row_tiles(slots)
+    regs = chip(T, sp.rows.R, sub, 128)
+    args = [chip(slots, sp.n_in, 32), chip(slots, sp.n_in), regs, regs,
+            *_state(sp, chip, slots)[2:], chip(slots)]
+    if profile:
+        args += [chip(slots, sp.N2)] * 3 + [chip(slots, sp.A2)] * 2
+    t = time.perf_counter()
+    assert "tpu_custom_call" in _compile(eng._pallas_step(K, True), *args)
+    assert time.perf_counter() - t < 60
+    assert eng.kernel_choice(slots).startswith("rows")
 
 
 @pytest.mark.parametrize("name", FABRICS)
