@@ -393,11 +393,10 @@ class SlotState:
         return int(self.active.shape[0])
 
     def free_slots(self) -> list[int]:
-        return [b for b in range(self.slots) if not self.active[b]]
+        return np.flatnonzero(self.active == 0).tolist()
 
     def quiesced_slots(self) -> list[int]:
-        return [b for b in range(self.slots)
-                if self.active[b] and self.quiesced[b]]
+        return np.flatnonzero((self.active != 0) & self.quiesced).tolist()
 
 
 def feed_capacity(n_in: int, L: int) -> int:

@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import heapq
 from typing import Callable
 
 POLICIES = ("reject", "block", "drop-oldest")
@@ -82,6 +83,12 @@ class FairQueue:
         self._buckets: dict[object, collections.deque] = {}
         self._ring: collections.deque = collections.deque()  # tenant keys
         self._n = 0
+        # deadline index: id(request) -> expiry block of every queued
+        # request that has one, and a min-heap of (block, id) entries
+        # over them; an entry whose request left (or whose block no
+        # longer matches) is dropped lazily when it reaches the top
+        self._expires: dict[int, int] = {}
+        self._expiry_heap: list[tuple[int, int]] = []
 
     def __len__(self) -> int:
         return self._n
@@ -109,16 +116,41 @@ class FairQueue:
             self._ring.append(tenant)
         return q
 
-    def push(self, req) -> None:
+    def push(self, req, expires: int | None = None) -> None:
+        """Queue ``req`` at the back of its tenant's bucket; ``expires``
+        is the block at which it expires (None: never), which
+        :meth:`earliest_expiry` reports."""
         self._bucket(getattr(req, "tenant", None)).append(req)
         self._n += 1
+        self._index(req, expires)
 
-    def push_front(self, req) -> None:
+    def push_front(self, req, expires: int | None = None) -> None:
         """Re-queue at the front of the request's own bucket (used when
         backend degradation evicts resident requests: they resume ahead
         of their tenant's later arrivals)."""
         self._bucket(getattr(req, "tenant", None)).appendleft(req)
         self._n += 1
+        self._index(req, expires)
+
+    def _index(self, req, expires: int | None) -> None:
+        if expires is not None:
+            self._expires[id(req)] = int(expires)
+            heapq.heappush(self._expiry_heap, (int(expires), id(req)))
+
+    def _unindex(self, req) -> None:
+        if self._expires:
+            self._expires.pop(id(req), None)
+
+    def earliest_expiry(self) -> int | None:
+        """The smallest ``expires`` of the queued requests, or None when
+        none carries one: O(1) but for the stale entries it drops."""
+        live, heap = self._expires, self._expiry_heap
+        if not live:
+            heap.clear()
+            return None
+        while live.get(heap[0][1]) != heap[0][0]:
+            heapq.heappop(heap)
+        return heap[0][0]
 
     def pop(self):
         """Next request, round-robin across tenants."""
@@ -128,7 +160,9 @@ class FairQueue:
             if q:
                 self._ring.append(t)       # tenant goes to the back
                 self._n -= 1
-                return q.popleft()
+                req = q.popleft()
+                self._unindex(req)
+                return req
             del self._buckets[t]           # empty bucket leaves the ring
         raise IndexError("pop from an empty FairQueue")
 
@@ -141,7 +175,9 @@ class FairQueue:
             raise IndexError("drop_oldest from an empty FairQueue")
         victim_t = max(self._buckets, key=lambda t: len(self._buckets[t]))
         self._n -= 1
-        return self._buckets[victim_t].popleft()
+        req = self._buckets[victim_t].popleft()
+        self._unindex(req)
+        return req
 
     def remove_if(self, pred: Callable[[object], bool]) -> list:
         """Remove and return every queued request matching ``pred``
@@ -153,4 +189,6 @@ class FairQueue:
                 (out if pred(r) else kept).append(r)
             self._buckets[t] = kept
         self._n -= len(out)
+        for r in out:
+            self._unindex(r)
         return out

@@ -187,6 +187,8 @@ def clear_engine_cache() -> None:
 # server can always still answer from there.
 FALLBACK_CHAIN = ("pallas", "xla", "reference")
 
+_NEVER = np.iinfo(np.int64).max     # expiry block of a request without one
+
 
 # ---------------------------------------------------------------------------
 # The server
@@ -286,6 +288,9 @@ class DataflowServer:
         self.events: list[dict] = []   # degradations/retries/drops log
         self._queued_at: dict[int, int] = {}     # uid -> block at submit
         self._resident: dict[int, tuple[Request, int]] = {}  # slot -> (req, admitted)
+        # slot -> block at which its request expires (_NEVER: no
+        # deadline), so the heartbeat finds expired slots in one pass
+        self._expire_at = np.full((slots,), _NEVER, np.int64)
         self._retries: dict[int, int] = {}       # uid -> dispatch retries
         self._wedge_traced: set[int] = set()     # first-wedge trace dedupe
         self._degraded_uids: set[int] = set()    # restarted by degradation
@@ -339,6 +344,9 @@ class DataflowServer:
                                     error=repr(e))
         if self.engine is not None and not self._reference:
             self.state = self.engine.init_state(slots)
+        if metrics is not None:
+            # reads 0 until a queued request's deadline comes due
+            metrics.counter("expiry_sweeps", where="queue")
         if self._obs is not None:
             self._obs.end(build, kernel="reference" if self.state is None
                           else self.engine.kernel_choice(slots))
@@ -541,7 +549,7 @@ class DataflowServer:
                 self._trace("poison", uid=request.uid,
                             tenant=request.tenant)
                 request = dataclasses.replace(request, feeds=poisoned)
-        self.queue.push(request)
+        self.queue.push(request, self._expiry(request, self.block))
         self._queued_at[request.uid] = self.block
         self.max_queue_depth = max(self.max_queue_depth, len(self.queue))
         self._trace("submit", uid=request.uid, tenant=request.tenant,
@@ -562,16 +570,24 @@ class DataflowServer:
             expired=expired, backend="",
             degraded=self.degraded)
 
+    @staticmethod
+    def _expiry(req: Request, queued: int) -> int | None:
+        """Block at which ``req``, queued at block ``queued``, expires
+        (None: it has no deadline)."""
+        return None if req.deadline_blocks is None \
+            else queued + req.deadline_blocks
+
     def _admit(self) -> None:
+        if not self.queue:
+            return
         free = self.state.free_slots()
-        if not (free and self.queue):
+        if not free:
             return
         obs = self._obs
         if obs is not None:
             sp = obs.begin("dataflow.admit")
-        batch: list[tuple[int, Request]] = []
-        while free and self.queue:
-            batch.append((free.pop(0), self.queue.pop()))
+        batch = [(b, self.queue.pop())
+                 for b in free[:min(len(free), len(self.queue))]]
         self.state = self.engine.reset_slots(
             self.state, [b for b, _ in batch],
             [r.feeds for _, r in batch],
@@ -579,6 +595,9 @@ class DataflowServer:
         self.admission_rounds += 1
         for b, r in batch:
             self._resident[b] = (r, self.block)
+            if r.deadline_blocks is not None:    # past int64: never
+                self._expire_at[b] = min(
+                    self._expiry(r, self._queued_at[r.uid]), _NEVER)
             self._trace("admit", uid=r.uid, slot=b, tenant=r.tenant,
                         queue_wait_blocks=self.block
                         - self._queued_at[r.uid])
@@ -624,29 +643,28 @@ class DataflowServer:
         if self._reference:
             return results + self._step_reference()
         # 1. deadline / budget / watchdog exits on resident slots
-        #    (precedence: expired > truncated > wedged)
-        results += self._harvest_slots(
-            [b for b in sorted(self._resident)
-             if not self.state.quiesced[b] and self._deadline_blown(b)],
-            kind="expired")
-        results += self._harvest_slots(
-            [b for b in sorted(self._resident)
-             if not self.state.quiesced[b]
-             and self.state.base[b] >= self.state.cap[b]],
-            kind="truncated")
-        results += self._harvest_slots(
-            [b for b in sorted(self._resident)
-             if int(self.state.stalled[b]) >= self.wedge_timeout_blocks],
-            kind="wedged")
+        #    (precedence: expired > truncated > wedged), each found by
+        #    one pass over the slot arrays, in ascending slot order
+        if self._resident:
+            st = self.state
+            running = (st.active != 0) & ~st.quiesced
+            expired = running & (self._expire_at <= self.block)
+            truncated = running & ~expired & (st.base >= st.cap)
+            wedged = (st.active != 0) & ~expired & ~truncated \
+                & (st.stalled >= self.wedge_timeout_blocks)
+            for mask, kind in ((expired, "expired"),
+                               (truncated, "truncated"),
+                               (wedged, "wedged")):
+                results += self._harvest_slots(
+                    np.flatnonzero(mask).tolist(), kind=kind)
         # 2. admission (round-robin across tenants)
         self._admit()
         if not self._resident:
             return results
         # 3. advance one block — with retry, then degradation
-        n_cycles = min(
-            self.engine.block_cycles,
-            min(int(self.state.cap[b]) - int(self.state.base[b])
-                for b in self._resident))
+        st = self.state
+        n_cycles = min(self.engine.block_cycles,
+                       int((st.cap - st.base)[st.active != 0].min()))
         obs = self._obs
         if obs is not None:
             obs.active = len(self._resident)
@@ -680,16 +698,15 @@ class DataflowServer:
             done = [b for b in done if b not in wedged]
         return results + self._harvest_slots(done)
 
-    def _deadline_blown(self, b: int) -> bool:
-        req, _ = self._resident[b]
-        return (req.deadline_blocks is not None
-                and self.block - self._queued_at[req.uid]
-                >= req.deadline_blocks)
-
     def _expire_queued(self) -> list[Result]:
         """Deadline sweep over the queue: requests whose budget elapsed
         before admission are answered as expired without ever touching
-        a slot."""
+        a slot.  The queue's deadline index says when one is due, so a
+        heartbeat with none due walks nothing."""
+        due = self.queue.earliest_expiry()
+        if due is None or due > self.block:
+            return []
+        self._count("expiry_sweeps", where="queue")
         expired = self.queue.remove_if(
             lambda r: r.deadline_blocks is not None
             and self.block - self._queued_at[r.uid] >= r.deadline_blocks)
@@ -747,8 +764,10 @@ class DataflowServer:
         seats = [(b, self._resident[b][0]) for b in sorted(self._resident)]
         victims = [req for _, req in seats]
         self._resident.clear()
+        self._expire_at[:] = _NEVER
         for req in reversed(victims):
-            self.queue.push_front(req)
+            self.queue.push_front(
+                req, self._expiry(req, self._queued_at[req.uid]))
             self._degraded_uids.add(req.uid)
         self.max_queue_depth = max(self.max_queue_depth, len(self.queue))
         self._log_event("degrade", from_backend=failed, error=repr(err),
@@ -850,6 +869,7 @@ class DataflowServer:
         if obs is not None:
             sp_results = obs.begin("dataflow.harvest.results")
         results = []
+        self._expire_at[done] = _NEVER
         for b, er in zip(done, engine_results):
             req, admitted = self._resident.pop(b)
             # strict: a uid resident in a slot MUST have submit-time

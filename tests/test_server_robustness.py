@@ -8,15 +8,24 @@ wedged / typed error), ``step()``/``drain()`` never raise a
 workload-induced error, and unfaulted co-resident requests stay
 bit-identical to solo ``DataflowEngine.run`` results.
 """
+import json
+import sys
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from repro.core import library
 from repro.core.engine import DataflowEngine, run_reference
+from repro.obs import MetricsRegistry
 from repro.serve.admission import DroppedError, FairQueue, Rejected
 from repro.serve.dataflow_server import DataflowServer
 from repro.serve.faults import FaultPlan, InjectedFault
 from repro.serve.types import Request
+
+CHIP = Path(__file__).resolve().parent.parent / "benchmarks" / "chip"
+MIXES = CHIP / "mixes"          # the chip benchmark's traffic mixes
+sys.path.insert(0, str(CHIP))   # for its traffic generator
 
 
 @pytest.fixture()
@@ -109,6 +118,92 @@ def test_fairness_one_tenant_cannot_starve_another(bench):
     # round-robin: solo's single request rides the second admission,
     # not behind all five of flood's
     assert order.index(1) <= 1
+
+
+# ---------------------------------------------------------------------------
+# the queue's deadline index
+# ---------------------------------------------------------------------------
+def test_fair_queue_earliest_expiry_tracks_every_exit():
+    rng = np.random.default_rng(5)
+    q, expires, uid = FairQueue(), {}, 0
+    assert q.earliest_expiry() is None
+    for _ in range(600):
+        op = rng.choice(["push", "push_front", "pop", "drop_oldest",
+                         "remove_if"], p=[.35, .1, .3, .15, .1])
+        if op in ("push", "push_front") or not len(q):
+            uid += 1
+            e = int(rng.integers(0, 50)) if rng.random() < 0.5 else None
+            r = Request(uid=uid, feeds={}, tenant=int(rng.integers(3)))
+            getattr(q, "push_front" if op == "push_front" else "push")(
+                r, expires=e)
+            expires[uid] = e
+        elif op == "pop":
+            expires.pop(q.pop().uid)
+        elif op == "drop_oldest":
+            expires.pop(q.drop_oldest().uid)
+        else:
+            cut = int(rng.integers(0, 50))
+            for r in q.remove_if(lambda r: (expires[r.uid] or 99) <= cut):
+                expires.pop(r.uid)
+        assert sorted(r.uid for r in q) == sorted(expires)
+        due = [e for e in expires.values() if e is not None]
+        assert q.earliest_expiry() == (min(due) if due else None)
+
+
+def test_queued_deadline_expires_in_its_block_with_one_sweep(bench):
+    metrics = MetricsRegistry()
+    srv = DataflowServer(bench.graph, slots=1, block_cycles=1,
+                         metrics=metrics)
+    srv.submit(Request(uid=1, feeds=_feeds(bench, 8, 0)))    # hogs the slot
+    for uid in range(2, 6):
+        srv.submit(Request(uid=uid, feeds=_feeds(bench, 2, uid)))
+    srv.step()
+    srv.submit(Request(uid=9, feeds=_feeds(bench, 2, 9), deadline_blocks=4))
+    sweeps = metrics.counter("expiry_sweeps", where="queue")
+    walks = []
+    remove_if = srv.queue.remove_if
+    srv.queue.remove_if = lambda pred: walks.append(srv.block) \
+        or remove_if(pred)
+    expired_at = None
+    while srv.pending:
+        block = srv.block
+        for r in srv.step():
+            if r.uid == 9:
+                expired_at = block
+                assert r.status == "expired" and r.metrics.slot == -1
+                assert r.metrics.finished_block == block == 1 + 4
+                assert r.metrics.queue_wait_blocks == 4
+    assert expired_at == 5 and walks == [5] and sweeps.value == 1
+
+
+def test_deadline_past_int64_never_expires(bench):
+    srv = DataflowServer(bench.graph, slots=1, block_cycles=1)
+    srv.submit(Request(uid=1, feeds=_feeds(bench, 3, 0)))
+    srv.submit(Request(uid=2, feeds=_feeds(bench, 3, 1),
+                       deadline_blocks=2 ** 70))
+    assert [(r.uid, r.status) for r in srv.drain()] == [(1, "ok"), (2, "ok")]
+
+
+@pytest.mark.parametrize("mix", sorted(p.stem for p in MIXES.glob("*.json")))
+def test_traffic_without_deadlines_never_walks_the_queue(bench, mix):
+    import traffic
+    slots, arcs = 8, bench.graph.input_arcs()
+    load = traffic.generate(json.loads((MIXES / f"{mix}.json").read_text()),
+                            len(arcs), slots, 2 ** 40 + 7, 1.0)
+    metrics = MetricsRegistry()
+    srv = DataflowServer(bench.graph, slots=slots, block_cycles=16,
+                         metrics=metrics)
+    srv.queue.remove_if = None          # a walk would raise
+    uid = 0
+    for _ in range(30):
+        while len(srv.queue) < load.queued:
+            i = uid % len(load.lengths)
+            srv.submit(Request(uid=uid, feeds=load.feeds(i, arcs),
+                               tenant=int(load.tenants[i])))
+            uid += 1
+        srv.step()
+    assert srv.block == 30
+    assert metrics.snapshot()["counters"]["expiry_sweeps{where=queue}"] == 0
 
 
 # ---------------------------------------------------------------------------
