@@ -18,8 +18,8 @@ from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.core import library
-from repro.kernels import ops as kops
-from repro.kernels.dataflow_fire import FabricSpec
+from repro.kernels.dataflow_fire import (FabricSpec, block_plan_arrays,
+                                         make_block_step)
 
 
 def _time(fn, reps=5):
@@ -64,8 +64,8 @@ def main():
     # dataflow fire block (K=16 cycles of the popcount fabric, 128 slots)
     bench = library.popcount_graph(16)
     B, K = 128, 16
-    tables, step = kops.make_block_step(bench.graph, K, batched=True)
-    sp = FabricSpec(tables)
+    sp = FabricSpec(block_plan_arrays(bench.graph))
+    step = make_block_step(sp, K)
     full = jnp.zeros((B, sp.A2), jnp.int32).at[:, sp.FULL_PAD].set(1)
     z = lambda *s: jnp.zeros((B, *s), jnp.int32)
     args = (z(sp.n_in, K), z(sp.n_in), full, z(sp.A2), z(sp.n_in),

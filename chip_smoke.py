@@ -145,8 +145,7 @@ def check_mosaic(jnp, srv):
                   f"scheduled slot step K={nb}: no Mosaic kernel")
             n += 1
     else:
-        for (nb, batched), step in eng._steps.items():
-            check(batched, "the server ran a single-stream step")
+        for nb, step in eng._slot_steps.items():
             hlo = step.lower(st.fv, st.fl, st.full, st.val, st.ptr,
                              st.out_last, st.out_count,
                              st.active_dev).compile().as_text()

@@ -37,12 +37,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.alu import alu
+from repro.core.engine import BACKENDS, EngineResult, pack_feeds
 from repro.core.graph import Graph, Op
-from repro.core.engine import BACKENDS, EngineResult, _alu, pack_feeds
-
-
-def _scalar_alu(op: Op, a, b, dtype):
-    return _alu(Op, a, b, dtype)[op]
 
 
 def _truthy1(v):
@@ -146,7 +143,7 @@ def compile_dag(graph: Graph, dtype=jnp.int32):
                 env[n.outputs[0]] = jnp.where(c, a, env[n.inputs[1]])
             else:
                 b = env[n.inputs[1]] if len(n.inputs) > 1 else a
-                env[n.outputs[0]] = _scalar_alu(n.op, a, b, dtype)
+                env[n.outputs[0]] = alu(n.op, a, b, dtype)
         return {a: env[a] for a in output_arcs}
 
     return fn
@@ -268,9 +265,8 @@ def compile_cyclic(graph: Graph, token_shape=(), dtype=jnp.int32,
                     elif n.op == Op.SINK:
                         z = a_v
                     else:
-                        z = _scalar_alu(n.op, a_v,
-                                        sval[i[1]] if len(i) > 1 else a_v,
-                                        dtype)
+                        z = alu(n.op, a_v,
+                                sval[i[1]] if len(i) > 1 else a_v, dtype)
                     for x in o:
                         produced[x] |= rdy
                         pval[x] = jnp.where(rdy, z, pval[x])

@@ -26,268 +26,22 @@ fixed priority (input ``a`` wins).  See DESIGN.md §2.
 """
 from __future__ import annotations
 
-import collections
 import dataclasses
 import functools
-import hashlib
 from typing import Mapping
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.alu import (ALU_OPS, _alu_numpy, alu, inputs_ready,
+                            truthy)
 from repro.core.graph import Graph, Op
+from repro.core.plan import MAX_IN, MAX_OUT, plan
+from repro.kernels.dataflow_fire import (LANES, FabricSpec, block_plan_arrays,
+                                         fire_block_masked_ref, from_tiles,
+                                         make_block_step)
 from repro.kernels.feed_stage import place_feeds
-
-_MAX_IN = 3
-_MAX_OUT = 2
-
-# -- _plan memoization ------------------------------------------------------
-# Plan construction walks the whole graph in python and dominates engine
-# construction cost (ROADMAP item 3); the result depends only on the
-# graph's asm signature and the optimize flag (schedule state is built
-# separately and never alters the plan), so one process-wide LRU serves
-# every engine/backend/reference run of the same fabric.  The cached
-# dict's numpy arrays are frozen read-only: sharing is safe because no
-# consumer mutates a plan, and the flag turns any future mutation into
-# an immediate error instead of silent cross-engine corruption.
-_PLAN_CACHE: collections.OrderedDict = collections.OrderedDict()
-_PLAN_CACHE_MAX = 256
-PLAN_CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0}
-
-
-def clear_plan_cache() -> None:
-    _PLAN_CACHE.clear()
-    for k in PLAN_CACHE_STATS:
-        PLAN_CACHE_STATS[k] = 0
-
-
-def _plan(graph: Graph, optimize: bool = False):
-    """Memoized :func:`_plan_build` keyed on (asm signature, optimize).
-
-    The signature is the full textual serialization (nodes, consts,
-    inits), so a mutated Graph re-keys automatically; hits skip both
-    validation and array construction."""
-    from repro.core import asm
-    sig = hashlib.sha256(asm.emit(graph).encode()).hexdigest()
-    key = (sig, bool(optimize))
-    hit = _PLAN_CACHE.get(key)
-    if hit is not None:
-        PLAN_CACHE_STATS["hits"] += 1
-        _PLAN_CACHE.move_to_end(key)
-        return hit
-    PLAN_CACHE_STATS["misses"] += 1
-    p = _plan_build(graph, optimize)
-    for v in p.values():
-        if isinstance(v, np.ndarray):
-            v.flags.writeable = False
-    _PLAN_CACHE[key] = p
-    if len(_PLAN_CACHE) > _PLAN_CACHE_MAX:
-        _PLAN_CACHE.popitem(last=False)
-        PLAN_CACHE_STATS["evictions"] += 1
-    return p
-
-
-def _plan_build(graph: Graph, optimize: bool = False):
-    """Static (numpy) arrays describing the fabric.
-
-    With ``optimize=True`` the plan is *opcode-class specialized*
-    (DESIGN.md §8): arcs are permuted into role order (inputs, outputs,
-    internal, consts) and nodes are permuted so that equal opcodes are
-    contiguous, with the per-class slice ranges recorded in
-    ``class_slices`` — the fire body can then unroll a static loop over
-    only the opcode classes present instead of evaluating the full ALU
-    ``where``-chain for every node.  The permutation is pure layout:
-    every node still fires against the same snapshot, so results are
-    bit-identical to the unoptimized plan.  ``node_perm``/``arc_perm``
-    map plan row -> original index and ``node_inv``/``arc_inv`` are the
-    inverses (original index -> plan row).
-    """
-    graph.validate()
-    arcs = graph.arcs
-    input_arcs = graph.input_arcs()
-    output_arcs = graph.output_arcs()
-    if optimize:
-        # arc permutation: environment buses first (inputs, then
-        # outputs), then internal arcs, then consts — role-contiguous
-        # so environment gathers walk compact index ranges
-        ordered: dict[str, None] = {}
-        for a in (*input_arcs, *output_arcs):
-            ordered.setdefault(a, None)
-        for a in arcs:
-            if a not in graph.consts:
-                ordered.setdefault(a, None)
-        for a in arcs:
-            ordered.setdefault(a, None)
-        old_pos = {a: i for i, a in enumerate(arcs)}
-        arcs = list(ordered)
-        arc_perm = np.asarray([old_pos[a] for a in arcs], np.int32)
-    else:
-        arc_perm = np.arange(len(arcs), dtype=np.int32)
-    arc_inv = np.empty_like(arc_perm)
-    arc_inv[arc_perm] = np.arange(len(arcs), dtype=np.int32)
-    aidx = {a: i for i, a in enumerate(arcs)}
-    A = len(arcs)
-    FULL_PAD = A        # dummy slot, always full (pads missing inputs)
-    EMPTY_PAD = A + 1   # dummy slot, always empty (pads missing outputs)
-
-    N = len(graph.nodes)
-    opcode = np.zeros((N,), np.int32)
-    in_idx = np.full((N, _MAX_IN), FULL_PAD, np.int32)
-    out_idx = np.full((N, _MAX_OUT), EMPTY_PAD, np.int32)
-    for i, n in enumerate(graph.nodes):
-        opcode[i] = int(n.op)
-        for k, a in enumerate(n.inputs):
-            in_idx[i, k] = aidx[a]
-        for k, a in enumerate(n.outputs):
-            out_idx[i, k] = aidx[a]
-
-    if optimize:
-        node_perm = np.argsort(opcode, kind="stable").astype(np.int32)
-        opcode = opcode[node_perm]
-        in_idx = in_idx[node_perm]
-        out_idx = out_idx[node_perm]
-        class_slices = []
-        s = 0
-        while s < N:
-            e = s
-            while e < N and opcode[e] == opcode[s]:
-                e += 1
-            class_slices.append((int(opcode[s]), s, e))
-            s = e
-        class_slices = tuple(class_slices) or None
-    else:
-        node_perm = np.arange(N, dtype=np.int32)
-        class_slices = None
-    node_inv = np.empty_like(node_perm)
-    node_inv[node_perm] = np.arange(N, dtype=np.int32)
-
-    const_mask = np.zeros((A + 2,), bool)
-    for a in graph.consts:
-        const_mask[aidx[a]] = True
-
-    return dict(
-        arcs=arcs, aidx=aidx, A=A, FULL_PAD=FULL_PAD, EMPTY_PAD=EMPTY_PAD,
-        opcode=opcode, in_idx=in_idx, out_idx=out_idx,
-        const_mask=const_mask, input_arcs=input_arcs,
-        output_arcs=output_arcs, class_slices=class_slices,
-        node_perm=node_perm, node_inv=node_inv,
-        arc_perm=arc_perm, arc_inv=arc_inv,
-    )
-
-
-def _alu(op, a, b, dtype):
-    """All primitive results for operands a, b; select by opcode later."""
-    is_int = jnp.issubdtype(dtype, jnp.integer)
-    if is_int:
-        bs = jnp.clip(b, 0, 31)
-        safe_b = jnp.where(b == 0, 1, b)
-        res = {
-            Op.ADD: a + b, Op.SUB: a - b, Op.MUL: a * b,
-            Op.DIV: jnp.where(b == 0, 0, a // safe_b),
-            Op.AND: a & b, Op.OR: a | b, Op.XOR: a ^ b,
-            Op.MAX: jnp.maximum(a, b), Op.MIN: jnp.minimum(a, b),
-            Op.SHL: a << bs, Op.SHR: a >> bs,
-            Op.NOT: (a == 0).astype(dtype),
-        }
-    else:
-        safe_b = jnp.where(b == 0, 1.0, b)
-        two_b = jnp.exp2(b)
-        res = {
-            Op.ADD: a + b, Op.SUB: a - b, Op.MUL: a * b,
-            Op.DIV: jnp.where(b == 0, 0.0, a / safe_b),
-            Op.AND: ((a != 0) & (b != 0)).astype(dtype),
-            Op.OR: ((a != 0) | (b != 0)).astype(dtype),
-            Op.XOR: ((a != 0) ^ (b != 0)).astype(dtype),
-            Op.MAX: jnp.maximum(a, b), Op.MIN: jnp.minimum(a, b),
-            Op.SHL: a * two_b, Op.SHR: a / jnp.where(two_b == 0, 1, two_b),
-            Op.NOT: (a == 0).astype(dtype),
-        }
-    res.update({
-        Op.IFGT: (a > b).astype(dtype), Op.IFGE: (a >= b).astype(dtype),
-        Op.IFLT: (a < b).astype(dtype), Op.IFLE: (a <= b).astype(dtype),
-        Op.IFEQ: (a == b).astype(dtype), Op.IFDF: (a != b).astype(dtype),
-    })
-    return res
-
-
-def _alu_op(op, a, b, dtype):
-    """Single-opcode ALU result — the specialized fire body's per-bucket
-    kernel.  Formula-identical to the matching :func:`_alu` entry, but
-    only the requested opcode is traced, so the ``b == 0`` / shift-clamp
-    guards materialize solely for DIV/SHL/SHR buckets."""
-    is_int = jnp.issubdtype(dtype, jnp.integer)
-    if op in (Op.COPY, Op.BRANCH, Op.SINK):
-        return a
-    if op == Op.ADD:
-        return a + b
-    if op == Op.SUB:
-        return a - b
-    if op == Op.MUL:
-        return a * b
-    if op == Op.DIV:
-        if is_int:
-            return jnp.where(b == 0, 0, a // jnp.where(b == 0, 1, b))
-        return jnp.where(b == 0, 0.0, a / jnp.where(b == 0, 1.0, b))
-    if op == Op.AND:
-        return (a & b) if is_int else ((a != 0) & (b != 0)).astype(dtype)
-    if op == Op.OR:
-        return (a | b) if is_int else ((a != 0) | (b != 0)).astype(dtype)
-    if op == Op.XOR:
-        return (a ^ b) if is_int else ((a != 0) ^ (b != 0)).astype(dtype)
-    if op == Op.MAX:
-        return jnp.maximum(a, b)
-    if op == Op.MIN:
-        return jnp.minimum(a, b)
-    if op == Op.SHL:
-        return (a << jnp.clip(b, 0, 31)) if is_int else a * jnp.exp2(b)
-    if op == Op.SHR:
-        if is_int:
-            return a >> jnp.clip(b, 0, 31)
-        two_b = jnp.exp2(b)
-        return a / jnp.where(two_b == 0, 1, two_b)
-    if op == Op.NOT:
-        return (a == 0).astype(dtype)
-    if op == Op.IFGT:
-        return (a > b).astype(dtype)
-    if op == Op.IFGE:
-        return (a >= b).astype(dtype)
-    if op == Op.IFLT:
-        return (a < b).astype(dtype)
-    if op == Op.IFLE:
-        return (a <= b).astype(dtype)
-    if op == Op.IFEQ:
-        return (a == b).astype(dtype)
-    if op == Op.IFDF:
-        return (a != b).astype(dtype)
-    raise AssertionError(op)
-
-
-def _truthy(v):
-    """Scalar truth of a (possibly tensor) control token: element 0."""
-    flat = v.reshape(v.shape[0], -1)
-    return flat[:, 0] != 0
-
-
-def _node_inputs_ready(opcode, in_idx, full, val):
-    """Per-node "all (selected) inputs present" on the post-feed
-    registers — the stall-attribution predicate (DESIGN.md §12).
-
-    ``ready`` (the fire rule) implies inputs-ready, so a profiled cycle
-    partitions every node into exactly one of fired / blocked-on-input
-    (``~inputs_ready``) / blocked-on-output (``inputs_ready & ~ready``).
-    Shared by the xla cycle body and the pallas block kernels (``full``
-    may be bool or int32; pads make the generic all-inputs reduction
-    correct for BRANCH)."""
-    inf = full[in_idx].astype(bool)              # [N,3]
-    ir = inf.all(axis=1)
-    is_nd = opcode == int(Op.NDMERGE)
-    is_dm = opcode == int(Op.DMERGE)
-    ir = jnp.where(is_nd, inf[:, 0] | inf[:, 1], ir)
-    ctrl3 = _truthy(val[in_idx[:, 2]])
-    ir = jnp.where(is_dm,
-                   inf[:, 2] & jnp.where(ctrl3, inf[:, 0], inf[:, 1]), ir)
-    return ir
 
 
 def _prof_zeros(n_nodes: int, n_arcs: int, batch: int | None = None):
@@ -541,17 +295,18 @@ class DataflowEngine:
         over *blocks* of ``block_cycles`` fused cycles (one XLA dispatch
         per run).  Supports tensor tokens and any dtype.  Batched runs
         vmap the whole block loop.
-      * ``"pallas"``    — the fused ``fire_block_pallas`` kernel: K
-        cycles + environment feed/drain per device dispatch, arc state
-        VMEM-resident within a block.  Scalar int32 tokens.  Batched
-        runs use the explicit batch grid in the kernel (one dispatch
-        for all B streams per block).
+      * ``"pallas"``    — the row kernel (``dataflow_fire_block``): K
+        cycles + environment feed/drain per device dispatch, the
+        fabric's tables in SMEM and a slot tile's registers in VMEM
+        within a block.  Scalar int32 tokens.  ``run()``/``run_batch()``
+        are one wave of the slot API (:meth:`_wave`): every stream
+        admitted at once, one dispatch per block for all of them.
       * ``"reference"`` — the pure-numpy oracle (`run_reference`).
 
-    All backends share one :func:`_plan` arc/state layout and report
-    bit-identical outputs/counts/fired; ``cycles`` is reconstructed from
-    the last progress cycle, so block-granular quiescence detection does
-    not change the reported cycle count.
+    All backends share one :func:`repro.core.plan.plan` arc/state layout
+    and report bit-identical outputs/counts/fired; ``cycles`` is
+    reconstructed from the last progress cycle, so block-granular
+    quiescence detection does not change the reported cycle count.
     """
 
     def __init__(self, graph: Graph, token_shape: tuple[int, ...] = (),
@@ -636,7 +391,7 @@ class DataflowEngine:
             # regions execute the fused SPMD cycle body; the static
             # firing schedule is a whole-fabric single-device program
             self._sched_on = False
-        self.p = _plan(graph, optimize=self.optimize)
+        self.p = plan(graph, optimize=self.optimize)
         self._slot_steps: dict[int, object] = {}
         self._state0_dev = None     # (full0, val0) on the device, once
         self._tables = None
@@ -648,7 +403,6 @@ class DataflowEngine:
                 raise ValueError(
                     "pallas backend supports scalar int32 tokens only")
             self._tables = self._block_tables()
-            self._steps: dict[tuple[int, bool], object] = {}
         else:
             self._run = jax.jit(self._run_impl,
                                 static_argnames=("max_cycles",))
@@ -668,7 +422,6 @@ class DataflowEngine:
         """Gather-layout node/arc/environment tables (built lazily for
         the xla backend, eagerly for pallas)."""
         if self._tables is None:
-            from repro.kernels.dataflow_fire import block_plan_arrays
             self._tables = block_plan_arrays(self.graph,
                                              optimize=self.optimize)
         return self._tables
@@ -676,7 +429,6 @@ class DataflowEngine:
     def _fabric_spec(self):
         """The pallas kernels' static view of the tables, built once."""
         if self._spec is None:
-            from repro.kernels.dataflow_fire import FabricSpec
             self._spec = FabricSpec(self._block_tables())
         return self._spec
 
@@ -706,7 +458,6 @@ class DataflowEngine:
         whatever layout the state keeps them in."""
         if state.full.ndim == 2:
             return state.full, state.val
-        from repro.kernels.dataflow_fire import LANES, from_tiles
         lay = self._fabric_spec().rows
         return tuple(from_tiles(x.reshape(x.shape[0], -1, LANES),
                                 state.slots, lay.R, lay.dst)
@@ -747,7 +498,7 @@ class DataflowEngine:
                                  np.dtype(str(self.dtype)), max_cycles,
                                  profile=self.profile)
         if self.backend == "pallas":
-            return self._run_pallas(feeds, max_cycles)
+            return self._wave([feeds], max_cycles)[0]
         p = self.p
         feed_vals, feed_len = pack_feeds(
             p["input_arcs"], feeds, self.token_shape, self.dtype)
@@ -793,18 +544,15 @@ class DataflowEngine:
                                   np.dtype(str(self.dtype)), max_cycles,
                                   profile=self.profile)
                     for f in feeds_batch]
-        p = self.p
+        if self.backend == "pallas":
+            return self._wave(feeds_batch, max_cycles)
         L = max((max((np.shape(v)[0] for v in (f or {}).values()),
                      default=0) for f in feeds_batch), default=0)
-        L = max(L, 1)
-        pad = 1 if self.backend == "pallas" else None
-        packed = [pack_feeds(p["input_arcs"], f, self.token_shape,
-                             self.dtype, pad_rows=pad, min_len=L)
+        packed = [pack_feeds(self.p["input_arcs"], f, self.token_shape,
+                             self.dtype, min_len=max(L, 1))
                   for f in feeds_batch]
         feed_vals = np.stack([fv for fv, _ in packed])
         feed_len = np.stack([fl for _, fl in packed])
-        if self.backend == "pallas":
-            return self._run_pallas_batch(feed_vals, feed_len, max_cycles)
         vrun = self._vruns.get(max_cycles)
         if vrun is None:
             mc = max_cycles
@@ -875,6 +623,12 @@ class DataflowEngine:
 
     def init_state(self, slots: int) -> SlotState:
         """Fresh B-slot state, every slot free (active == 0)."""
+        return self._init_state(slots, self._sched_on)
+
+    def _init_state(self, slots: int, scheduled: bool) -> SlotState:
+        """init_state; ``scheduled=False`` gives a scheduled engine a
+        dynamic state, which the slot API steps with the dynamic kernel
+        (the wave's fallback when the schedule cannot run)."""
         self._check_slot_api()
         if slots < 1:
             raise ValueError("slots must be >= 1")
@@ -912,40 +666,33 @@ class DataflowEngine:
             # the plan instead (closed form — no device counters).
             prof=_prof_zeros(len(self.graph.nodes) + 1, p["A"] + 2,
                              batch=B)
-            if self.profile and not self._sched_on else None,
+            if self.profile and not scheduled else None,
             prof_cycles=z64() if self.profile else None,
-            sched=self._make_slot_sched(B) if self._sched_on else None)
+            sched=self._make_slot_sched(B) if scheduled else None)
 
     def _make_slot_sched(self, slots: int):
         from repro.core.schedule import SlotSched
         return SlotSched(self._sched_ctx(), slots, self.profile)
 
     def _slot_step(self, n_cycles: int):
-        """Jitted masked batched block step (backend-appropriate)."""
-        if self.backend == "pallas":
-            return self._pallas_step(n_cycles, True)
+        """Jitted masked batched block step of the dynamic fabric, one
+        per block length, compiled lazily: the row kernel on pallas, the
+        vmapped jnp block on xla."""
         step = self._slot_steps.get(n_cycles)
         if step is None:
-            from repro.kernels import ref as _kref
-            tables = self._block_tables()
-            vstep = jax.vmap(functools.partial(
-                _kref.fire_block_masked_prof_ref if self.profile
-                else _kref.fire_block_masked_ref,
-                tables, n_cycles=n_cycles))
+            if self.backend == "pallas":
+                step = make_block_step(self._fabric_spec(), n_cycles,
+                                       self.profile)
+            else:
+                vstep = jax.vmap(functools.partial(
+                    fire_block_masked_ref, self._block_tables(),
+                    n_cycles=n_cycles))
 
-            def dataflow_slot_step_xla(*args):   # the module's name
-                return vstep(*args)
-            step = jax.jit(dataflow_slot_step_xla)
+                def dataflow_slot_step_xla(*args):   # the module's name
+                    return vstep(*args)
+                step = jax.jit(dataflow_slot_step_xla)
             self._slot_steps[n_cycles] = step
         return step
-
-    def _has_step(self, n_cycles: int) -> bool:
-        if self.backend == "pallas":
-            return (n_cycles, True) in self._steps
-        return n_cycles in self._slot_steps
-
-    def _step_cache_size(self) -> int:
-        return len(self._slot_steps) + len(getattr(self, "_steps", ()))
 
     @_spans_closed_on_error
     def reset_slots(self, state: SlotState, slot_ids,
@@ -1055,9 +802,7 @@ class DataflowEngine:
             prof_cycles = prof_cycles.copy()
             prof_cycles[slot_ids] = 0
         sched = state.sched
-        if self._sched_on:
-            if sched is None:
-                sched = self._make_slot_sched(B)
+        if sched is not None:
             ctx = self._sched_ctx()
             n_real = len(p["input_arcs"])
             for b, fl in zip(slots, lens):
@@ -1112,14 +857,14 @@ class DataflowEngine:
             return state
         if self._part_on:
             return self._mf_ctx().slot_step(state, nb)
-        if self._sched_on:
+        if state.sched is not None:
             from repro.core import schedule as _sched
             return _sched.step_block_sched(self, state, nb)
         build = -1
         if obs is not None:
             sp = obs.begin("dataflow.step.dispatch")
-            n_steps = self._step_cache_size()
-            if not self._has_step(nb):      # lowered and compiled now
+            n_steps = len(self._slot_steps)
+            if nb not in self._slot_steps:  # lowered and compiled now
                 build = obs.begin("dataflow.build",
                                   nodes=len(self.graph.nodes),
                                   arcs=self.p["A"],
@@ -1139,7 +884,7 @@ class DataflowEngine:
             prof = state.prof
         if obs is not None:
             obs.end(build)
-            if self._step_cache_size() > n_steps:
+            if len(self._slot_steps) > n_steps:
                 obs.count("retraces", what="step")
             obs.end(sp)
             obs.count("d2h_bytes", f.nbytes + lp.nbytes, site="step")
@@ -1210,7 +955,7 @@ class DataflowEngine:
         def _prof_row(b):
             # scheduled engines accrue §12 counters on the host from the
             # plan (closed form); dynamic engines read the device rows
-            if self.profile and self._sched_on and state.sched is not None:
+            if self.profile and state.sched is not None:
                 return (*state.sched.prof_row(b),
                         int(state.prof_cycles[b]),
                         int(state.dispatches[b]))
@@ -1233,104 +978,24 @@ class DataflowEngine:
         return dataclasses.replace(state, active=active, quiesced=quiesced,
                                    active_dev=jnp.asarray(active)), results
 
-    # -- pallas backend (host loop over fused blocks) --------------------
-    def _pallas_step(self, n_cycles: int, batched: bool):
-        """Jitted block step for a given size, compiled lazily and cached
-        (the plan tables are built once in __init__ and shared).  Only
-        two sizes ever occur per run: block_cycles and the final
-        max_cycles remainder."""
-        key = (n_cycles, batched)
-        step = self._steps.get(key)
-        if step is None:
-            from repro.kernels import ops as _kops
-            _, step = _kops.make_block_step(
-                self.graph, n_cycles, batched=batched, tables=self._tables,
-                profile=self.profile, spec=self._fabric_spec())
-            self._steps[key] = step
-        return step
-
-    def _pallas_state0(self, batch: int | None = None):
-        p = self.p
-        n_in = max(len(p["input_arcs"]), 1)
-        n_out = max(len(p["output_arcs"]), 1)
-        full, val = self._state0_rows()
-        state = (full, val, np.zeros((n_in,), np.int32),
-                 np.zeros((n_out,), np.int32), np.zeros((n_out,), np.int32))
-        if batch is not None:
-            state = tuple(np.broadcast_to(x, (batch, *x.shape)).copy()
-                          for x in state)
-        return tuple(jnp.asarray(x) for x in state)
-
-    def _run_pallas(self, feeds, max_cycles: int) -> EngineResult:
-        p = self.p
-        K = self.block_cycles
-        fv, fl = pack_feeds(p["input_arcs"], feeds, (), np.int32,
-                            pad_rows=1)
-        fv, fl = jnp.asarray(fv), jnp.asarray(fl)
-        state = self._pallas_state0()
-        prof = _prof_zeros(len(self.graph.nodes) + 1, p["A"] + 2) \
-            if self.profile else None
-        base = last = fired = dispatches = 0
-        while True:
-            nb = min(K, max_cycles - base)  # never simulate past the cap
-            if self.profile:
-                res = self._pallas_step(nb, False)(fv, fl, *state, *prof)
-                state, f, lp = res[:5], res[5], res[6]
-                prof = tuple(res[7:12])
-            else:
-                *state, f, lp = self._pallas_step(nb, False)(fv, fl, *state)
-                state = tuple(state)
-            dispatches += 1
-            fired += int(f[0])
-            lp = int(lp[0])
-            if lp > 0:
-                last = base + lp
-            base += nb
-            if lp < nb or base >= max_cycles:
-                break   # idle block tail => quiescent (idle is absorbing)
-        cycles = min(last + 1, max_cycles)
-        return self._result_from_state(
-            state[3], state[4], cycles, fired, dispatches,
-            prof=None if prof is None else
-            (*jax.device_get(prof), base, dispatches))
-
-    def _run_pallas_batch(self, feed_vals, feed_len,
-                          max_cycles: int) -> list[EngineResult]:
-        K = self.block_cycles
-        B = feed_vals.shape[0]
-        fv, fl = jnp.asarray(feed_vals), jnp.asarray(feed_len)
-        state = self._pallas_state0(batch=B)
-        prof = _prof_zeros(len(self.graph.nodes) + 1, self.p["A"] + 2,
-                           batch=B) if self.profile else None
-        base = dispatches = 0
-        last = np.zeros((B,), np.int64)
-        fired = np.zeros((B,), np.int64)
-        ones = jnp.ones((B,), jnp.int32)
-        while True:
-            nb = min(K, max_cycles - base)  # never simulate past the cap
-            if self.profile:
-                res = self._pallas_step(nb, True)(fv, fl, *state, ones,
-                                                  *prof)
-                state, f, lp = res[:5], res[5], res[6]
-                prof = tuple(res[7:12])
-            else:
-                *state, f, lp = self._pallas_step(nb, True)(fv, fl, *state,
-                                                            ones)
-                state = tuple(state)
-            dispatches += 1
-            fired += np.asarray(f)[:, 0]
-            lp = np.asarray(lp)[:, 0]
-            last = np.where(lp > 0, base + lp, last)
-            base += nb
-            if (lp < nb).all() or base >= max_cycles:
-                break
-        hprof = jax.device_get(prof) if prof is not None else None
-        return [self._result_from_state(
-            state[3][b], state[4][b],
-            int(min(last[b] + 1, max_cycles)), int(fired[b]), dispatches,
-            prof=None if hprof is None else
-            (*(x[b] for x in hprof), base, dispatches))
-            for b in range(B)]
+    # -- pallas run()/run_batch(): one wave of the slot API -------------
+    def _wave(self, feeds_batch, max_cycles: int) -> list[EngineResult]:
+        """Run B streams as one admission round of the slot API: all
+        admitted at once with ``max_cycles`` as their cap, stepped a
+        block per dispatch (never past the cap) until the last one
+        quiesces, then harvested together.  Every slot stays active to
+        the end (idle is absorbing, so stepping a quiesced slot changes
+        nothing), and each result reports the wave's blocks as its
+        dispatches.  The state is dynamic even on a scheduled engine:
+        the wave is that engine's fallback."""
+        B = len(feeds_batch)
+        state = self.reset_slots(self._init_state(B, scheduled=False),
+                                 range(B), feeds_batch,
+                                 caps=[max_cycles] * B)
+        while not state.quiesced.all() and state.base[0] < max_cycles:
+            state = self.step_block(state, min(
+                self.block_cycles, max_cycles - int(state.base[0])))
+        return self.harvest(state, range(B))[1]
 
     # -- implementation ---------------------------------------------------
     def _run_impl(self, feed_vals, feed_len, *, max_cycles):
@@ -1383,8 +1048,8 @@ class DataflowEngine:
             oute = ~full[out_idx]                    # [N,2]
             a = val[in_idx[:, 0]]
             b = val[in_idx[:, 1]]
-            ctrl3 = _truthy(val[in_idx[:, 2]])       # dmerge control
-            ctrl2 = _truthy(b)                       # branch control
+            ctrl3 = truthy(val[in_idx[:, 2]])        # dmerge control
+            ctrl2 = truthy(b)                        # branch control
             all_in = inf.all(axis=1)
             all_out = oute.all(axis=1)
 
@@ -1407,15 +1072,15 @@ class DataflowEngine:
             # operand/result values
             nd_val = jnp.where(_expand(inf[:, 0], ts), a, b)
             dm_val = jnp.where(_expand(ctrl3, ts), a, b)
-            alu = _alu(Op, a, b, dtype)
             z = a  # default (COPY / BRANCH route a; SINK ignores)
-            for op, r in alu.items():
-                z = jnp.where(_expand(opcode == int(op), ts), r, z)
+            for op in ALU_OPS:
+                z = jnp.where(_expand(opcode == int(op), ts),
+                              alu(op, a, b, dtype), z)
             z = jnp.where(_expand(is_nd, ts), nd_val, z)
             z = jnp.where(_expand(is_dm, ts), dm_val, z)
 
             # consumption mask [N,3]
-            consume = ready[:, None] & jnp.ones((1, _MAX_IN), bool)
+            consume = ready[:, None] & jnp.ones((1, MAX_IN), bool)
             nd_pick = jnp.stack([inf[:, 0], ~inf[:, 0],
                                  jnp.zeros_like(inf[:, 0])], axis=1)
             dm_pick = jnp.stack([ctrl3, ~ctrl3,
@@ -1426,7 +1091,7 @@ class DataflowEngine:
                                 consume)
 
             # production mask [N,2]
-            produce = ready[:, None] & jnp.ones((1, _MAX_OUT), bool)
+            produce = ready[:, None] & jnp.ones((1, MAX_OUT), bool)
             br_pick = jnp.stack([ctrl2, ~ctrl2], axis=1)
             produce = jnp.where(is_br[:, None], ready[:, None] & br_pick,
                                 produce)
@@ -1451,10 +1116,10 @@ class DataflowEngine:
             all_in = inf.all(axis=1)
             all_out = oute.all(axis=1)
             base = all_in & all_out
-            ones_i = jnp.ones((1, _MAX_IN), bool)
-            ones_o = jnp.ones((1, _MAX_OUT), bool)
+            ones_i = jnp.ones((1, MAX_IN), bool)
+            ones_o = jnp.ones((1, MAX_OUT), bool)
             if not has_ctrl:
-                z_p = [_alu_op(Op(op), a[lo:hi], b[lo:hi], dtype)
+                z_p = [alu(Op(op), a[lo:hi], b[lo:hi], dtype)
                        for op, lo, hi in cs]
                 z = z_p[0] if len(z_p) == 1 else jnp.concatenate(z_p)
                 return (base, z, base[:, None] & ones_i,
@@ -1472,7 +1137,7 @@ class DataflowEngine:
                          jnp.zeros_like(infk[:, 0])], axis=1)
                     pk = rk[:, None] & ones_o
                 elif op == Op.DMERGE:
-                    c3 = _truthy(val[in_idx[lo:hi, 2]])
+                    c3 = truthy(val[in_idx[lo:hi, 2]])
                     rk = (infk[:, 2]
                           & jnp.where(c3, infk[:, 0], infk[:, 1])
                           & all_out[lo:hi])
@@ -1481,7 +1146,7 @@ class DataflowEngine:
                         [c3, ~c3, jnp.ones_like(c3)], axis=1)
                     pk = rk[:, None] & ones_o
                 elif op == Op.BRANCH:
-                    c2 = _truthy(bk)
+                    c2 = truthy(bk)
                     rk = (infk[:, 0] & infk[:, 1]
                           & jnp.where(c2, outek[:, 0], outek[:, 1]))
                     zk = ak
@@ -1489,7 +1154,7 @@ class DataflowEngine:
                     pk = rk[:, None] & jnp.stack([c2, ~c2], axis=1)
                 else:
                     rk = base[lo:hi]
-                    zk = _alu_op(op, ak, bk, dtype)
+                    zk = alu(op, ak, bk, dtype)
                     ck = rk[:, None] & ones_i
                     pk = rk[:, None] & ones_o
                 r_p.append(rk)
@@ -1524,7 +1189,7 @@ class DataflowEngine:
             if profile:
                 # stall attribution reads the post-feed registers the
                 # fire rule is about to see (ready ⊆ inputs_ready)
-                ir = _node_inputs_ready(opcode, in_idx, full, val)
+                ir = inputs_ready(opcode, in_idx, full, val)
             ready, z, consume, produce = fire_rule(full, val)
             pvals = jnp.stack([z, z], axis=1)        # [N,2,*ts]
 
@@ -1614,67 +1279,6 @@ def _expand(mask, ts):
 # ---------------------------------------------------------------------------
 # Pure-numpy reference engine (oracle for property tests + Pallas kernel ref)
 # ---------------------------------------------------------------------------
-def alu_numpy(op, a, b, dtype):
-    """Numpy mirror of the engine ALU — the reference engine's fire math
-    and the constant-folding pass's compile-time evaluator (sharing one
-    implementation keeps folded values bit-identical to fired ones).
-
-    Integer overflow wraps two's-complement and float specials follow
-    IEEE, exactly like the jax ALUs — numpy's over/invalid warnings are
-    suppressed because that wrapping IS the contract (the fuzz harness
-    feeds INT_MIN/INT_MAX operands on purpose).  Hot loops
-    (:func:`run_reference`'s fire step) enter one errstate around the
-    whole run and call :func:`_alu_numpy` directly instead of paying
-    the context-manager round-trip per firing."""
-    with np.errstate(all="ignore"):
-        return _alu_numpy(op, a, b, dtype)
-
-
-def _alu_numpy(op, a, b, dtype):
-    is_int = np.issubdtype(dtype, np.integer)
-    if op in (Op.COPY, Op.BRANCH, Op.SINK):
-        return a
-    if op == Op.ADD: return a + b
-    if op == Op.SUB: return a - b
-    if op == Op.MUL: return a * b
-    if op == Op.DIV:
-        return np.where(b == 0, 0, a // np.where(b == 0, 1, b)) if is_int \
-            else np.where(b == 0, 0.0, a / np.where(b == 0, 1.0, b))
-    if op == Op.AND:
-        return (a & b) if is_int else ((a != 0) & (b != 0)).astype(dtype)
-    if op == Op.OR:
-        return (a | b) if is_int else ((a != 0) | (b != 0)).astype(dtype)
-    if op == Op.XOR:
-        return (a ^ b) if is_int else ((a != 0) ^ (b != 0)).astype(dtype)
-    if op == Op.MAX:
-        if is_int:
-            return np.maximum(a, b)
-        # match the jax ALUs' signed-zero tie: max(+0., -0.) is +0. in
-        # either order, where np.maximum keeps b's zero
-        return np.where((a == 0) & (b == 0), a + b, np.maximum(a, b))
-    if op == Op.MIN:
-        if is_int:
-            return np.minimum(a, b)
-        # dually min(+0., -0.) is -0. in either order
-        return np.where((a == 0) & (b == 0), -(-a + -b), np.minimum(a, b))
-    if op == Op.SHL:
-        return (a << np.clip(b, 0, 31)) if is_int else a * np.exp2(b)
-    if op == Op.SHR:
-        if is_int:
-            return a >> np.clip(b, 0, 31)
-        two_b = np.exp2(b)
-        return a / np.where(two_b == 0, 1, two_b)
-    if op == Op.NOT: return (a == 0).astype(dtype)
-    if op == Op.IFGT: return (a > b).astype(dtype)
-    if op == Op.IFGE: return (a >= b).astype(dtype)
-    if op == Op.IFLT: return (a < b).astype(dtype)
-    if op == Op.IFLE: return (a <= b).astype(dtype)
-    if op == Op.IFEQ: return (a == b).astype(dtype)
-    if op == Op.IFDF: return (a != b).astype(dtype)
-    raise AssertionError(op)
-
-
-
 def run_reference(graph: Graph, feeds=None, token_shape=(), dtype=np.int32,
                   max_cycles: int = 100_000, trace=None,
                   profile: bool = False) -> EngineResult:
@@ -1686,7 +1290,7 @@ def run_reference(graph: Graph, feeds=None, token_shape=(), dtype=np.int32,
     DESIGN.md §12 fabric counters (the oracle for the device backends'
     profiled runs).  One errstate for the whole run: integer
     wraparound / float specials are the ALU contract (see
-    :func:`alu_numpy`), and entering a context manager per firing
+    :func:`repro.core.alu.alu_numpy`), and entering a context manager per firing
     would tax the per-node python loop."""
     with np.errstate(all="ignore"):
         return _run_reference(graph, feeds, token_shape, dtype,
@@ -1695,7 +1299,7 @@ def run_reference(graph: Graph, feeds=None, token_shape=(), dtype=np.int32,
 
 def _run_reference(graph, feeds, token_shape, dtype, max_cycles,
                    trace, profile=False) -> EngineResult:
-    p = _plan(graph)
+    p = plan(graph)
     feeds = {a: np.asarray(v, dtype).reshape(-1, *token_shape)
              if np.asarray(v).ndim == 1 and token_shape == ()
              else np.broadcast_to(
@@ -1731,8 +1335,9 @@ def _run_reference(graph, feeds, token_shape, dtype, max_cycles,
         ab = np.zeros((len(p["arcs"]),), np.int64)
         ahw = np.zeros((len(p["arcs"]),), np.int64)
 
-    def inputs_ready(n, sfull, sval):
-        """Mirror of :func:`_node_inputs_ready` on the dict registers."""
+    def node_inputs_ready(n, sfull, sval):
+        """Mirror of :func:`repro.core.alu.inputs_ready` on the dict
+        registers."""
         i = n.inputs
         if n.op == Op.NDMERGE:
             return sfull[i[0]] or sfull[i[1]]
@@ -1800,7 +1405,7 @@ def _run_reference(graph, feeds, token_shape, dtype, max_cycles,
             for n_idx, n in enumerate(graph.nodes):
                 if n_idx in fired_set:
                     nf[n_idx] += 1
-                elif inputs_ready(n, sfull, sval):
+                elif node_inputs_ready(n, sfull, sval):
                     so[n_idx] += 1
                 else:
                     si[n_idx] += 1

@@ -2,7 +2,7 @@
 
 A :class:`~repro.core.partition.Partition` splits the graph into P
 regions; each region compiles to an independent fabric plan (the same
-:func:`repro.core.engine._plan` layout the solo engine uses, including
+:func:`repro.core.plan.plan` layout the solo engine uses, including
 the role-ordered arc permutation under ``optimize``) and every
 inter-region arc becomes a *token channel* — a replicated
 (full, value) register pair that both endpoint fabrics see.  Execution
@@ -50,19 +50,11 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec
 
-from repro.core.engine import (EngineResult, SlotState, _node_inputs_ready,
-                               _alu_op, _plan, _truthy, pack_feeds)
+from repro.core.alu import ALU_OPS, alu, inputs_ready, truthy
+from repro.core.engine import EngineResult, SlotState, pack_feeds
 from repro.core.graph import Graph, Op
 from repro.core.partition import Partition
-
-_MAX_IN = 3
-_MAX_OUT = 2
-
-# opcodes whose result comes from the ALU where-chain (COPY/BRANCH/SINK
-# default to operand `a`; the merges pick operands by arrival/control)
-_ALU_OPS = tuple(
-    int(op) for op in Op
-    if op not in (Op.COPY, Op.BRANCH, Op.SINK, Op.NDMERGE, Op.DMERGE))
+from repro.core.plan import MAX_IN, MAX_OUT, plan
 
 
 @jax.jit
@@ -167,7 +159,7 @@ class MultiFabric:
                 if assign[cons[a][0]] == r:
                     sub.inits[a] = v
             self.subs.append(sub)
-        self.plans = [_plan(sub, optimize=self.optimize)
+        self.plans = [plan(sub, optimize=self.optimize)
                       for sub in self.subs]
 
         env_in_all = g.input_arcs()
@@ -193,8 +185,8 @@ class MultiFabric:
         self.Nm, self.A2m, self.n_in, self.n_out = Nm, A2m, n_in, n_out
 
         opcode = np.zeros((P, Nm), np.int32)
-        in_idx = np.zeros((P, Nm, _MAX_IN), np.int32)
-        out_idx = np.zeros((P, Nm, _MAX_OUT), np.int32)
+        in_idx = np.zeros((P, Nm, MAX_IN), np.int32)
+        out_idx = np.zeros((P, Nm, MAX_OUT), np.int32)
         const_mask = np.zeros((P, A2m), bool)
         full0 = np.zeros((P, A2m), bool)
         val0 = np.zeros((P, A2m), self._np_dtype)
@@ -257,8 +249,8 @@ class MultiFabric:
                 ch_val0[c] = g.inits[a]
 
         self._present = tuple(
-            op for op in _ALU_OPS
-            if any(int(n.op) == op for n in g.nodes))
+            int(op) for op in ALU_OPS
+            if any(n.op == op for n in g.nodes))
         self.tables = dict(
             opcode=opcode, in_idx=in_idx, out_idx=out_idx,
             const_mask=const_mask, in_arc_idx=in_arc_idx,
@@ -307,8 +299,8 @@ class MultiFabric:
                 oute = ~full[out_idx]                 # [N,2]
                 a = val[in_idx[:, 0]]
                 b = val[in_idx[:, 1]]
-                ctrl3 = _truthy(val[in_idx[:, 2]])
-                ctrl2 = _truthy(b)
+                ctrl3 = truthy(val[in_idx[:, 2]])
+                ctrl2 = truthy(b)
                 all_in = inf.all(axis=1)
                 all_out = oute.all(axis=1)
                 is_nd = opcode == int(Op.NDMERGE)
@@ -327,10 +319,10 @@ class MultiFabric:
                 z = a
                 for op in present:
                     z = jnp.where(opcode == op,
-                                  _alu_op(Op(op), a, b, dtype), z)
+                                  alu(Op(op), a, b, dtype), z)
                 z = jnp.where(is_nd, jnp.where(inf[:, 0], a, b), z)
                 z = jnp.where(is_dm, jnp.where(ctrl3, a, b), z)
-                consume = ready[:, None] & jnp.ones((1, _MAX_IN), bool)
+                consume = ready[:, None] & jnp.ones((1, MAX_IN), bool)
                 nd_pick = jnp.stack([inf[:, 0], ~inf[:, 0],
                                      jnp.zeros_like(inf[:, 0])], axis=1)
                 dm_pick = jnp.stack([ctrl3, ~ctrl3,
@@ -339,7 +331,7 @@ class MultiFabric:
                                     ready[:, None] & nd_pick, consume)
                 consume = jnp.where(is_dm[:, None],
                                     ready[:, None] & dm_pick, consume)
-                produce = ready[:, None] & jnp.ones((1, _MAX_OUT), bool)
+                produce = ready[:, None] & jnp.ones((1, MAX_OUT), bool)
                 br_pick = jnp.stack([ctrl2, ~ctrl2], axis=1)
                 produce = jnp.where(is_br[:, None],
                                     ready[:, None] & br_pick, produce)
@@ -366,7 +358,7 @@ class MultiFabric:
                 full = full.at[EMPTY_PAD].set(False)
                 # 3. fire every ready node (engine cycle step 2)
                 if profiled:
-                    ir = _node_inputs_ready(opcode, in_idx, full, val)
+                    ir = inputs_ready(opcode, in_idx, full, val)
                 ready, z, consume, produce = fire(full, val)
                 cidx = jnp.where(consume, in_idx, EMPTY_PAD).reshape(-1)
                 full = full.at[cidx].set(False)
@@ -446,7 +438,7 @@ class MultiFabric:
             out = lax.fori_loop(0, nb, body, carry)
             # clock-gate: a free slot's block never happened — state,
             # channels and counters revert, fired/lp report 0 (the
-            # kernels/ref.py masked-block contract)
+            # dataflow_fire.fire_block_masked_ref contract)
             actb = act > 0
 
             def sel(new, old):

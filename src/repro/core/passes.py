@@ -9,8 +9,8 @@ static structure out of the runtime schedule:
 * **constant folding** — a pure single-output value node (primitive /
   decider / NOT) whose inputs are all sticky const buses always produces
   the same token, so its output arc *becomes* a const bus and the
-  operator is dropped from the fabric (evaluated with the engine's own numpy ALU,
-  :func:`repro.core.engine.alu_numpy`, so folded values are bit-identical
+  operator is dropped from the fabric (evaluated with the oracle's own numpy
+  ALU, :func:`repro.core.alu.alu_numpy`, so folded values are bit-identical
   to fired ones at the target dtype);
 * **identity elimination** — ``x op c`` where the const ``c`` makes the
   op a no-op at the target dtype (``+0 -0 |0 ^0 <<0 >>0 *1 /1`` for
@@ -87,7 +87,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.engine import alu_numpy
+from repro.core.alu import alu_numpy
 from repro.core.graph import (DECIDER_OPS, Graph, Node, Op,
                               PRIMITIVE_OPS)
 

@@ -45,7 +45,7 @@ The pieces, in dependency order:
   pid sequence, one ``fori_loop`` per block, per-slot clocks advanced
   on the host from the plan (no device sync per block at all).
 
-Everything here is bookkeeping over the engine's `_plan` arrays;
+Everything here is bookkeeping over the plan's arrays (core/plan.py);
 results stay bit-identical to :func:`repro.core.engine.run_reference`
 in every field (values, counts, cycles, node_fires, per-arc registers
 at block boundaries) — property-tested in tests/test_schedule.py.
@@ -58,7 +58,8 @@ import jax
 import jax.numpy as jnp
 
 from .graph import Graph, Op
-from .engine import _alu_op, _alu_numpy, pack_feeds
+from .alu import _alu_numpy, alu
+from .engine import pack_feeds
 
 _CONTROL_OPS = (Op.BRANCH, Op.NDMERGE, Op.DMERGE)
 
@@ -551,7 +552,7 @@ class ScheduleContext:
             val = val.at[pat.fed_arcs].set(nxt)
             ptr = ptr.at[pat.fed].add(1)
         if pat.n_fires:
-            zs = [(out, jnp.repeat(_alu_op(op, val[i0], val[i1],
+            zs = [(out, jnp.repeat(alu(op, val[i0], val[i1],
                                            self.dtype), 2, axis=0))
                   for op, i0, i1, out in pat.bundles]
             for out, z2 in zs:
@@ -668,7 +669,7 @@ class ScheduleContext:
             if Op(op) in (Op.COPY, Op.SINK):
                 continue                          # z defaults to a
             z = jnp.where(opv == op,
-                          _alu_op(Op(op), a, b, jnp.int32), z)
+                          alu(Op(op), a, b, jnp.int32), z)
         val = val.at[t_o0[pid]].set(z, mode="drop")
         val = val.at[t_o1[pid]].set(z, mode="drop")
         dm = t_drain[pid]

@@ -17,12 +17,15 @@ Two implementations of the same block:
   ``in_idx[N2,3]``, ``out_idx[N2,2]``, arc adjacency, environment
   maps).  Gather-only: node-side arrays compute readiness and results,
   then each arc pulls its next state from its unique producer/consumer
-  (the paper's one-sender/one-receiver rule).  The xla backend vmaps it
-  over slots.
+  (the paper's one-sender/one-receiver rule).  The xla backend's slot
+  step vmaps :func:`fire_block_masked_ref` over slots.
 * :func:`fire_block_batched_pallas` — the Pallas kernel (the row
   kernel, see its section below): the fabric as tables in SMEM, one
   register row per arc reader, slots on sublanes and lanes.
-  :func:`fire_block_pallas` is the same kernel with one live slot.
+  :func:`make_block_step` builds the pallas backend's slot step on it.
+
+What a node computes comes from :mod:`repro.core.alu`, the layout of
+its arcs from :mod:`repro.core.plan`.
 """
 from __future__ import annotations
 
@@ -31,9 +34,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-from repro.core.graph import Op
+from repro.core.alu import ALU_OPS, alu, inputs_ready
+from repro.core.graph import ARITY, Op
+from repro.core.plan import plan
 
 
 def _ready_and_z(opcode, in_idx, out_idx, full, val, class_slices=None):
@@ -69,25 +75,11 @@ def _ready_and_z(opcode, in_idx, out_idx, full, val, class_slices=None):
     ready = jnp.where(is_br, inf[:, 0] & inf[:, 1] &
                       jnp.where(ctrl2, oute[:, 0], oute[:, 1]), ready)
 
-    bs = jnp.clip(b, 0, 31)
-    safe_b = jnp.where(b == 0, 1, b)
-    zs = {
-        Op.ADD: a + b, Op.SUB: a - b, Op.MUL: a * b,
-        Op.DIV: jnp.where(b == 0, 0, a // safe_b),
-        Op.AND: a & b, Op.OR: a | b, Op.XOR: a ^ b,
-        Op.MAX: jnp.maximum(a, b), Op.MIN: jnp.minimum(a, b),
-        Op.SHL: a << bs, Op.SHR: a >> bs,
-        Op.NOT: (a == 0).astype(a.dtype),
-        Op.IFGT: (a > b).astype(a.dtype), Op.IFGE: (a >= b).astype(a.dtype),
-        Op.IFLT: (a < b).astype(a.dtype), Op.IFLE: (a <= b).astype(a.dtype),
-        Op.IFEQ: (a == b).astype(a.dtype),
-        Op.IFDF: (a != b).astype(a.dtype),
-        Op.NDMERGE: jnp.where(inf[:, 0], a, b),
-        Op.DMERGE: jnp.where(ctrl3, a, b),
-    }
     z = a
-    for op, r in zs.items():
-        z = jnp.where(opcode == int(op), r, z)
+    for op in ALU_OPS:
+        z = jnp.where(opcode == int(op), alu(op, a, b, a.dtype), z)
+    z = jnp.where(is_nd, jnp.where(inf[:, 0], a, b), z)
+    z = jnp.where(is_dm, jnp.where(ctrl3, a, b), z)
 
     # per-slot consume/produce masks
     nd_pick = jnp.stack([inf[:, 0], ~inf[:, 0],
@@ -111,7 +103,6 @@ def _ready_and_z_spec(class_slices, in_idx, out_idx, full, val):
     """Opcode-class-specialized firing rule (scalar int32 fabric).
     Control-free fabrics keep uniform ready/consume/produce masks as
     whole-array ops; only the ALU result is bucketed."""
-    from repro.core.engine import _alu_op
     inf = full[in_idx] > 0                    # [N,3]
     oute = full[out_idx] == 0                 # [N,2]
     a = val[in_idx[:, 0]]
@@ -120,7 +111,7 @@ def _ready_and_z_spec(class_slices, in_idx, out_idx, full, val):
     all_out = oute.all(axis=1)
     base = all_in & all_out
     if not any(op in _CTRL_OPS for op, _, _ in class_slices):
-        z_p = [_alu_op(Op(op), a[lo:hi], b[lo:hi], jnp.int32)
+        z_p = [alu(Op(op), a[lo:hi], b[lo:hi], jnp.int32)
                for op, lo, hi in class_slices]
         z = z_p[0] if len(z_p) == 1 else jnp.concatenate(z_p)
         return (base, z, base[:, None] & jnp.ones_like(inf),
@@ -152,7 +143,7 @@ def _ready_and_z_spec(class_slices, in_idx, out_idx, full, val):
             pk = rk[:, None] & jnp.stack([c2, ~c2], 1)
         else:
             rk = base[lo:hi]
-            zk = _alu_op(op, ak, bk, jnp.int32)
+            zk = alu(op, ak, bk, jnp.int32)
             ck = rk[:, None] & jnp.ones_like(infk)
             pk = rk[:, None] & jnp.ones_like(outek)
         r_p.append(rk)
@@ -180,12 +171,11 @@ def _fire_parts(opcode, in_idx, out_idx, prod_node, prod_slot, cons_node,
 def plan_arrays(graph, optimize: bool = False):
     """Static numpy tables incl. arc adjacency (dummy node N = never
     ready; dummy slots pad).  With ``optimize=True`` the node table is
-    opcode-bucketed (see ``_plan``) and ``class_slices`` records the
-    static per-class ranges — the dummy node rides as a trailing
-    one-row SINK bucket so the specialized rule covers all N+1 rows."""
-    import numpy as np
-    from repro.core.engine import _plan
-    p = _plan(graph, optimize=optimize)
+    opcode-bucketed (see :func:`repro.core.plan.plan`) and
+    ``class_slices`` records the static per-class ranges — the dummy
+    node rides as a trailing one-row SINK bucket so the specialized rule
+    covers all N+1 rows."""
+    p = plan(graph, optimize=optimize)
     A2 = p["A"] + 2
     N = len(graph.nodes)
     opcode = np.concatenate([p["opcode"], [int(Op.SINK)]]).astype(np.int32)
@@ -236,7 +226,6 @@ def block_plan_arrays(graph, optimize: bool = False):
     n_in/n_out are padded to at least 1 so the kernel never sees a
     zero-length axis.
     """
-    import numpy as np
     t = plan_arrays(graph, optimize=optimize)
     p = t["plan"]
     A2 = p["A"] + 2
@@ -285,8 +274,7 @@ def _env_cycle(tab, feed_vals, feed_len, carry, class_slices=None,
     ptr = ptr + can_feed.astype(ptr.dtype)
     # 2. fire every ready node
     if profile:
-        from repro.core.engine import _node_inputs_ready
-        ir = _node_inputs_ready(tab["opcode"], tab["in_idx"], full, val)
+        ir = inputs_ready(tab["opcode"], tab["in_idx"], full, val)
     full, val, ready = _fire_parts(
         tab["opcode"], tab["in_idx"], tab["out_idx"], tab["prod_node"],
         tab["prod_slot"], tab["cons_node"], tab["cons_slot"],
@@ -329,6 +317,45 @@ def _block_body(tab, feed_vals, feed_len, full, val, ptr, out_last,
                                 profile=profile),
         carry)
     return carry[:7] + tuple(carry[8:])
+
+
+def fire_block_ref(tables, feed_vals, feed_len, full, val, ptr, out_last,
+                   out_count, *, n_cycles: int, prof=None):
+    """Same math as the fused block kernel, plain jnp (no pallas_call).
+    Also the vmap target for the batched-stream path.  ``prof`` is an
+    optional 5-tuple of §12 counter arrays (nf, si, so, ab, ahw); when
+    given the return tuple gains the accumulated counters after
+    last_prog."""
+    tab = {k: jnp.asarray(tables[k]) for k in _TABLE_KEYS}
+    return _block_body(tab, jnp.asarray(feed_vals), jnp.asarray(feed_len),
+                       full, val, ptr, out_last, out_count,
+                       n_cycles=n_cycles,
+                       class_slices=tables.get("class_slices")
+                       if hasattr(tables, "get") else None,
+                       prof=prof)
+
+
+def fire_block_masked_ref(tables, feed_vals, feed_len, full, val, ptr,
+                          out_last, out_count, active, *prof, n_cycles: int):
+    """Single-stream block step gated by a scalar ``active`` flag — the
+    pure-jnp mirror of the batched kernel's per-stream clock gate.  When
+    active == 0 the state passes through untouched and fired/last_prog
+    report 0.  vmapping this over a leading B axis gives the xla
+    backend's slot stepper (a `where`-select per row; the Pallas kernel
+    gates each slot's feed, firing and drain instead).
+
+    ``prof``: optionally the five §12 counter arrays (nf, si, so, ab,
+    ahw), threaded through the block and returned after last_prog.  A
+    clock-gated slot keeps its old counters — its block never happened,
+    so the per-slot invariant nf+si+so == profiled-cycles holds."""
+    res = fire_block_ref(tables, feed_vals, feed_len, full, val, ptr,
+                         out_last, out_count, n_cycles=n_cycles,
+                         prof=prof or None)
+    keep = active != 0
+    kept = tuple(jnp.where(keep, n, o) for n, o in zip(
+        (*res[:5], *res[7:]), (full, val, ptr, out_last, out_count, *prof)))
+    return (*kept[:5], jnp.where(keep, res[5], 0),
+            jnp.where(keep, res[6], 0), *kept[5:])
 
 
 # ---------------------------------------------------------------------------
@@ -468,7 +495,6 @@ class FabricSpec:
     layout (:attr:`rows`) is built."""
 
     def __init__(self, tables):
-        import numpy as np
         p = tables["plan"]
         self.A = int(p["A"])
         self.A2 = self.A + 2
@@ -516,29 +542,9 @@ def _node_rule(op, inf, oute, v):
         ready = _and(inf[0], inf[1], _msel(c2, oute[0], oute[1]))
         return (ready, v[0], (ready,) * 3,
                 (_and(ready, c2), _and(ready, ~c2)), ir)
-    from repro.core.engine import _alu_op
     ready = _and(ir, all_out)
-    z = lambda: _alu_op(Op(op), v[0](), v[1](), jnp.int32)
+    z = lambda: alu(Op(op), v[0](), v[1](), jnp.int32)
     return ready, z, (ready,) * 3, (ready, ready), ir
-
-
-def fire_block_pallas(sp: FabricSpec, feed_vals, feed_len, full, val, ptr,
-                      out_last, out_count, *, n_cycles: int, prof=None):
-    """K fused engine cycles (environment included) for one stream: the
-    batched kernel with one live slot.
-
-    feed_vals[n_in, L] int32, feed_len[n_in] int32.
-    State: full/val[A2], ptr[n_in], out_last/out_count[n_out], int32.
-    Returns (full', val', ptr', out_last', out_count', fired[1],
-    last_prog[1]).  prof: optional 5-tuple of §12 counter arrays
-    (nf/si/so[N2], ab/ahw[A2] int32) — accumulated in-kernel and
-    returned after last_prog."""
-    one = lambda x: x[None]
-    res = fire_block_batched_pallas(
-        sp, one(feed_vals), one(feed_len), one(full), one(val),
-        one(ptr), one(out_last), one(out_count), n_cycles=n_cycles,
-        prof=None if prof is None else tuple(map(one, prof)))
-    return tuple(x[0] for x in res)
 
 
 # ---------------------------------------------------------------------------
@@ -594,8 +600,6 @@ class RowLayout:
     row (a const bus read by several nodes has a row per reader)."""
 
     def __init__(self, sp: FabricSpec):
-        import numpy as np
-        from repro.core.graph import ARITY
         arity = [ARITY[Op(op)] for op in sp.opcode]
         keys = [(_class_rank(op), op, tuple(
             sp.const[sp.in_idx[n][k]] for k in range(arity[n][0])))
@@ -796,7 +800,6 @@ def _row_block_kernel(lay, n_cycles, profile, s, *refs):
     actc = actv[...] != 0
 
     def fire(sg, i, c):
-        from repro.core.graph import ARITY
         n_in, n_out = ARITY[Op(sg.op)]
         j0 = sg.lo + i * _CH
         at = [lay.base[k] - lay.lo[k] + j0 for k in range(n_in)]
@@ -995,3 +998,32 @@ def fire_block_batched_pallas(sp: FabricSpec, feed_vals, feed_len, full,
            else from_tiles(y, B, r, c)
            for i, (y, r, c) in enumerate(zip(res, rows, cols))]
     return tuple(out)
+
+
+def make_block_step(sp: FabricSpec, n_cycles: int, profile: bool = False):
+    """The pallas backend's slot step: :func:`fire_block_batched_pallas`
+    for K = ``n_cycles``, jitted.  Signature:
+      step(feed_vals, feed_len, full, val, ptr, out_last, out_count,
+           active) -> (full', val', ptr', out_last', out_count',
+                       fired[B, 1], last_prog[B, 1])
+    every array with a leading B slots axis; ``active`` int32[B] is the
+    clock gate (slots with active == 0 keep their state and report 0).
+    With profile=True the step takes five trailing §12 counter arrays
+    (nf, si, so, ab, ahw) and returns them, accumulated in-kernel, after
+    last_prog: profiling adds no dispatch.  Its jitted module is named
+    ``jit_dataflow_slot_step`` in the profiler's trace."""
+    if profile:
+        def dataflow_slot_step(feed_vals, feed_len, full, val, ptr,
+                               out_last, out_count, active,
+                               nf, si, so, ab, ahw):
+            return fire_block_batched_pallas(
+                sp, feed_vals, feed_len, full, val, ptr, out_last,
+                out_count, n_cycles=n_cycles, active=active,
+                prof=(nf, si, so, ab, ahw))
+    else:
+        def dataflow_slot_step(feed_vals, feed_len, full, val, ptr,
+                               out_last, out_count, active):
+            return fire_block_batched_pallas(
+                sp, feed_vals, feed_len, full, val, ptr, out_last,
+                out_count, n_cycles=n_cycles, active=active)
+    return jax.jit(dataflow_slot_step)

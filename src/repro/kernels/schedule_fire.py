@@ -31,15 +31,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.alu import alu
 from repro.core.graph import Op
 from repro.kernels.dataflow_fire import (LANES, compiler_params, feed_window,
                                          from_lanes, interpret_mode, pick,
                                          slot_tiles, to_lanes)
-
-
-def _alu(op, a, b):
-    from repro.core.engine import _alu_op
-    return _alu_op(Op(op), a, b, jnp.int32)
 
 
 def _needs_b(op) -> bool:
@@ -72,8 +68,8 @@ def _sched_run_kernel(ctx, struct, reps_ref, fv_ref, ol_ref, oc_ref,
         for op, i0, i1, out in pat.bundles:
             for x, y, o2 in zip(i0.tolist(), i1.tolist(),
                                 out.reshape(-1, 2).tolist()):
-                zs.append((o2, _alu(op, val_ref[row(x), :],
-                                    val_ref[row(y), :])))
+                zs.append((o2, alu(Op(op), val_ref[row(x), :],
+                                   val_ref[row(y), :], jnp.int32)))
         for o2, z in zs:
             for o in o2:
                 if o < ctx.A2:          # A2: the missing-output sentinel
@@ -171,7 +167,8 @@ def _sched_slot_kernel(sp, n_cycles, n_words, bits, win, val_i, ptr_i,
             ptr[r] = ptr[r] + fm.astype(jnp.int32)
         # 2. fire: every ALU reads the post-feed snapshot; a node's
         # result lands on its output rows where its fire bit is set
-        zs = [(n, outs, _alu(op, V(i0), V(i1) if _needs_b(op) else None))
+        zs = [(n, outs, alu(Op(op), V(i0), V(i1) if _needs_b(op) else None,
+                            jnp.int32))
               for n, op, i0, i1, outs in sp["nodes"]]
         new = {a: vc[a] for a in sp["in_arcs"]}
         for n, outs, z in zs:

@@ -84,10 +84,10 @@ from typing import Iterable, Mapping
 import numpy as np
 
 from repro.core import asm
-from repro.core.engine import (BACKENDS, PLAN_CACHE_STATS, DataflowEngine,
-                               run_reference)
-from repro.core.partition import resolve_partition
+from repro.core.engine import BACKENDS, DataflowEngine, run_reference
 from repro.core.graph import Graph
+from repro.core.partition import resolve_partition
+from repro.core.plan import PLAN_CACHE_STATS
 from repro.obs.probe import Probe
 from repro.serve.admission import (POLICIES, DroppedError, FairQueue,
                                    QueueFullError, Rejected)
@@ -105,8 +105,8 @@ _ENGINE_CACHE_MAX = 64      # LRU bound: a long-running service sees a
                             # finite fabric vocabulary; evicted engines
                             # stay alive wherever still referenced
 CACHE_STATS = {"hits": 0, "misses": 0, "evictions": 0,
-               # live view of the process-wide _plan memo (engine-level;
-               # ROADMAP item 3): same dict object, not a snapshot
+               # live view of the process-wide plan memo
+               # (core/plan.py): same dict object, not a snapshot
                "plan": PLAN_CACHE_STATS}
 
 

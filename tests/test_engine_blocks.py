@@ -86,10 +86,12 @@ def test_batched_pallas_kernel_matches_vmap():
     import jax
     import jax.numpy as jnp
     from repro.core.engine import pack_feeds
-    from repro.kernels import ops, ref
+    from repro.kernels.dataflow_fire import (FabricSpec, block_plan_arrays,
+                                             fire_block_ref, make_block_step)
 
     bench = library.popcount_graph(8)
-    tables, bstep = ops.make_block_step(bench.graph, 8, batched=True)
+    tables = block_plan_arrays(bench.graph)
+    bstep = make_block_step(FabricSpec(tables), 8)
     p = tables["plan"]
     B, L = 4, 4   # L = longest stream (stream b carries 1+b tokens)
     packed = [pack_feeds(p["input_arcs"],
@@ -113,7 +115,7 @@ def test_batched_pallas_kernel_matches_vmap():
     active = jnp.ones((B,), jnp.int32)
     got = bstep(fv, fl, *state, active)
     want = jax.vmap(
-        lambda fv1, fl1, *s: ref.fire_block_ref(
+        lambda fv1, fl1, *s: fire_block_ref(
             tables, fv1, fl1, *s, n_cycles=8))(fv, fl, *state)
     for g, w in zip(got[:5], want[:5]):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

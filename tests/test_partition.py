@@ -25,8 +25,8 @@ import pytest
 
 from repro.core import compile as C
 from repro.core import library
-from repro.core.engine import (DataflowEngine, PLAN_CACHE_STATS,
-                               clear_plan_cache, run_reference)
+from repro.core.engine import DataflowEngine, run_reference
+from repro.core.plan import PLAN_CACHE_STATS, clear_plan_cache
 from repro.core.graph import Graph, Op
 from repro.core.partition import (Partition, auto_partition,
                                   partition_graph, resolve_partition)

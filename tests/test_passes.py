@@ -538,9 +538,9 @@ def test_float_add_zero_is_not_spliced_signed_zero():
 def test_float_shr_underflow_guard_matches_jax_alus():
     """alu_numpy (the reference engine's fire math AND the folder's
     compile-time evaluator) guards float SHR's exp2 underflow exactly
-    like the jax `_alu`/`_alu_op` paths: exp2(-200) underflows float32
+    like the jax `alu`: exp2(-200) underflows float32
     to 0, and a/0 would fold to inf where the live engines produce a."""
-    from repro.core.engine import alu_numpy
+    from repro.core.alu import alu_numpy
     a = np.float32(3.0)
     assert alu_numpy(Op.SHR, a, np.float32(-200.0), np.float32) == a
     g = Graph(name="shr_fold")
@@ -563,8 +563,8 @@ def test_float_shr_underflow_guard_matches_jax_alus():
 
 
 def test_alu_numpy_matches_jax_alu_on_edge_operands():
-    """alu_numpy (fold / reference fire math) and the jax `_alu_op`
-    (specialized fire) are hand-synced copies of one formula table, and
+    """alu_numpy (fold / reference fire math) and the jax `alu`
+    (every device path's fire math) are hand-synced copies of one formula table, and
     the float-SHR underflow drift shipped because no test compared them
     on edge operands.  Pin bit-for-bit parity across every value op x
     dtype on the historical drift points: zero divisors, signed zeros,
@@ -573,7 +573,7 @@ def test_alu_numpy_matches_jax_alu_on_edge_operands():
     shift-by->=-width (clipped, both directions), and unsigned
     wraparound on a uint32 fabric."""
     import jax.numpy as jnp
-    from repro.core.engine import _alu_op, alu_numpy
+    from repro.core.alu import alu, alu_numpy
     cases = {
         np.int32: [-(2 ** 31), -(2 ** 31) + 1, -40, -2, -1, 0, 1, 5,
                    31, 32, 33, 40, 2 ** 31 - 1],
@@ -591,7 +591,7 @@ def test_alu_numpy_matches_jax_alu_on_edge_operands():
             with np.errstate(all="ignore"):
                 want = np.asarray(alu_numpy(op, a, b, dt), dt)
             got = np.asarray(
-                _alu_op(op, jnp.asarray(a), jnp.asarray(b), dt)
+                alu(op, jnp.asarray(a), jnp.asarray(b), dt)
             ).astype(dt, copy=False)
             nan = np.isnan(want) if is_f else np.zeros(want.shape, bool)
             assert (got.view(uview)[~nan]
@@ -606,7 +606,7 @@ def test_alu_integer_edge_regressions_pin_exact_values():
     INT_MIN (and never traps), shifts by >= width clip to 31, negative
     shift counts clip to 0, and uint32 SUB wraps."""
     import jax.numpy as jnp
-    from repro.core.engine import _alu_op, alu_numpy
+    from repro.core.alu import alu, alu_numpy
     INT_MIN = np.int32(-(2 ** 31))
     checks = [
         (Op.DIV, np.int32, INT_MIN, np.int32(-1), INT_MIN),
@@ -626,10 +626,10 @@ def test_alu_integer_edge_regressions_pin_exact_values():
         with np.errstate(all="ignore"):
             got_np = np.asarray(alu_numpy(op, a, b, dt), dt).reshape(())
         got_jx = np.asarray(
-            _alu_op(op, jnp.asarray(a), jnp.asarray(b), dt)
+            alu(op, jnp.asarray(a), jnp.asarray(b), dt)
         ).astype(dt).reshape(())
         assert got_np == want, (op, dt, "alu_numpy")
-        assert got_jx == want, (op, dt, "_alu_op")
+        assert got_jx == want, (op, dt, "alu")
 
 
 def test_uint32_fabric_runs_bit_identical_across_engines():

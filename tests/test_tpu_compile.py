@@ -88,7 +88,7 @@ def test_batched_fire_kernel_compiles(name, profile, chip):
             *_state(sp, chip, SLOTS), chip(SLOTS)]
     if profile:
         args += [chip(SLOTS, sp.N2)] * 3 + [chip(SLOTS, sp.A2)] * 2
-    assert "tpu_custom_call" in _compile(eng._pallas_step(K, True), *args)
+    assert "tpu_custom_call" in _compile(eng._slot_step(K), *args)
 
 
 @pytest.mark.parametrize("profile", (False, True))
@@ -111,17 +111,22 @@ def test_fire_kernel_compiles_for_c6288(profile, chip):
     if profile:
         args += [chip(slots, sp.N2)] * 3 + [chip(slots, sp.A2)] * 2
     t = time.perf_counter()
-    assert "tpu_custom_call" in _compile(eng._pallas_step(K, True), *args)
+    assert "tpu_custom_call" in _compile(eng._slot_step(K), *args)
     assert time.perf_counter() - t < 60
     assert eng.kernel_choice(slots).startswith("rows")
 
 
 @pytest.mark.parametrize("name", FABRICS)
 def test_single_stream_fire_kernel_compiles(name, chip):
+    """What run() dispatches: the slot step over one slot, its
+    registers in the row kernel's tile layout."""
     _, eng = _engine(name, backend="pallas")
-    sp = dataflow_fire.FabricSpec(eng._tables)
-    args = [chip(sp.n_in, 1024), chip(sp.n_in), *_state(sp, chip)]
-    assert "tpu_custom_call" in _compile(eng._pallas_step(K, False), *args)
+    sp = eng._fabric_spec()
+    T, sub = eng._row_tiles(1)
+    regs = chip(T, sp.rows.R, sub, 128)
+    args = [chip(1, sp.n_in, 1024), chip(1, sp.n_in), regs, regs,
+            *_state(sp, chip, 1)[2:], chip(1)]
+    assert "tpu_custom_call" in _compile(eng._slot_step(K), *args)
 
 
 def _sched(name):
